@@ -36,6 +36,20 @@ import jax as _jax
 # explicitly, so this does not affect accelerator performance.
 _jax.config.update("jax_enable_x64", True)
 
+# persistent compile cache, placed from outside: JAX_COMPILATION_CACHE_DIR
+# wins (jax reads it itself; no directory is set in code then). Otherwise a
+# FIXED path in the checkout — the path is part of the cache key, so a
+# directory that moves (tempfile, pid, time) would never hit.
+import os as _os
+
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(
+            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+            ".jax_cache"),
+    )
+
 from . import core
 from .core import (  # noqa: F401
     CPUPlace,

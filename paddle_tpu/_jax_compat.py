@@ -1,117 +1,9 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-One import site for the whole tree (library modules AND tests): jax
-promoted ``shard_map`` from ``jax.experimental.shard_map`` to the top-level
-namespace (and later removed the experimental module), so neither spelling
-imports across every version we run against. Import it from here instead:
+"""The tree's one import site for ``shard_map`` and ``axis_size`` (library
+modules and tests), so a future jax move is one edit:
 
     from paddle_tpu._jax_compat import shard_map
 """
-from __future__ import annotations
+from jax import shard_map
+from jax.lax import axis_size
 
-__all__ = ["axis_size", "shard_map", "shardmap_autodiff_limitation"]
-
-try:  # jax >= 0.5: top-level export
-    from jax import shard_map as _shard_map
-    if not callable(_shard_map):  # transitional releases export the module
-        _shard_map = _shard_map.shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_params = frozenset(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f=None, *args, **kwargs):
-    """``jax.shard_map`` with version drift normalized: the replication
-    check is spelled ``check_vma`` (new) or ``check_rep`` (0.4.x), and the
-    manual-axes set is ``axis_names`` (new) or the complementary ``auto``
-    (0.4.x) — accept either spelling and pass whichever the installed
-    version understands. Positional ``(f, mesh, in_specs, out_specs)``
-    calls work as with the real API."""
-    if args:
-        if len(args) > 3:
-            raise TypeError(
-                f"shard_map() takes at most 4 positional arguments "
-                f"({1 + len(args)} given)"
-            )
-        for name, val in zip(("mesh", "in_specs", "out_specs"), args):
-            if name in kwargs:
-                raise TypeError(
-                    f"shard_map() got multiple values for argument {name!r}"
-                )
-            kwargs[name] = val
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        if "check_vma" in _params:
-            kwargs["check_vma"] = check
-        elif "check_rep" in _params:
-            kwargs["check_rep"] = check
-    if "axis_names" in kwargs and "axis_names" not in _params:
-        # newer jax: axis_names = the MANUAL axes; 0.4.x spells the same
-        # contract as `auto` = the complement set of the mesh's axes.
-        # Size-1 axes are folded into the manual set instead: replication
-        # over a 1-sized axis is a no-op, and 0.4.x cannot differentiate
-        # through shard_map when `auto` is non-empty.
-        manual = frozenset(kwargs.pop("axis_names"))
-        mesh = kwargs.get("mesh")
-        if "auto" in _params and mesh is not None:
-            kwargs["auto"] = frozenset(
-                a for a in mesh.axis_names
-                if a not in manual and mesh.shape[a] > 1
-            )
-        else:  # never silently widen the manual set
-            raise TypeError(
-                "this jax version supports neither the axis_names kwarg "
-                "nor an auto+mesh translation for it; pass mesh= and drop "
-                "axis_names, or upgrade jax"
-            )
-    if f is None:
-        import functools
-
-        return functools.partial(shard_map, **kwargs)
-    return _shard_map(f, **kwargs)
-
-
-def shardmap_autodiff_limitation():
-    """Reason string when the installed jax cannot differentiate through a
-    ``shard_map`` region with non-empty ``auto`` axes, else ``None``.
-
-    jax 0.4.x (including 0.4.37) hits a partial-eval bug when a shard_map
-    with auto (replicated) axes is transposed: scalar residuals produced
-    inside the manual region come out as per-shard values the transpose
-    rule cannot re-broadcast, and the trace dies deep inside
-    ``jax.interpreters.partial_eval`` with an opaque shape error. The two
-    consumers of this contract:
-
-    - ``analysis.sharding.pipelined_step_context`` falls back to a
-      forward-only loss program on affected versions (its per-shard
-      memory/donation report says so), and
-    - the whole-step capture controller (``core.lazy``) refuses to capture
-      a step on a pipelined (pp) mesh with a structured
-      ``_CaptureIneligible(shardmap_autodiff_limitation())`` instead of
-      surfacing the opaque trace error — the pp schedule is a shard_map
-      region, so capturing forward+backward there would differentiate
-      through it.
-
-    jax >= 0.5 rewrote shard_map partial-eval and does not have the bug.
-    """
-    import jax
-
-    try:
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:
-        return None  # unparseable dev version: assume fixed
-    return "shardmap_autodiff" if ver < (0, 5) else None
-
-
-try:  # jax >= 0.5
-    from jax.lax import axis_size
-except ImportError:
-    def axis_size(axis_name):
-        """Size of a named mesh axis inside a shard_map/pmap region. psum of
-        a Python literal folds to a concrete int on every jax version."""
-        import jax
-
-        return jax.lax.psum(1, axis_name)
+__all__ = ["axis_size", "shard_map"]

@@ -37,7 +37,14 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+# The IR's one point of contact with JAX's jaxpr types: the other analysis
+# modules import these names from here, never from jax directly.
+from jax.core import DropVar, ShapedArray, eval_jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
+
 from ..core import flags as _flags
+
+get_aval = jax.typeof
 
 __all__ = [
     "Severity",
@@ -114,7 +121,7 @@ class ConstAtom:
     def __init__(self, val):
         self.val = val
         try:
-            self.aval = jax.core.get_aval(val)
+            self.aval = get_aval(val)
         except Exception:  # non-array const (rare) — shapeless placeholder
             self.aval = None
 
@@ -212,7 +219,7 @@ def _sub_jaxprs(eqn):
 
 
 def _resolve(atom, env):
-    if isinstance(atom, jax.core.Literal):
+    if isinstance(atom, Literal):
         return atom
     return env.get(atom, atom)
 
@@ -311,7 +318,7 @@ def scalar_const(atom, producers, depth=6):
     simple constant arithmetic; None if it is not a compile-time scalar."""
     if depth <= 0:
         return None
-    if isinstance(atom, (jax.core.Literal, ConstAtom)):
+    if isinstance(atom, (Literal, ConstAtom)):
         try:
             arr = np.asarray(atom.val)
         except Exception:
@@ -409,10 +416,10 @@ class Context:
         used = set()
         for op in self.ops:
             for a in op.invars:
-                if isinstance(a, (jax.core.Var, CanonVar)):
+                if isinstance(a, (Var, CanonVar)):
                     used.add(a)
         for a in self.out_atoms:
-            if isinstance(a, (jax.core.Var, CanonVar)):
+            if isinstance(a, (Var, CanonVar)):
                 used.add(a)
         return used
 
@@ -590,7 +597,7 @@ def _context_of(target, feed_specs):
             raise ValueError(
                 "open jaxpr with constvars — pass the ClosedJaxpr instead"
             )
-        return jax.core.ClosedJaxpr(target, []), [], "jaxpr"
+        return ClosedJaxpr(target, []), [], "jaxpr"
 
     if isinstance(target, Program):
         return _trace_program(target, feed_specs)
@@ -695,8 +702,7 @@ def check_launch_budget(step_fn=None, *args, budget=None, counters=None,
     around a step. ``budget=None`` picks the budget from the counters: 1
     when whole-step capture replayed the step as one donated program
     (``FLAGS_eager_step_capture``), else 3 — the lazy-dispatch steady state
-    (fused forward + compiled-tape backward + fused optimizer —
-    PROFILE_EAGER.md)."""
+    (fused forward + compiled-tape backward + fused optimizer)."""
     if counters is None:
         if step_fn is None:
             raise ValueError("check_launch_budget needs a step_fn or counters")

@@ -66,6 +66,7 @@ import numpy as np
 
 from . import (
     CanonVar,
+    Literal,
     ConstAtom,
     Context,
     Diagnostic,
@@ -161,7 +162,7 @@ class CanonicalProgram:
             self.rewrites["literal_folds"] += 1
             self._memo[id(atom)] = sk
             return sk
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             k = f"lit:{atom_dtype(atom)}:{_val_digest(atom.val)}"
         elif isinstance(atom, ConstAtom):
             k = _val_digest(atom.val)
@@ -204,7 +205,7 @@ class CanonicalProgram:
     # -- divergence helpers ------------------------------------------------
     def producer(self, atom):
         """producers.get with unhashable-atom (Literal) guard."""
-        if isinstance(atom, (jax.core.Literal, ConstAtom)):
+        if isinstance(atom, (Literal, ConstAtom)):
             return None
         try:
             return self.producers.get(atom)
@@ -227,7 +228,7 @@ class CanonicalProgram:
         k = self._memo.get(id(atom), "")
         if k.startswith("in:"):
             return f"invar[{k[3:]}]"
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return f"literal {atom.val!r}"
         if isinstance(atom, ConstAtom):
             return f"const{list(atom_shape(atom))}:{atom_dtype(atom)}"

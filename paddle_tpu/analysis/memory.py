@@ -55,6 +55,7 @@ import numpy as np
 from ..core import flags as _flags
 from . import (
     Context,
+    Literal,
     Diagnostic,
     Severity,
     register_pass,
@@ -248,7 +249,7 @@ def _scope_peak(ops, scope, scope_peaks) -> int:
     intervals = []
     for i, op in enumerate(ops):
         for a in op.invars:
-            if isinstance(a, jax.core.Literal):
+            if isinstance(a, Literal):
                 continue
             last_use[id(a)] = i
             avals[id(a)] = _aval_nbytes(getattr(a, "aval", None))
@@ -303,11 +304,11 @@ def plan_memory(ctx: Context, donated: Optional[Sequence[int]] = None,
     last_use: Dict = {}
     for i, op in enumerate(top):
         for a in op.invars:
-            if not isinstance(a, jax.core.Literal):
+            if not isinstance(a, Literal):
                 last_use[a] = i
     out_set = set()
     for a in getattr(ctx, "out_atoms", ()):
-        if not isinstance(a, jax.core.Literal):
+        if not isinstance(a, Literal):
             try:
                 out_set.add(a)
             except TypeError:
@@ -370,7 +371,7 @@ def plan_memory(ctx: Context, donated: Optional[Sequence[int]] = None,
     # allocations per position, see MEMORY_PLAN.md)
     seen_outs = set()
     for pos, a in enumerate(getattr(ctx, "out_atoms", ())):
-        if isinstance(a, jax.core.Literal):
+        if isinstance(a, Literal):
             _mk("out-copy", f"output[{pos}]", getattr(a, "aval", None), n, n)
             continue
         fresh = a in produced and a not in seen_outs
@@ -409,14 +410,9 @@ def device_hbm_bytes() -> Optional[int]:
             return None  # uninitialized — don't init, don't cache
     except Exception:
         pass  # cannot tell — fall through and probe as before
-    val = None
-    try:
-        d = jax.devices()[0]
-        if getattr(d, "platform", "") in ("tpu", "gpu"):
-            stats = d.memory_stats() or {}
-            val = int(stats.get("bytes_limit") or 0) or None
-    except Exception:
-        val = None
+    d = jax.devices()[0]
+    # a TPU that reports no limit is an error, not a quiet "no budget"
+    val = int(d.memory_stats()["bytes_limit"]) if d.platform == "tpu" else None
     _hbm_cache[:] = [True, val]
     return val
 
@@ -797,24 +793,27 @@ def plan_block_pool(trace_thunk, *, block_bytes: int,
     letting XLA OOM mid-decode.
 
     Budget precedence: explicit ``budget_mb`` > FLAGS_memory_budget_mb > the
-    detected device HBM; with none of the three, ``num_blocks`` is None.
-    Tracing failures fall back to an overhead of 0 (budget // block_bytes)
-    rather than breaking engine construction."""
+    detected device HBM times FLAGS_fraction_of_gpu_memory_to_use; with none
+    of the three, ``num_blocks`` is None.
+    A failure to trace or plan the program propagates: a pool sized without
+    its program's overhead would claim memory the weights already hold."""
     if budget_mb is None:
         flagged = float(_flags.flag("memory_budget_mb"))
         budget_mb = flagged if flagged > 0 else None
     budget_bytes = int(budget_mb * _MB) if budget_mb is not None else None
     if budget_bytes is None:
+        # the device's reported limit, less the share the framework leaves
+        # free (FLAGS_fraction_of_gpu_memory_to_use): the trace below covers
+        # ONE program, and the prefill programs, XLA's own scratch and the
+        # caller's other arrays live on the same device
         hbm = device_hbm_bytes()
-        budget_bytes = int(hbm) if hbm else None
+        budget_bytes = int(
+            hbm * float(_flags.flag("fraction_of_gpu_memory_to_use"))
+        ) if hbm else None
 
-    peak = 0
-    try:
-        closed = trace_thunk()
-        ctx = Context(closed, list(roles), source, donated=tuple(donated))
-        peak = plan_memory(ctx, donated=tuple(donated)).peak_bytes
-    except Exception:
-        peak = int(pool_bytes_in_trace)
+    closed = trace_thunk()
+    ctx = Context(closed, list(roles), source, donated=tuple(donated))
+    peak = plan_memory(ctx, donated=tuple(donated)).peak_bytes
     overhead = max(0, int(peak) - int(pool_bytes_in_trace))
 
     num_blocks: Optional[int] = None

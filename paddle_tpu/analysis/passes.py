@@ -22,6 +22,9 @@ import numpy as np
 
 from . import (
     Context,
+    DropVar,
+    Literal,
+    Var,
     Diagnostic,
     Severity,
     atom_dtype,
@@ -173,16 +176,16 @@ def _eqn_label(eqn):
 
 
 def _dead_eqns(open_jaxpr, path, acc, index_base=0):
-    live = {v for v in open_jaxpr.outvars if isinstance(v, jax.core.Var)}
+    live = {v for v in open_jaxpr.outvars if isinstance(v, Var)}
     status = []
     for eqn in reversed(open_jaxpr.eqns):
         is_live = bool(getattr(eqn, "effects", None)) or any(
-            not isinstance(ov, jax.core.DropVar) and ov in live
+            not isinstance(ov, DropVar) and ov in live
             for ov in eqn.outvars
         )
         status.append((eqn, is_live))
         if is_live:
-            live.update(v for v in eqn.invars if isinstance(v, jax.core.Var))
+            live.update(v for v in eqn.invars if isinstance(v, Var))
     for i, (eqn, is_live) in enumerate(reversed(status)):
         here = f"{path}eqn[{i}]"
         if not is_live:
@@ -267,7 +270,7 @@ def _from_rng(atom, producers, depth=12):
             "bitcast_convert_type", "threefry2x32",
         ):
             return True
-        stack.extend(a for a in op.invars if not isinstance(a, jax.core.Literal))
+        stack.extend(a for a in op.invars if not isinstance(a, Literal))
     return False
 
 
@@ -360,13 +363,13 @@ def redundant_ops(ctx: Context) -> List[Diagnostic]:
                         hint="use F.log_softmax: one fused op, and it cannot "
                              "underflow to log(0) = -inf",
                     ))
-        elif op.name in ("psum", "psum2") and \
+        elif op.name in ("psum", "psum_invariant") and \
                 getattr(ctx, "mesh_axes", None) is None:
             # collective idioms on plain contexts; a mesh-scoped context
             # defers to resharding_lint (analysis.sharding) so the full
             # suite never reports one defect twice
             p = prod.get(op.invars[0]) if op.invars else None
-            if p is not None and p.name in ("psum", "psum2"):
+            if p is not None and p.name in ("psum", "psum_invariant"):
                 a0 = set(_coll_axis_names(op.params))
                 a1 = set(_coll_axis_names(p.params))
                 # psum(psum(x, 'a'), 'b') is the legitimate staged two-axis
@@ -683,7 +686,7 @@ _KEY_PLUMBING = {"random_wrap", "random_unwrap"}
 
 def _key_root(atom, producers, depth=8):
     while depth > 0:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return atom
         op = producers.get(atom)
         if op is None or op.name not in _KEY_PLUMBING or not op.invars:
@@ -697,7 +700,7 @@ def _index_root(atom, producers, depth=12):
     """Chase an index tensor through shape/convert plumbing to the value
     that actually carries the indices."""
     while depth > 0:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return atom
         op = producers.get(atom)
         if op is None or op.name not in _CHAIN_PASSTHROUGH or not op.invars:
@@ -711,7 +714,7 @@ def _indices_provably_unique(root, producers):
     """True when the index values cannot contain duplicates: an iota (or a
     compile-time constant whose values are distinct)."""
     op = producers.get(root) if not isinstance(
-        root, jax.core.Literal) else None
+        root, Literal) else None
     if op is not None and op.name == "iota":
         return True
     val = getattr(root, "val", None)  # Literal / ConstAtom
@@ -745,7 +748,7 @@ def determinism(ctx: Context) -> List[Diagnostic]:
     for op in ctx.ops:
         if op.name == "gather" and len(op.invars) >= 2:
             r = _index_root(op.invars[1], prod)
-            if not isinstance(r, jax.core.Literal):
+            if not isinstance(r, Literal):
                 gather_roots.add(id(r))
 
     key_users = {}
@@ -759,7 +762,7 @@ def determinism(ctx: Context) -> List[Diagnostic]:
             root = _index_root(op.invars[1], prod)
             if _indices_provably_unique(root, prod):
                 continue
-            if not isinstance(root, jax.core.Literal) \
+            if not isinstance(root, Literal) \
                     and id(root) in gather_roots:
                 continue  # autodiff gather transpose (see above)
             diags.append(Diagnostic(
@@ -774,7 +777,7 @@ def determinism(ctx: Context) -> List[Diagnostic]:
                 shapes=(atom_shape(op.outvars[0]),),
                 dtypes=(str(dt),),
             ))
-        elif op.name in ("psum", "psum2"):
+        elif op.name in ("psum", "psum_invariant"):
             dt = atom_dtype(op.outvars[0]) if op.outvars else None
             if not _is_float(dt):
                 continue
@@ -799,7 +802,7 @@ def determinism(ctx: Context) -> List[Diagnostic]:
                 ))
         elif op.name in _RNG_CONSUMERS and op.invars:
             k = _key_root(op.invars[0], prod)
-            if not isinstance(k, jax.core.Literal):
+            if not isinstance(k, Literal):
                 key_users.setdefault(id(k), []).append(op)
         elif op.name in _CALLBACK_PRIMS:
             diags.append(Diagnostic(

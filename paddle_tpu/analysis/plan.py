@@ -17,9 +17,8 @@ in their own ``jax.checkpoint``. Each marked stage then keeps only its
 boundary values live; its interior is recomputed immediately before that
 stage's backward and freed after. Unmarked stages keep their residuals
 saved and pay zero recompute — which is how a plan beats the uniform
-per-block checkpoint configuration's flat 4/3 recompute tax
-(PROFILE_GPT.md): it only recomputes the slices that actually hold the
-peak up.
+per-block checkpoint configuration's flat 4/3 recompute tax: it only
+recomputes the slices that actually hold the peak up.
 
 The planner works at the granularity of the loss jaxpr's top-level
 equations (one per framework op — each is a pjit-wrapped fused region),
@@ -47,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import ClosedJaxpr, Jaxpr, Literal, Var, eval_jaxpr
+
 __all__ = [
     "RematPlan",
     "build_remat_plan",
@@ -69,11 +70,21 @@ def _aval_bytes(aval) -> int:
 def _eqn_out_bytes(eqn) -> int:
     return sum(
         _aval_bytes(v.aval) for v in eqn.outvars
-        if type(v) is jax.core.Var
+        if type(v) is Var
     )
 
 
-def _eqn_flops(eqn) -> int:
+def _slice_jaxpr(parent, invars, outvars, eqns):
+    """Some of ``parent``'s equations as a jaxpr of their own, with the
+    parent's debug info re-sized to the slice (jax wants one on every
+    Jaxpr)."""
+    dbg = parent.debug_info._replace(
+        arg_names=tuple(f"in{i}" for i in range(len(invars))),
+        result_paths=tuple(f"out{i}" for i in range(len(outvars))))
+    return Jaxpr((), invars, outvars, eqns, debug_info=dbg)
+
+
+def _eqn_flops(eqn, parent) -> int:
     """Recompute cost of one top-level equation, via the attribution
     registry's flop model over its inlined flat ops (sees through the
     pjit wrapper — same estimates program_costs caches)."""
@@ -82,13 +93,13 @@ def _eqn_flops(eqn) -> int:
 
     invars, seen = [], set()
     for a in eqn.invars:
-        if isinstance(a, jax.core.Var) and id(a) not in seen:
+        if isinstance(a, Var) and id(a) not in seen:
             seen.add(id(a))
             invars.append(a)
-    outvars = [v for v in eqn.outvars if type(v) is jax.core.Var]
-    mini = jax.core.Jaxpr((), invars, outvars, [eqn])
+    outvars = [v for v in eqn.outvars if type(v) is Var]
+    mini = _slice_jaxpr(parent, invars, outvars, [eqn])
     try:
-        ops, _producers, _outs = _inline_ops(jax.core.ClosedJaxpr(mini, []))
+        ops, _producers, _outs = _inline_ops(ClosedJaxpr(mini, []))
         return sum(_op_flops(op) for op in ops)
     except Exception:
         return sum(_aval_bytes(v.aval) for v in outvars)
@@ -108,11 +119,11 @@ def sliced_callable(closed, stages: Sequence[Tuple[int, int, bool]]):
     other segmentation: the same equations run in the same order)."""
     jx = closed.jaxpr
     consts = list(closed.consts)
-    outvar_set = {v for v in jx.outvars if isinstance(v, jax.core.Var)}
+    outvar_set = {v for v in jx.outvars if isinstance(v, Var)}
     last_use: Dict[Any, int] = {}
     for i, eqn in enumerate(jx.eqns):
         for a in eqn.invars:
-            if isinstance(a, jax.core.Var):
+            if isinstance(a, Var):
                 last_use[a] = i
 
     prepared = []
@@ -124,20 +135,20 @@ def sliced_callable(closed, stages: Sequence[Tuple[int, int, bool]]):
         ins, seen = [], set()
         for eqn in eqns:
             for a in eqn.invars:
-                if (isinstance(a, jax.core.Var) and a not in produced
+                if (isinstance(a, Var) and a not in produced
                         and a not in seen):
                     seen.add(a)
                     ins.append(a)
         outs = []
         for eqn in eqns:
             for v in eqn.outvars:
-                if type(v) is jax.core.Var and (
+                if type(v) is Var and (
                         last_use.get(v, -1) >= end or v in outvar_set):
                     outs.append(v)
-        sub = jax.core.Jaxpr((), ins, outs, eqns)
+        sub = _slice_jaxpr(jx, ins, outs, eqns)
 
         def run_stage(vals, _sub=sub):
-            return jax.core.eval_jaxpr(_sub, (), *vals)
+            return eval_jaxpr(_sub, (), *vals)
 
         if remat:
             run_stage = jax.checkpoint(run_stage)
@@ -154,7 +165,7 @@ def sliced_callable(closed, stages: Sequence[Tuple[int, int, bool]]):
             for v, val in zip(outs, vals):
                 env[v] = val
         return [
-            a.val if isinstance(a, jax.core.Literal) else env[a]
+            a.val if isinstance(a, Literal) else env[a]
             for a in jx.outvars
         ]
 
@@ -332,7 +343,7 @@ def build_remat_plan(loss_closed, *, budget_bytes: int, measure: Callable,
     peak_before = int(measure(None))
     evals = 1
 
-    flops = [_eqn_flops(e) for e in jx.eqns]
+    flops = [_eqn_flops(e, jx) for e in jx.eqns]
     out_bytes = [_eqn_out_bytes(e) for e in jx.eqns]
     full_flops = sum(flops)
 
@@ -362,7 +373,8 @@ def build_remat_plan(loss_closed, *, budget_bytes: int, measure: Callable,
         if k > n:
             break
         bounds = _byte_balanced_bounds(out_bytes, k)
-        for m in sorted({max(1, k // 2), k - 1, k}):
+        k = len(bounds) - 1  # fewer chunks when the tail eqns hold the bytes
+        for m in sorted({max(1, k // 2), max(1, k - 1), k}):
             stages = [
                 (bounds[i], bounds[i + 1], i < m) for i in range(k)
             ]
@@ -437,7 +449,7 @@ def cold_state_indices(closed, roles) -> List[Tuple[int, str]]:
     last_read: Dict[Any, int] = {}
     for i, eqn in enumerate(jx.eqns):
         for a in eqn.invars:
-            if isinstance(a, jax.core.Var):
+            if isinstance(a, Var):
                 first_read.setdefault(a, i)
                 last_read[a] = i
     n = max(1, len(jx.eqns))

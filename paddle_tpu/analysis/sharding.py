@@ -60,6 +60,9 @@ import numpy as np
 from ..core import flags as _flags
 from . import (
     CanonVar,
+    Literal,
+    ShapedArray,
+    Var,
     ConstAtom,
     Context,
     Diagnostic,
@@ -87,16 +90,18 @@ __all__ = [
     "sharded_step_context",
 ]
 
-# primitives that move bytes between devices; psum2/pbroadcast appear under
-# shard_map's check_rep rewrite, the rest are the explicit lax collectives
+# primitives that move bytes between devices; the *_invariant forms appear
+# under shard_map's check_vma typing, the rest are the explicit lax collectives
 _COLLECTIVE_PRIMS = {
-    "psum", "psum2", "pmax", "pmin", "all_gather", "reduce_scatter",
-    "all_to_all", "ppermute", "pbroadcast",
+    "psum", "psum_invariant", "pmax", "pmin", "all_gather",
+    "all_gather_invariant", "reduce_scatter", "all_to_all", "ppermute",
+    "pbroadcast",
 }
 # cost-model kind per primitive (pmax/pmin are all-reduces on the wire)
 _COLL_KIND = {
-    "psum": "psum", "psum2": "psum", "pmax": "psum", "pmin": "psum",
-    "all_gather": "all_gather", "reduce_scatter": "reduce_scatter",
+    "psum": "psum", "psum_invariant": "psum", "pmax": "psum", "pmin": "psum",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "reduce_scatter": "reduce_scatter",
     "all_to_all": "all_to_all", "ppermute": "ppermute",
     "pbroadcast": "pbroadcast",
 }
@@ -253,7 +258,7 @@ def _shard_aval(aval, spec, axes):
     try:
         return aval.update(shape=tuple(new))
     except Exception:
-        return jax.core.ShapedArray(tuple(new), aval.dtype)
+        return ShapedArray(tuple(new), aval.dtype)
 
 
 def _aval_nbytes(aval) -> int:
@@ -410,7 +415,7 @@ class _ShardInliner:
             if (outer_aval is not None and iv_aval is not None
                     and tuple(getattr(outer_aval, "shape", ())) ==
                     tuple(getattr(iv_aval, "shape", ()))
-                    and not isinstance(outer, jax.core.Literal)):
+                    and not isinstance(outer, Literal)):
                 # per-shard shapes agree: the body reads the caller's buffer
                 # in place — substitute (sound: fresh ShardVars upstream)
                 ienv[iv] = outer
@@ -434,7 +439,7 @@ class _ShardInliner:
             if (inner_aval is not None and tuple(
                     getattr(inner_aval, "shape", ())) ==
                     tuple(getattr(per_shard, "shape", ()))
-                    and not isinstance(inner, jax.core.Literal)):
+                    and not isinstance(inner, Literal)):
                 if isinstance(inner, ShardVar):
                     inner.spec = spec
                     inner.explicit = True
@@ -512,7 +517,7 @@ class _ShardInliner:
         if name in _COLLECTIVE_PRIMS:
             payload = sum(_aval_nbytes(getattr(a, "aval", None))
                           for a in ins
-                          if not isinstance(a, jax.core.Literal))
+                          if not isinstance(a, Literal))
             self._record(_COLL_KIND[name], op.path, _coll_axes(eqn.params),
                          payload, count=mult,
                          shape=tuple(getattr(
@@ -616,6 +621,16 @@ class _ShardInliner:
                 for d in range(len(g_in))
             )
             return [out]
+
+        if name == "split":
+            # jnp.split's own primitive: every piece spans the other dims
+            # whole, so only the split axis loses its sharding (slice rule)
+            g_in = tuple(getattr(eqn.invars[0].aval, "shape", ()))
+            axis = int(eqn.params.get("axis", 0))
+            in_spec = _spec_of(ins[0], len(g_in))
+            piece = tuple(() if d == axis else in_spec[d]
+                          for d in range(len(g_in)))
+            return [piece] * n_out
 
         if name == "dynamic_slice":
             g_in = tuple(getattr(eqn.invars[0].aval, "shape", ()))
@@ -921,12 +936,10 @@ def pipelined_step_context(step, batch_specs, *, memory_budget_mb=None,
     per-shard avals, every param/state position donated
     (``donate_argnums=(0, 1, 2, 3)``).
 
-    Under jax<0.5 the full step cannot be traced — an upstream shard_map
-    autodiff bug drops the rank of scalar residuals under partial-eval
-    (see ``_jax_compat`` / the ``needs_shardmap_grad`` skips) — so the
-    context falls back to the forward GPipe loss program: the identical
-    shard_map schedule with the identical ppermute/psum collectives, minus
-    the optimizer tail (and hence with nothing donated)."""
+    If the full step cannot be traced the context falls back to the forward
+    GPipe loss program: the identical shard_map schedule with the identical
+    ppermute/psum collectives, minus the optimizer tail (and hence with
+    nothing donated)."""
     import jax.numpy as jnp
 
     mesh = step.mesh
@@ -961,8 +974,8 @@ def pipelined_step_context(step, batch_specs, *, memory_budget_mb=None,
                 repl_sds, stacked_sds, rs_sds, ss_sds, b_sds, key_sds,
                 lr_sds, *batch_sds)
         except Exception:
-            # jax<0.5 shard_map autodiff bug — trace the forward loss
-            # program instead (same collectives, no optimizer tail)
+            # trace the forward loss program instead (same collectives, no
+            # optimizer tail)
             full_step = False
             closed = jax.make_jaxpr(step._loss_program)(
                 repl_sds, stacked_sds, b_sds, key_sds, *batch_sds)
@@ -1102,7 +1115,7 @@ def collective_records(ctx) -> List[CollectiveOp]:
         n = _shard_factor(names, axes)
         payload = sum(_aval_nbytes(getattr(a, "aval", None))
                       for a in op.invars
-                      if not isinstance(a, jax.core.Literal))
+                      if not isinstance(a, Literal))
         kind = _COLL_KIND[op.name]
         first = getattr(op.invars[0], "aval", None) if op.invars else None
         out.append(CollectiveOp(
@@ -1211,7 +1224,7 @@ def _scan_hoist_findings(open_jaxpr, path, acc):
             nc = int(eqn.params.get("num_consts", 0))
             pure = set(body.invars[:nc])
             for bi, be in enumerate(body.eqns):
-                ins = [v for v in be.invars if isinstance(v, jax.core.Var)]
+                ins = [v for v in be.invars if isinstance(v, Var)]
                 if ins and all(v in pure for v in ins):
                     if be.primitive.name in _COLLECTIVE_PRIMS:
                         acc.append((
@@ -1245,9 +1258,9 @@ def resharding_lint(ctx: Context) -> List[Diagnostic]:
         # redundant_ops pass defers when ctx.mesh_axes is set) so the full
         # suite never double-reports one defect
         for op in ctx.ops:
-            if op.name in ("psum", "psum2"):
+            if op.name in ("psum", "psum_invariant"):
                 p = prod.get(op.invars[0]) if op.invars else None
-                if p is not None and p.name in ("psum", "psum2") and \
+                if p is not None and p.name in ("psum", "psum_invariant") and \
                         set(_coll_axes(op.params)) == \
                         set(_coll_axes(p.params)):
                     diags.append(Diagnostic(
@@ -1347,7 +1360,7 @@ def schedule_of(ops) -> List[Dict[str, Any]]:
         names = _coll_axes(op.params)
         payload = sum(_aval_nbytes(getattr(a, "aval", None))
                       for a in op.invars
-                      if not isinstance(a, jax.core.Literal))
+                      if not isinstance(a, Literal))
         out.append({
             "kind": _COLL_KIND[op.name],
             "op": op.name,
@@ -1387,7 +1400,7 @@ def _rank_varying(atom, producers, depth=64) -> bool:
     while stack and steps < depth:
         a = stack.pop()
         steps += 1
-        if isinstance(a, jax.core.Literal):
+        if isinstance(a, Literal):
             continue
         try:
             op = producers.get(a)
@@ -1430,7 +1443,7 @@ def collective_schedule(ctx) -> List[Diagnostic]:
                        for b in bodies):
                 continue
             if any(_rank_varying(a, prod) for a in op.invars
-                   if not isinstance(a, jax.core.Literal)):
+                   if not isinstance(a, Literal)):
                 diags.append(Diagnostic(
                     Severity.ERROR, "collective_schedule", op.path,
                     "collective inside a while loop whose carry derives "

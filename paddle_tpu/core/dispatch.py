@@ -138,7 +138,7 @@ def _note_op_program(name, fn, kw_items, vals, t0):
 # accounting, and compile-cache hit/miss/eviction counts. Readable via
 # paddle_tpu.profiler.dispatch_counters(). Program counts are one per
 # dispatched call (op / segment flush / backward sweep / fused optimizer
-# update) — the unit PROFILE_EAGER.md's relay-turnaround arithmetic uses.
+# update) — the unit the programs-per-step arithmetic uses.
 # ---------------------------------------------------------------------------
 _counters: Dict[str, Any] = {}
 # serializes reset against off-thread counter updates (the background

@@ -711,6 +711,11 @@ define_flag("init_allocated_mem", False, "compat: poison fresh allocations")
 define_flag(
     "allocator_strategy", "auto_growth", "compat: allocator strategy name (XLA owns HBM)"
 )
-define_flag("fraction_of_gpu_memory_to_use", 0.92, "compat alias; XLA preallocation")
+define_flag(
+    "fraction_of_gpu_memory_to_use", 0.92,
+    "share of the device's reported memory limit a planner may budget when "
+    "no explicit budget is given (analysis.memory.plan_block_pool sizes the "
+    "serving KV pool against it); the rest stays free for other programs",
+)
 define_flag("cudnn_deterministic", False, "compat: deterministic kernels")
 define_flag("embedding_deterministic", 0, "compat: deterministic embedding grad")

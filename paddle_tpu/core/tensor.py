@@ -38,10 +38,7 @@ def _commit(value, place: Optional[Place]):
     if place is None:
         return value
     if isinstance(value, jax.Array) and not isinstance(value, jax.core.Tracer):
-        try:
-            return jax.device_put(value, place.jax_device)
-        except Exception:
-            return value
+        return jax.device_put(value, place.jax_device)
     return value
 
 

@@ -31,8 +31,10 @@ __all__ = ["DeviceSpec", "ClusterSpec", "ModelDesc", "Candidate", "Plan",
 @dataclass
 class DeviceSpec:
     """One accelerator (reference: cluster.py Device — dp_gflops/memory).
-    Defaults are TPU v5e-class, matching the measured numbers committed in
-    PROFILE_RESNET.md (practical bf16 throughput ≈135 TF/s of the 197 peak)."""
+    Defaults are TPU v5e's published peaks; ``mxu_efficiency`` is a builder's
+    estimate, not a ledger number. ``plan()`` applies them to whatever
+    ``jax.devices()`` returns — ROADMAP S0's peaks table (keyed by
+    ``device_kind``, unknown kind an error) is to own these."""
 
     flops_bf16: float = 197e12          # peak MXU throughput, bytes/s
     mxu_efficiency: float = 0.68        # practical fraction at healthy tiles

@@ -13,7 +13,7 @@ online-softmax streaming kernel (Flash-Attention-2 style) tiled to the MXU:
 
 Causal masking skips fully-masked tiles via predication. Accumulation is
 always f32 regardless of input dtype (bf16 in → bf16 out, f32 math).
-On CPU (tests/dev) the kernel runs in interpret mode automatically.
+Off the TPU (tests/dev on CPU) the kernel runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -30,30 +30,28 @@ NEG_INF = np.float32(-1e30)
 
 
 def _default_block_q(seq_len: int) -> int:
-    """Measured on v5e (PROFILE_LONGSEQ.md block sweep): bq=1024 beats 512
-    by ~3.4% at seq 4096 (27.9k vs 27.0k tok/s on the 345M unrolled step,
-    and compiles FASTER — 42s vs 54s); 512 only wins past 4k where Mosaic
-    compile time for the wider grid grows. Seqs in (2048, 4096] that
-    1024 does not divide (2560, 3584...) keep 512 — the wider default
-    must never SHRINK the eligible set. Shared by flash_attention and
-    supports() so eligibility always mirrors the kernel."""
+    """bq=1024 up to seq 4096, 512 past it (a builder's block sweep from
+    before PR 1, not in the ledger; re-measure when the benchmark has the
+    s4096 cell). Seqs in (2048, 4096] that 1024 does not divide (2560,
+    3584...) keep 512 — the wider default must never SHRINK the eligible
+    set. Shared by flash_attention and supports() so eligibility always
+    mirrors the kernel."""
     if seq_len <= 2048:
         return 1024
     if seq_len <= 4096 and seq_len % 1024 == 0:
         return 1024
     return 512
+
+
 _0 = np.int32(0)  # index-map literal; Python ints trace to i64 under x64
 
 
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    return jax.default_backend() != "tpu"
 
 
 def _compiler_params(dims):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dims)
-    except Exception:
-        return None
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 def _causal_mask(s, j, kk, bq, bk):

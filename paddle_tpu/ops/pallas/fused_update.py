@@ -1,7 +1,7 @@
 """Fused multi-tensor optimizer update as a Pallas TPU kernel.
 
-PROFILE_GPT.md's breakdown puts the residual per-step device time after the
-matmuls in the long elementwise tail of the optimizer update: for Adam, XLA
+The optimizer update is a long elementwise tail after the matmuls (its share
+of the step on the chip is not measured yet): for Adam, XLA
 lowers each parameter's update to a chain of ~10 elementwise HLOs whose
 fusion still walks the parameter, gradient, and both moment buffers several
 times. This kernel (FLAGS_pallas_fused_update) runs each parameter's WHOLE
@@ -38,6 +38,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +48,7 @@ __all__ = ["enabled", "rule_kind", "supported", "param_update"]
 
 _LANES = 128
 _MIN_ROWS = 8  # f32 sublane tile
+_0 = np.int32(0)  # index-map literal; Python ints trace to i64 under x64
 
 
 def enabled() -> bool:
@@ -54,10 +56,7 @@ def enabled() -> bool:
         return False
     if flags.flag("pallas_update_interpret"):
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:
@@ -173,9 +172,9 @@ def _call(kernel, scalars, bufs, n_out, interpret):
     br = _block_rows(rows)
     grid = (rows // br,)
     tiled = [b.reshape(rows, _LANES) for b in bufs]
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
+    scalar_spec = pl.BlockSpec((1, 1), lambda i: (_0, _0),
                                memory_space=pltpu.SMEM)
-    buf_spec = pl.BlockSpec((br, _LANES), lambda i: (i, 0),
+    buf_spec = pl.BlockSpec((br, _LANES), lambda i: (i, _0),
                             memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,

@@ -24,7 +24,7 @@ import jax
 
 # dispatch-level counters: device-program launches by category, lazy-segment
 # flush reasons, compile-cache hit/miss/eviction counts (core/dispatch.py).
-# The programs-per-step arithmetic in PROFILE_EAGER.md reads these.
+# The programs-per-step arithmetic reads these.
 from ..core.dispatch import (  # noqa: F401
     dispatch_counters,
     reset_dispatch_counters,
@@ -406,7 +406,7 @@ def measure_programs(step_fn, *args, warmup: int = 2, **kwargs):
     the counters, runs one measured call, flushes again so trailing lazy ops
     are charged to the step, and returns the counter dict — including the
     capture hit/fallback/eviction counters and a `_capture_state` snapshot.
-    This is the measurement the PROFILE_EAGER.md programs-per-step
+    This is the measurement the programs-per-step
     arithmetic — and the analysis launch-budget pass — is defined over."""
     from ..core import lazy
 

@@ -4,7 +4,7 @@ Reference analogue: the slim stack's quantized inference ops
 (quantize/dequantize + int8 conv/mul kernels dispatched by the analysis
 passes). TPU-native: `lax.dot_general` on int8 operands with an int32
 accumulator — exactly the MXU's 8-bit mode (the chip's int8 throughput is
-~2x its bf16 FLOPs; PROFILE_RESNET.md measured 161 TOP/s) — then a float
+~2x its bf16 FLOPs) — then a float
 dequant fused in by XLA.
 """
 from __future__ import annotations

@@ -81,7 +81,7 @@ def is_transient(e: BaseException) -> bool:
         return True
     if type(e).__name__ in _TRANSIENT_TYPE_NAMES:
         # PJRT runtime errors surface infra failures (device preempted,
-        # relay dropped); compile-time program errors raise python types
+        # connection dropped); compile-time program errors raise python types
         # handled above, so a runtime-status error here is worth one retry
         return True
     return any(m in str(e) for m in _TRANSIENT_MARKERS)

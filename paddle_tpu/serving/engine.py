@@ -46,8 +46,10 @@ Overload robustness (ISSUE 11) wraps that loop in three layers:
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import signal as _signal
+import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence as Seq
@@ -133,6 +135,22 @@ class ServingConfig:
     max_positions: Optional[int] = None
 
 
+_WEIGHT_BIND_LOCK = threading.RLock()
+
+
+@contextlib.contextmanager
+def _bound_weights(weights, w_vals):
+    """Run a model over ``w_vals`` (tracers while a program is being traced).
+    Rebinding mutates the shared model, so it is serialized: two engines may
+    serve one model from two threads. Replays of a compiled program never
+    come here. Module-level on purpose: the step functions live in the
+    serve-program cache and must not keep an Engine (and its pool) alive."""
+    from ..jit import _bind_values
+
+    with _WEIGHT_BIND_LOCK, _bind_values(weights, w_vals), no_grad():
+        yield
+
+
 class Engine:
     """Continuous-batching serving runtime over one generative model.
 
@@ -173,6 +191,13 @@ class Engine:
         )
         scratch = self._buckets.max_decode_batch
 
+        # the model's weights enter every serving program as an ARGUMENT
+        # (position 2, after the donated pools): a closed-over array is
+        # baked into the HLO as a constant, so each prefill bucket and
+        # decode signature would carry its own copy of the model in device
+        # memory
+        self._weights = list(model.parameters()) + [
+            b for _, b in model.named_buffers()]
         self._decode_fn = self._make_decode_fn()
         self._prefill_fn = self._make_prefill_fn()
 
@@ -275,10 +300,15 @@ class Engine:
     # ------------------------------------------------------------------
     # step functions (shared by all three execution tiers)
     # ------------------------------------------------------------------
+    def _weight_vals(self):
+        with _WEIGHT_BIND_LOCK:  # never read while another trace has them bound
+            return tuple(t._value for t in self._weights)
+
     def _make_decode_fn(self) -> Callable:
         model, layers, bs = self._model, self._layers, self._block_size
+        weights = self._weights
 
-        def decode_fn(k_pools, v_pools, tables, lens, tokens):
+        def decode_fn(k_pools, v_pools, w_vals, tables, lens, tokens):
             from ..core.dispatch import apply as _apply
             from ..core.tensor import Tensor
 
@@ -286,7 +316,7 @@ class Engine:
             views = [PagedCacheView(st, i, bs) for i in range(layers)]
             ids = Tensor(tokens.astype(jnp.int64)[:, None], stop_gradient=True)
             pos = Tensor(lens, stop_gradient=True)
-            with no_grad():
+            with _bound_weights(weights, w_vals):
                 logits = model(ids, caches=views, pos_offset=pos)
             row, nxt = _apply(_decode_pick, logits, op_name="serve_decode_pick")
             return (
@@ -299,15 +329,16 @@ class Engine:
 
     def _make_prefill_fn(self) -> Callable:
         model, layers, bs = self._model, self._layers, self._block_size
+        weights = self._weights
 
-        def prefill_fn(k_pools, v_pools, tables, ids, plen):
+        def prefill_fn(k_pools, v_pools, w_vals, tables, ids, plen):
             from ..core.dispatch import apply as _apply
             from ..core.tensor import Tensor
 
             lens = jnp.zeros((ids.shape[0],), jnp.int32)
             st = _BatchState(k_pools, v_pools, tables, lens, prefill=True)
             views = [PagedCacheView(st, i, bs) for i in range(layers)]
-            with no_grad():
+            with _bound_weights(weights, w_vals):
                 logits = model(Tensor(ids, stop_gradient=True),
                                caches=views, pos_offset=0)
             row, nxt = _apply(_prefill_pick, logits, plen,
@@ -338,11 +369,16 @@ class Engine:
         pshape = (n_total, self._block_size, heads, head_dim)
         pool_spec = jax.ShapeDtypeStruct(pshape, np.dtype(dtype))
         k_specs = tuple(pool_spec for _ in range(self._layers))
+        w_specs = tuple(
+            jax.ShapeDtypeStruct(tuple(v.shape), v.dtype)
+            for v in self._weight_vals())
         t_spec = jax.ShapeDtypeStruct((B, nblk), np.int32)
         l_spec = jax.ShapeDtypeStruct((B,), np.int32)
         roles = (
             [("buffer", f"k_pool{i}") for i in range(self._layers)]
             + [("buffer", f"v_pool{i}") for i in range(self._layers)]
+            + [("param", getattr(t, "name", "") or f"weight{i}")
+               for i, t in enumerate(self._weights)]
             + [("feed", "block_tables"), ("feed", "seq_lens"),
                ("feed", "tokens")]
         )
@@ -352,7 +388,7 @@ class Engine:
         )
         return _mem.plan_block_pool(
             lambda: jax.make_jaxpr(self._decode_fn)(
-                k_specs, k_specs, t_spec, l_spec, l_spec),
+                k_specs, k_specs, w_specs, t_spec, l_spec, l_spec),
             block_bytes=block_bytes,
             pool_bytes_in_trace=pool_bytes_in_trace,
             budget_mb=budget_mb,
@@ -1011,7 +1047,7 @@ class Engine:
         padded = self._buckets.pad_prompt(req.prompt)
         P = int(padded.shape[-1])
         args = (
-            tuple(self._pool.k), tuple(self._pool.v),
+            tuple(self._pool.k), tuple(self._pool.v), self._weight_vals(),
             jnp.asarray(np.asarray([seq.table_row()], np.int32)),
             jnp.asarray(padded[None, :].astype(np.int64)),
             jnp.asarray(np.asarray([plen], np.int32)),
@@ -1071,7 +1107,7 @@ class Engine:
             lens.append(0)
             toks.append(0)
         args = (
-            tuple(self._pool.k), tuple(self._pool.v),
+            tuple(self._pool.k), tuple(self._pool.v), self._weight_vals(),
             jnp.asarray(np.asarray(rows, np.int32)),
             jnp.asarray(np.asarray(lens, np.int32)),
             jnp.asarray(np.asarray(toks, np.int32)),

@@ -42,7 +42,7 @@ def test_dtype_pass_flags_silent_float64_upcast():
     def f(x):
         return jnp.asarray(x, jnp.float64) * 2.0  # injected f32 -> f64
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         diags = analysis.check(f, [((4,), "float32")])
     found = hits(diags, "dtype_check", Severity.ERROR, "float64")
     assert found, diags
@@ -231,7 +231,7 @@ def test_launch_budget_measures_live_step():
         return float(loss)
 
     diags = analysis.check_launch_budget(step, budget=3)
-    # per-op dispatch blows the 3-program budget (PROFILE_EAGER.md: ~13)
+    # per-op dispatch blows the 3-program budget (~13 programs)
     assert hits(diags, "launch_budget", Severity.WARNING), diags
 
 

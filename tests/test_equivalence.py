@@ -34,7 +34,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -334,7 +334,7 @@ def test_collective_under_rank_variant_cond_is_an_error():
                                 lambda u: u, v)
 
         return shard_map(body, mesh=mesh, in_specs=P("dp", "mp"),
-                         out_specs=P("dp", "mp"), check_rep=False)(x)
+                         out_specs=P("dp", "mp"), check_vma=False)(x)
 
     d = _diags_of(rank_variant, jax.ShapeDtypeStruct((4, 4), F32),
                   passes=["collective_schedule"])
@@ -353,7 +353,7 @@ def test_collective_under_rank_invariant_cond_is_silent():
                                 lambda u: u, v)
 
         return shard_map(body, mesh=mesh, in_specs=(P("dp", "mp"), P()),
-                         out_specs=P("dp", "mp"), check_rep=False)(x, n)
+                         out_specs=P("dp", "mp"), check_vma=False)(x, n)
 
     assert _diags_of(rank_invariant, jax.ShapeDtypeStruct((4, 4), F32),
                      jax.ShapeDtypeStruct((), jnp.int32),
@@ -368,7 +368,7 @@ def test_schedule_of_orders_the_collective_schedule():
             return jax.lax.all_gather(jax.lax.psum(v, "mp"), "dp", tiled=True)
 
         return shard_map(body, mesh=mesh, in_specs=P("dp", "mp"),
-                         out_specs=P(None, "mp"), check_rep=False)(x)
+                         out_specs=P(None, "mp"), check_vma=False)(x)
 
     closed = jax.make_jaxpr(two_colls)(jax.ShapeDtypeStruct((4, 4), F32))
     ctx = A.Context(closed, [("arg", "a0")], "probe")

@@ -327,7 +327,7 @@ def test_estimate_matches_measured_lazy_segment(lazy_capture_mode):
     lazy.flush_if_pending("test")
 
     input_bytes = sum(int(v.nbytes) for v in ext)
-    fn = jax.jit(jax.core.jaxpr_as_fun(closed))
+    fn = jax.jit(jax.extend.core.jaxpr_as_fun(closed))
     base = live_bytes()
     outs = jax.tree_util.tree_leaves(fn(*ext))
     measured = input_bytes + (live_bytes() - base)

@@ -21,21 +21,6 @@ from paddle_tpu.models import GPTConfig, GPTForPretraining, GPTPretrainingCriter
 M = 4  # microbatches
 VOCAB, HID, LAYERS, HEADS, SEQ = 128, 32, 4, 4, 16
 
-# The PP trainer differentiates THROUGH shard_map. jax 0.4.x's
-# experimental shard_map has an upstream partial-eval bug there: scalar
-# residuals forwarded between the known/unknown jaxprs keep a
-# fully-sharded name map on a rank-0 aval (_SpecError at the transpose),
-# fixed with the 0.5 shard_map rewrite. The schedule/forward tests below
-# still run; only grad-through-shard_map trainer tests are gated.
-_JAX_SHARD_MAP_GRAD_OK = tuple(
-    int(x) for x in jax.__version__.split(".")[:2]
-) >= (0, 5)
-needs_shardmap_grad = pytest.mark.skipif(
-    not _JAX_SHARD_MAP_GRAD_OK,
-    reason="upstream jax<0.5 shard_map autodiff bug: scalar residuals "
-           "lose their rank under partial-eval (see _jax_compat)",
-)
-
 
 def _make(seed, lr=1e-3, wd=0.01):
     paddle.seed(seed)
@@ -86,7 +71,6 @@ def _fleet_pp(dp, mp, pp, stage=0):
     return strategy
 
 
-@needs_shardmap_grad
 def test_pp4_matches_single_device():
     X = _batch()
     ref = _reference_losses(X)
@@ -102,7 +86,6 @@ def test_pp4_matches_single_device():
     np.testing.assert_allclose(ref, got, rtol=3e-4)
 
 
-@needs_shardmap_grad
 def test_pp_composes_with_tp_and_dp():
     X = _batch()
     ref = _reference_losses(X)
@@ -126,7 +109,6 @@ def test_pp_composes_with_tp_and_dp():
     assert "mp" in str(qkv.sharding.spec)
 
 
-@needs_shardmap_grad
 def test_pipeline_layer_train_batch_runs_schedule():
     """PipelineLayer + PipelineParallel.train_batch drive the compiled
     schedule (reference API: model.train_batch(data, opt))."""
@@ -162,7 +144,6 @@ def test_pipeline_layer_train_batch_runs_schedule():
     np.testing.assert_allclose(ref, got, rtol=3e-4)
 
 
-@needs_shardmap_grad
 def test_pp_with_zero_sharding():
     X = _batch()
     ref = _reference_losses(X)
@@ -188,7 +169,6 @@ def test_pp_with_zero_sharding():
     np.testing.assert_allclose(ref, got, rtol=3e-4)
 
 
-@needs_shardmap_grad
 def test_pp_grad_clip_and_state_sync():
     """Clipping applies under pp (parity with ShardedTrainStep), and
     state_dict on model/optimizer lazily pulls the stacked values."""
@@ -240,7 +220,6 @@ def test_pp_grad_clip_and_state_sync():
     assert any(k.endswith(".exp_avg") or ".moment" in k for k in osd)
 
 
-@needs_shardmap_grad
 def test_pp_checkpoint_resume_uses_restored_moments():
     """set_state_dict → pipelined step must start from the restored Adam
     moments, not zeros (same continuation as the single-device run)."""
@@ -286,7 +265,6 @@ def test_pp_checkpoint_resume_uses_restored_moments():
     np.testing.assert_allclose(ref, got, rtol=3e-3, atol=1e-4)
 
 
-@needs_shardmap_grad
 def test_pp_per_token_loss_fn_mean_reduced():
     """A loss_fn returning per-token losses works under pp (parity with the
     pp==1 fallback's loss.mean())."""

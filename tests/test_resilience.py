@@ -186,7 +186,7 @@ def test_retry_unsafe_skips_in_place_retry():
 
     def real_transient_thunk():
         calls.append(1)
-        raise RuntimeError("UNAVAILABLE: relay dropped mid-execute")
+        raise RuntimeError("UNAVAILABLE: connection dropped mid-execute")
 
     with pytest.raises(RuntimeError):
         runtime.execute("captured", real_transient_thunk, retry_unsafe=True)

@@ -644,3 +644,83 @@ def test_serve_probe_cli():
     )
     assert out.returncode == 0, out.stdout + out.stderr
     assert "ALL SCENARIOS PASSED" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# weights are ARGUMENTS of every serving program (PR 21): a closed-over array
+# is baked into the HLO, i.e. one copy of the model per bucket signature in
+# device memory
+# ---------------------------------------------------------------------------
+def test_serving_programs_take_weights_as_arguments():
+    import jax
+
+    model = tiny_model()
+    eng = make_engine(model)
+    try:
+        prompt = np.arange(1, 7)
+        before = eng.serve([prompt], max_new_tokens=4)[0].tokens
+        args = (tuple(eng._pool.k), tuple(eng._pool.v), eng._weight_vals(),
+                np.zeros((1, 2), np.int32), np.zeros((1,), np.int32),
+                np.zeros((1,), np.int32))
+        closed = jax.make_jaxpr(eng._decode_fn)(*args)
+        weight_bytes = sum(v.nbytes for v in eng._weight_vals())
+        const_bytes = sum(getattr(c, "nbytes", 0) for c in closed.consts)
+        assert const_bytes < weight_bytes / 10, (const_bytes, weight_bytes)
+        # ... so a compiled program serves the weights the model has NOW
+        builds = prof.dispatch_counters()["serve_capture_builds"]
+        for p, q in zip(model.parameters(), tiny_model(seed=11).parameters()):
+            p.set_value(q.numpy())
+        after = eng.serve([prompt], max_new_tokens=4)[0].tokens
+        assert prof.dispatch_counters()["serve_capture_builds"] == builds
+        fresh = make_engine(model)
+        try:
+            assert after == fresh.serve([prompt], max_new_tokens=4)[0].tokens
+        finally:
+            fresh.close()
+        assert after != before
+    finally:
+        eng.close()
+
+
+def test_two_engines_trace_one_model_from_two_threads():
+    """Tracing rebinds the shared model's weights to tracers; the rebinding
+    is serialized, so two engines over one model may build their programs
+    at the same time. More threads than signatures, a short switch interval,
+    every join bounded."""
+    import threading
+
+    model = tiny_model()
+    prompts = [np.arange(1, 1 + n) for n in (3, 6, 9, 12, 14)]
+    base_eng = make_engine(model)
+    try:
+        want = [r.tokens for r in base_eng.serve(prompts, max_new_tokens=5)]
+    finally:
+        base_eng.close()
+    from paddle_tpu.core.lazy import reset_serve_programs
+
+    reset_serve_programs()  # every thread below builds its programs afresh
+    got, errors = {}, []
+
+    def worker(i):
+        eng = make_engine(model)
+        try:
+            got[i] = [r.tokens for r in eng.serve(prompts, max_new_tokens=5)]
+        except BaseException as e:  # reported by the assert below
+            errors.append(e)
+        finally:
+            eng.close()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(i,), daemon=True)
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert all(got[i] == want for i in range(4)), got

@@ -20,7 +20,7 @@ trainer and replaying ONE donated multi-chip program per step on the
   replay faults and re-promotes after cooldown, final numerics bitwise
   equal to the fault-free run;
 - a pipelined (pp>1) mesh refuses capture structurally
-  (shardmap_autodiff) and trains on at the lazy tier.
+  (pipelined_mesh) and trains on at the lazy tier.
 """
 import numpy as np
 import pytest
@@ -295,5 +295,5 @@ def test_pp_mesh_refuses_capture_and_trains_on(sharded_capture_mode):
     assert c["capture_sharded_builds"] == 0, c
     assert c["capture_sharded_replays"] == 0, c
     reasons = dict(c["capture_fallback_reasons"])
-    assert reasons.get("shardmap_autodiff", 0) >= 1, reasons
+    assert reasons.get("pipelined_mesh", 0) >= 1, reasons
     assert all(np.isfinite(l) for l in losses)  # still trains, lazy tier

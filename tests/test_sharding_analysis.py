@@ -351,23 +351,35 @@ def test_gpt_dp2mp2_estimate_matches_measured_per_device(gpt_dp2mp2):
 
 
 def test_gpt_pp2_collective_goldens():
-    """The GPipe pipeline step under pp=2 (fleet back-fills dp=4 on the
-    8-device platform): per-microbatch stage-boundary ppermute of the
-    per-shard hidden, the pp loss-sum, the dp loss-mean."""
+    """The full GPipe pipeline step under pp=2 (fleet back-fills dp=4 on the
+    8-device platform). Forward: per-microbatch stage-boundary ppermute of
+    the per-shard hidden, the pp loss-sum, the dp loss-mean. Backward: the
+    transposes of those three, then one grad all-reduce per parameter —
+    over dp for the pp-stacked block params, over (pp, dp) for the
+    replicated embedding / final-norm params."""
     md = _dryrun()
     step, specs = md.build_model_pp({"pp": 2})
     ctx = pipelined_step_context(step, specs)
     assert ctx.mesh_axes["pp"] == 2 and ctx.mesh_axes["dp"] == 4
-    got = {(r.kind, r.axes):
-           (r.group_size, r.payload_bytes, r.wire_bytes, r.count)
-           for r in ctx.collectives}
-    assert got == {
-        # hidden per shard: f32[2, 16, 32] = 4096B, once per microbatch
-        ("ppermute", ("pp",)): (2, 4096, 4096, 2),
-        ("psum", ("pp",)): (2, 4, 4, 1),    # scalar loss sum over stages
-        ("psum", ("dp",)): (4, 4, 6, 1),    # loss pmean: 2·4·(4-1)/4
+    assert len(ctx.donated) == 80  # the full step, not the forward program
+    got = Counter((r.kind, r.axes, r.group_size, r.payload_bytes,
+                   r.wire_bytes, r.count) for r in ctx.collectives)
+    schedule = {
+        # hidden per shard: f32[2, 16, 32] = 4096B, once per microbatch;
+        # forward and its transpose
+        ("ppermute", ("pp",), 2, 4096, 4096, 2): 2,
+        ("psum", ("pp",), 2, 4, 4, 1): 2,   # scalar loss sum over stages
+        ("psum", ("dp",), 4, 4, 6, 1): 2,   # loss pmean: 2·4·(4-1)/4
     }
-    assert sum(r.total_wire_bytes for r in ctx.collectives) == 8202
+    for k, n in schedule.items():
+        assert got[k] == n, (k, got)
+    grads = Counter({k: n for k, n in got.items() if k not in schedule})
+    by_axes = Counter()
+    for (kind, axes, *_), n in grads.items():
+        assert kind == "psum"
+        by_axes[axes] += n
+    assert by_axes == {("dp",): 12, ("pp", "dp"): 4}
+    assert sum(r.total_wire_bytes for r in ctx.collectives) == 298324
     diags = analysis.run_passes(ctx)
     assert not [d for d in diags if d.severity == analysis.Severity.ERROR]
 

@@ -1,0 +1,196 @@
+"""AOT compiles for the chip — the one file that holds them.
+
+The TPU compiler is installed here and compiles for a DESCRIBED v5e:2x2
+topology with no chip attached (on-chip-measurement guide, section 2): what
+Mosaic or XLA:TPU refuses in these tests it would refuse on the chip. Nothing
+runs, so these say nothing about results or times.
+
+Rules this file keeps: the topology is described inside the ``topo`` fixture
+(never at import, in a skipif, in parametrize or in conftest.py); the fixture
+is not autouse; every compile happens in the test's own process; the
+persistent compile cache is off around them (such an entry cannot be read
+back without a chip). The kernels choose interpret mode from
+``jax.default_backend()``, which is still ``cpu`` here — the tests steer that
+by monkeypatch, not through an option of the program.
+"""
+import importlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu as paddle
+# the package re-exports the flash_attention FUNCTION under the module's name
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+fu = importlib.import_module("paddle_tpu.ops.pallas.fused_update")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Both kernels take their compiled (non-interpret) branch, as on the
+    chip."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(fu, "_interpret", lambda: False)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+# ---------------------------------------------------------------------------
+# flash attention
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (2, 4096, 16, 64)],
+                         ids=["b8s1024", "b2s4096"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, compiled_kernels, shape,
+                                          grad):
+    spec = _sds(shape, jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return fwd(q, k, v).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd if grad else fwd, spec, spec, spec)
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# fused optimizer update
+# ---------------------------------------------------------------------------
+_HYPER = {
+    "sgd": {},
+    "momentum": {"mu": 0.9, "nesterov": False},
+    "adam": {"b1": 0.9, "b2": 0.999, "eps": 1e-8},
+}
+
+
+def _state_specs(kind, shape, sharding):
+    buf = _sds(shape, jnp.float32, sharding)
+    scalar = _sds((), jnp.float32, sharding)
+    if kind == "momentum":
+        return {"velocity": buf}
+    if kind == "adam":
+        return {"moment1": buf, "moment2": buf,
+                "beta1_pow": scalar, "beta2_pow": scalar}
+    return {}
+
+
+@pytest.mark.parametrize("shape", [(1024, 4096), (50304, 1024)],
+                         ids=["1024x4096", "50304x1024"])
+@pytest.mark.parametrize("gate", [False, True], ids=["nogate", "gate"])
+@pytest.mark.parametrize("kind", ["adam", "momentum", "sgd"])
+def test_fused_update_compiles_for_v5e(one_chip, compiled_kernels, kind,
+                                       gate, shape):
+    buf = _sds(shape, jnp.float32, one_chip)
+    lr = _sds((), jnp.float32, one_chip)
+    bad = _sds((), jnp.bool_, one_chip)
+    state = _state_specs(kind, shape, one_chip)
+
+    def update(p, g, lr, state, bad):
+        return fu.param_update(kind, p, g, lr, state, _HYPER[kind],
+                               wd=0.01, bad=bad if gate else None)
+
+    text = _compiled_text(update, buf, buf, lr, state, bad)
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# the attention path a full-width GPT layer takes, through the functional API
+# ---------------------------------------------------------------------------
+def test_gpt_attention_path_compiles_with_flash_kernel(one_chip,
+                                                       compiled_kernels):
+    """hidden 1024 = 16 heads x 64, s1024, b8, bf16, causal, fwd+bwd through
+    ``nn.functional.scaled_dot_product_attention``: the flash kernel must be
+    in the compiled text — neither the dense path nor interpret mode."""
+    import paddle_tpu.nn.functional as F
+
+    spec = _sds((8, 1024, 16, 64), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            out = F.scaled_dot_product_attention(
+                paddle.Tensor(q), paddle.Tensor(k), paddle.Tensor(v),
+                is_causal=True, training=True)
+            return out._value.astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, spec, spec, spec)
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# a whole compile_train_step program (full width, depth 2)
+# ---------------------------------------------------------------------------
+def test_compile_train_step_program_compiles_for_v5e(one_chip,
+                                                     compiled_kernels):
+    """GPT-2 345M width at depth 2, AMP O2 bf16, AdamW, b8 x s1024: the jitted
+    step ``CompiledTrainStep`` builds, lowered over shapes placed on the
+    described chip. Fits 16 GB with room, and carries the flash kernel."""
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models import (GPTForPretraining, GPTPretrainingCriterion,
+                                   gpt2_345m)
+
+    cfg = gpt2_345m(max_seq_len=1024, dropout=0.0, attn_dropout=0.0)
+    cfg.num_layers = 2  # depth cut; every width as published
+    paddle.seed(0)
+    model = paddle.amp.decorate(GPTForPretraining(cfg), level="O2",
+                                dtype="bfloat16")
+    crit = GPTPretrainingCriterion(cfg)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, lb: crit(lg.astype("float32"), lb), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((8, 1024), jnp.int32, one_chip)
+    key = _random.next_key()
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), key,
+            jnp.asarray(1e-4, jnp.float32), ids, ids)
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    compiled = step._build().lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16 * 2**30, mem

@@ -14,8 +14,8 @@ GPT (the planner's target workload — activation-dominated attention):
                          — remat must not change numerics, only memory
   beats-naive-recompute  the planned step's steps/s strictly beats the
                          same model built with cfg.use_recompute=True
-                         (uniform per-block recompute — the measured 4/3
-                         step tax from PROFILE_GPT.md)
+                         (uniform per-block recompute — a flat 4/3
+                         recompute tax)
   offload-overhead       host offload of cold Adam state: transfers
                          actually happen, offload on/off final params and
                          losses are bitwise equal, and the measured

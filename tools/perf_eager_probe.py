@@ -1,6 +1,6 @@
 """Programs-per-step probe for the eager LeNet train step.
 
-Measures what PROFILE_EAGER.md's arithmetic predicts: the number of device
+Measures the programs-per-step arithmetic: the number of device
 programs one eager LeNet train step launches on the per-op path, the
 lazy-dispatch path (FLAGS_eager_lazy_dispatch), and the whole-step
 capture-and-replay path (FLAGS_eager_step_capture — one donated program per

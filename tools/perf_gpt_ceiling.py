@@ -1,6 +1,5 @@
 """GPT-345M ceiling study: hand-rolled pure-JAX transformer train step vs
-the framework's compiled step (PROFILE_RESNET.md methodology, VERDICT r3
-task 8).
+the framework's compiled step.
 
 The hand-rolled step uses raw jax/jnp + the same pallas flash-attention
 kernel, bf16 weights with fp32 AdamW state, one donated jit — everything a
@@ -202,8 +201,7 @@ def run(variant):
     loss, p, m, v, t = step(p, m, v, t, x, y)
     float(loss)
 
-    # min-of-REPS windows: the relay's ambient congestion only slows a
-    # window down (PROFILE_EAGER.md)
+    # min-of-REPS windows: ambient host load only slows a window down
     reps = int(os.environ.get("BENCH_REPS", 2))
     dt = float("inf")
     last = first
