@@ -1,0 +1,8 @@
+"""Run with ``JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q``: not
+part of tier-1, takes nothing from it."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
