@@ -9,6 +9,13 @@
     python chip_smoke.py --rehearse   the same phases at a tiny config on
                                       whatever backend is there (CPU
                                       rehearsal); never prints the ok line
+    python chip_smoke.py --profile 345m|774m
+                                      ONLY device + train, on that GPT-2 at
+                                      the benchmark's batch, with three more
+                                      steps under paddle.profiler.Profiler
+                                      and its summary(): where the device
+                                      time of the step goes, by section,
+                                      layer and kernel
 
 One process, which touches JAX once and spawns nothing. No accelerator means
 failure at once — there is no CPU carry-on. Every check raises; nothing
@@ -61,35 +68,19 @@ def fmt_gib(n):
     return f"{n / 2**30:.3f} GiB"
 
 
-class CompileCounter:
-    """Backend compiles and persistent-cache traffic, from jax.monitoring."""
-
-    def __init__(self):
-        import jax
-
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._evt)
-
-    def _dur(self, name, secs, **kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _evt(self, name, **kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 # ---------------------------------------------------------------------------
 # configurations
 # ---------------------------------------------------------------------------
-def model_cfg(rehearse, max_seq_len):
+def model_cfg(rehearse, max_seq_len, size="345m"):
     from paddle_tpu.models import GPTConfig, gpt2_345m
 
     if rehearse:
         return GPTConfig(vocab_size=512, hidden_size=64, num_layers=2,
                          num_heads=4, max_seq_len=max_seq_len, dropout=0.0,
+                         attn_dropout=0.0)
+    if size == "774m":  # gpt2-large, as benchmark/configs/gpt2-large-774m.json
+        return GPTConfig(hidden_size=1280, num_layers=36, num_heads=20,
+                         max_seq_len=max_seq_len, dropout=0.0,
                          attn_dropout=0.0)
     return gpt2_345m(max_seq_len=max_seq_len, dropout=0.0, attn_dropout=0.0)
 
@@ -250,24 +241,39 @@ def phase_kernels(jax, paddle, kind, rehearse):
                           "FLAGS_pallas_update_interpret": False})
 
 
-def phase_train(jax, paddle, kind, rehearse, counter):
-    bsz, seq = (2, 64) if rehearse else (8, 1024)
-    cfg = model_cfg(rehearse, seq)
+def compile_counts(paddle):
+    """(backend compiles, persistent-cache hits) so far, as the program's
+    own jax.monitoring listener counted them."""
+    c = paddle.profiler.dispatch_counters()
+    return c["backend_compiles"], c["compile_cache_hits"]
+
+
+def phase_train(jax, paddle, kind, rehearse, profile):
+    # 774M at batch 8 needs 14.3 GiB of the chip's 15.75 (PERF.md, section 4)
+    bsz, seq = (2, 64) if rehearse else (4 if profile == "774m" else 8, 1024)
+    cfg = model_cfg(rehearse, seq, profile or "345m")
     model, loss_fn, opt = build_trainer(paddle, cfg)
     step = paddle.jit.compile_train_step(model, loss_fn, opt)
     x, y = make_batch(paddle, cfg, bsz, seq)
     dev = jax.devices()[0]
 
+    paddle.profiler.trace.clear()
     losses, secs = timed_steps(step, x, y, 1)
     compile_s = secs[0]
+    # a first step whose program itself came from the persistent cache (the
+    # machine kept it from an earlier call) has no compile time to collapse:
+    # the ring holds a cache_hit inside that step's launch
+    cold = not paddle.profiler.trace.events(kind="cache_hit",
+                                            site="compile_train_step/launch")
     check(step._step._cache_size() == 1, "first step compiled != 1 program")
-    compiles_before = counter.compiles
+    compiles_before, _ = compile_counts(paddle)
     more, steady = timed_steps(step, x, y, 4)
     losses += more
-    say("train", f"GPT-2 {'tiny (rehearsal)' if rehearse else '345M'} "
+    say("train", f"GPT-2 {'tiny (rehearsal)' if rehearse else profile or '345m'} "
         f"L{cfg.num_layers} h{cfg.hidden_size} b{bsz} x s{seq} AMP-O2 bf16 "
         f"AdamW: losses {[round(l, 4) for l in losses]}", kind)
-    say("train", f"first step (trace + compile + run) {compile_s:.1f} s; "
+    say("train", f"first step (trace + {'compile' if cold else 'cache fetch'} "
+        f"+ run) {compile_s:.1f} s; "
         f"steps 2-5 {[round(s, 4) for s in steady]} s/step "
         "(smoke run, not a benchmark)", kind)
     check(all(math.isfinite(l) for l in losses), "a loss is not finite")
@@ -275,7 +281,7 @@ def phase_train(jax, paddle, kind, rehearse, counter):
           f"first loss {losses[0]} not within 0.5 of ln(vocab)")
     check(losses[4] < losses[0], "loss did not fall over 5 steps")
     check(step._step._cache_size() == 1
-          and counter.compiles == compiles_before,
+          and compile_counts(paddle)[0] == compiles_before,
           "steps 2-5 compiled something")
     if not rehearse:
         for t in list(model.parameters()):
@@ -289,23 +295,45 @@ def phase_train(jax, paddle, kind, rehearse, counter):
         say("train", f"peak_bytes_in_use={fmt_gib(st['peak_bytes_in_use'])} "
             f"of bytes_limit={fmt_gib(st['bytes_limit'])}", kind)
 
+    if profile:
+        # where the step's device time goes, by the program's own tool: the
+        # scopes compile_train_step and Layer.__call__ wrote, read back from
+        # the trace the Profiler itself took
+        with paddle.profiler.Profiler() as prof:
+            for loss in [step(x, y) for _ in range(3)]:
+                loss._value.block_until_ready()
+        view = prof.device_view()
+        prof.summary()
+        check(view is not None and view["busy_s"] > 0,
+              "the profiled stretch holds no device op")
+        total = sum(view["sections"].values())
+        check(abs(total - view["busy_s"]) <= 0.01 * view["busy_s"],
+              f"sections sum to {total}, busy time is {view['busy_s']}")
+        if not rehearse:
+            check(set(view["kernels"]) >= {"flash_attention_fwd",
+                                           "flash_attention_bwd_dkv",
+                                           "flash_attention_bwd_dq"},
+                  f"attention kernels not named in the trace: "
+                  f"{sorted(view['kernels'])}")
+
     # the same program again with every in-memory cache dropped: trace,
     # lower, and the executable must come back from the PERSISTENT compile
     # cache; its HLO must carry the flash kernel
     jax.clear_caches()
-    hits = counter.cache_hits
+    _, hits = compile_counts(paddle)
     t0 = time.perf_counter()
     compiled = step._step.lower(*step._arg_specs).compile()
     again_s = time.perf_counter() - t0
+    hits_after = compile_counts(paddle)[1]
     say("train", f"same program traced and compiled again in {again_s:.1f} s "
         f"(first step {compile_s:.1f} s); persistent cache hits "
-        f"+{counter.cache_hits - hits}", kind)
+        f"+{hits_after - hits}", kind)
     if not rehearse:
         check("tpu_custom_call" in compiled.as_text(),
               "compiled train step has no flash kernel (dense or interpret)")
-        check(counter.cache_hits > hits,
+        check(hits_after > hits,
               "recompile did not hit the persistent compile cache")
-        check(again_s < 0.5 * compile_s,
+        check(not cold or again_s < 0.5 * compile_s,
               "compile seconds did not collapse on the repeat")
 
 
@@ -528,6 +556,9 @@ def main():
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny config on whatever backend is there; never "
                          "prints the ok line")
+    ap.add_argument("--profile", choices=("345m", "774m"),
+                    help="only device + train on that model, with a profiled "
+                         "stretch and the profiler's summary()")
     args = ap.parse_args()
 
     import jax
@@ -544,16 +575,17 @@ def main():
         phases = [("mesh4", lambda: phase_mesh4(jax, paddle, kind,
                                                 args.rehearse))]
     else:
-        counter = CompileCounter()
         phases = [
             ("device", lambda: phase_device(jax, kind)),
             ("kernels", lambda: phase_kernels(jax, paddle, kind,
                                               args.rehearse)),
             ("train", lambda: phase_train(jax, paddle, kind, args.rehearse,
-                                          counter)),
+                                          args.profile)),
             ("serve", lambda: phase_serve(jax, paddle, kind, args.rehearse)),
             ("eager", lambda: phase_eager(jax, paddle, kind, args.rehearse)),
         ]
+        if args.profile:
+            phases = [p for p in phases if p[0] in ("device", "train")]
     t_all = time.perf_counter()
     for name, run in phases:
         t0 = time.perf_counter()

@@ -192,6 +192,13 @@ def _reset_counters_locked():
         jit_cache_evictions=0,
         vjp_cache_evictions=0,
         captured_programs=0,
+        # one per paddle.jit.compile_train_step call (the jitted whole step)
+        compiled_programs=0,
+        # backend compiles (count, seconds) and persistent-cache hits, from
+        # profiler/trace.py's jax.monitoring listener
+        backend_compiles=0,
+        backend_compile_s=0.0,
+        compile_cache_hits=0,
         capture_builds=0,
         capture_replays=0,
         capture_fallbacks=0,
@@ -491,6 +498,15 @@ def _cache_token(fn: Callable):
     return code if code is not None else fn
 
 
+def _named_partial(fn: Callable, kw_items: Tuple) -> Callable:
+    """`fn` with its static kwargs bound, under fn's own name: jax names a
+    jitted callable by `__name__`, and a bare partial reads `jit(<unknown>)`
+    in every op_name and device-trace kernel name below it."""
+    bound = functools.partial(fn, **dict(kw_items))
+    bound.__name__ = getattr(fn, "__name__", type(fn).__name__)
+    return bound
+
+
 def _jitted(fn: Callable, kw_items: Tuple, token=None) -> Optional[Callable]:
     if token is not None:
         # explicit token (to_static's per-config closures): store the jit
@@ -507,7 +523,7 @@ def _jitted(fn: Callable, kw_items: Tuple, token=None) -> Optional[Callable]:
             except TypeError:
                 return None
             if cached is None:
-                cached = jax.jit(functools.partial(fn, **dict(kw_items)))
+                cached = jax.jit(_named_partial(fn, kw_items))
                 store[kw_items] = cached
             return cached
     token = token if token is not None else _cache_token(fn)
@@ -519,7 +535,7 @@ def _jitted(fn: Callable, kw_items: Tuple, token=None) -> Optional[Callable]:
     except TypeError:  # unhashable static kwarg — run unjitted
         return None
     if cached is None:
-        cached = jax.jit(functools.partial(fn, **dict(kw_items)))
+        cached = jax.jit(_named_partial(fn, kw_items))
         _lru_put(_jit_cache, key, cached, "jit_cache_evictions")
     return cached
 

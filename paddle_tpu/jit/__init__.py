@@ -32,10 +32,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import dispatch as _dispatch
 from ..core import random as _random
 from ..core.dispatch import apply, no_grad
 from ..core.tensor import Tensor
 from ..nn.layer_base import Layer
+from ..profiler import trace as _trace
 
 __all__ = [
     "to_static",
@@ -418,8 +420,13 @@ class CompiledTrainStep:
             ins = [Tensor(v, stop_gradient=True) for v in full]
             with _bind_values(params + buffers, list(p_vals) + list(b_vals)), \
                     no_grad(), _random.rng_scope(key):
-                out = model(*ins[:-1]) if len(ins) > 1 else model(ins[0])
-                loss = loss_fn(out, ins[-1]) if loss_fn is not None else out
+                # the sections the profiler's device view splits by; the
+                # backward of each reads transpose(jvp(forward))/... by itself
+                with jax.named_scope("forward"):
+                    out = model(*ins[:-1]) if len(ins) > 1 else model(ins[0])
+                with jax.named_scope("loss"):
+                    loss = (loss_fn(out, ins[-1]) if loss_fn is not None
+                            else out)
                 # buffer values after forward (BN running stats updates)
                 new_b = tuple(b._value for b in buffers)
             lv = loss._value if isinstance(loss, Tensor) else loss
@@ -473,24 +480,26 @@ class CompiledTrainStep:
             if grad_clip is not None:
                 # the clip objects are pure jnp math on Tensor wrappers —
                 # tracer-safe, so the eager clip semantics apply unchanged
-                pairs = grad_clip(
-                    [
-                        (Tensor(pv, stop_gradient=True), Tensor(gv, stop_gradient=True))
-                        for pv, gv in zip(p_vals, grads)
-                    ]
-                )
+                with jax.named_scope("grad_clip"):
+                    pairs = grad_clip(
+                        [
+                            (Tensor(pv, stop_gradient=True), Tensor(gv, stop_gradient=True))
+                            for pv, gv in zip(p_vals, grads)
+                        ]
+                    )
                 grads = [g._value for _, g in pairs]
             new_p, new_s = [], []
-            for pv, gv, st, h, mask in zip(
-                p_vals, grads, opt_states, per_hyper, asp_masks
-            ):
-                if gv.dtype != pv.dtype:
-                    gv = gv.astype(pv.dtype)
-                np_, ns_ = rule(opt, pv, gv, lr, st, **h)
-                if mask is not None:
-                    np_ = np_ * mask.astype(np_.dtype)
-                new_p.append(np_)
-                new_s.append(ns_)
+            with jax.named_scope("optimizer"):
+                for pv, gv, st, h, mask in zip(
+                    p_vals, grads, opt_states, per_hyper, asp_masks
+                ):
+                    if gv.dtype != pv.dtype:
+                        gv = gv.astype(pv.dtype)
+                    np_, ns_ = rule(opt, pv, gv, lr, st, **h)
+                    if mask is not None:
+                        np_ = np_ * mask.astype(np_.dtype)
+                    new_p.append(np_)
+                    new_s.append(ns_)
             return loss, in_grads, tuple(new_p), tuple(new_s), new_b
 
         return step_fn
@@ -739,6 +748,43 @@ class CompiledTrainStep:
 
     @no_grad()
     def __call__(self, *batch) -> Tensor:
+        """One training step. Spans (paddle.profiler.span: on a running
+        trace's /host:CPU plane, and in the flight recorder's ring whether or
+        not one runs): the root `compile_train_step` is a step annotation
+        numbered by the optimizer's step count; `/args` gathers the leaves,
+        lr and key and builds the step when the shapes are new; `/launch` is
+        the jitted call alone (jax.jit compiles inside the first one: the
+        ring's `compile` event says which); `/writeback` rebinds the
+        results. The launch counts as one `compiled` program."""
+        with _trace.span("compile_train_step",
+                         step_num=self.optimizer._step_count):
+            with _trace.span("compile_train_step/args"):
+                args = self._gather_args(batch)
+            with _trace.span("compile_train_step/launch"):
+                loss, in_grads, new_p, new_s, new_b = self._step(*args)
+            _dispatch._count_program("compiled")
+            with _trace.span("compile_train_step/writeback"):
+                # the old (donated) leaves die here, inside the span: left to
+                # the frame's teardown, freeing ~5 arrays a parameter would be
+                # host time of the step that no span covers
+                del args
+                for p, v in zip(self._params, new_p):
+                    p._value = v
+                for b, v in zip(self._buffers, new_b):
+                    b._value = v
+                self._opt_state = list(new_s)
+                for p, st in zip(self._params, self._opt_state):
+                    self.optimizer._accumulators[id(p)] = st
+                self.optimizer._step_count += 1
+                loss_t = Tensor(loss, stop_gradient=True)
+                if self._grad_input_idx:
+                    return loss_t, [Tensor(g, stop_gradient=True)
+                                    for g in in_grads]
+                return loss_t
+
+    def _gather_args(self, batch):
+        """The jitted step's arguments for this call; (re)builds the step
+        when the batch's shapes are new."""
         if self._opt_state is None:
             self._opt_state = self._init_opt_state()
         batch_vals = [b._value if isinstance(b, Tensor) else jnp.asarray(b) for b in batch]
@@ -775,19 +821,7 @@ class CompiledTrainStep:
             # donation-safety gate (analysis.memory): flag live aliases of
             # the donated param/state buffers before XLA reuses them
             self._check_donation(self._opt_state)
-        loss, in_grads, new_p, new_s, new_b = self._step(*args)
-        for p, v in zip(self._params, new_p):
-            p._value = v
-        for b, v in zip(self._buffers, new_b):
-            b._value = v
-        self._opt_state = list(new_s)
-        for p, st in zip(self._params, self._opt_state):
-            self.optimizer._accumulators[id(p)] = st
-        self.optimizer._step_count += 1
-        loss_t = Tensor(loss, stop_gradient=True)
-        if self._grad_input_idx:
-            return loss_t, [Tensor(g, stop_gradient=True) for g in in_grads]
-        return loss_t
+        return args
 
 
 def compile_train_step(model, loss_fn, optimizer, mesh=None, in_shardings=None,

@@ -21,6 +21,8 @@ import math
 from dataclasses import dataclass
 from typing import Optional
 
+import jax
+
 import paddle_tpu as paddle
 
 from .. import nn
@@ -339,8 +341,11 @@ class GPTForPretraining(nn.Layer):
 
     def _tied_head(self, h):
         w = self.gpt.embeddings.word_embeddings.weight
-        logits = paddle.matmul(h, w, transpose_y=True)
-        return _sp(logits, self.cfg, ("dp", "sharding"), "sep", "mp")
+        # no Layer of its own: the scope names the vocabulary-wide matmul in
+        # the profiler's by-layer view
+        with jax.named_scope("lm_head"):
+            logits = paddle.matmul(h, w, transpose_y=True)
+            return _sp(logits, self.cfg, ("dp", "sharding"), "sep", "mp")
 
     # pipeline-partition protocol (parallel/pipeline.py): homogeneous middle
     # = the decoder stack; embedding/head replicated across pp stages

@@ -257,7 +257,7 @@ class LayerList(Layer):
         return list(self._sub_layers.values())[idx]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -279,7 +279,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
 
 class ParameterList(Layer):

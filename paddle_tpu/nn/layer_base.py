@@ -10,6 +10,7 @@ from __future__ import annotations
 import collections
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..core.dispatch import no_grad
@@ -69,6 +70,9 @@ class Layer:
         self._forward_post_hooks = collections.OrderedDict()
         self._casted_dtype = None  # set by amp O2 decorate / .to(dtype)
         self._full_name = name_scope or self.__class__.__name__.lower()
+        # the attribute this layer is registered under in its parent (_adopt);
+        # __call__ opens a jax.named_scope of that name
+        self._scope_name = None
 
     # -- construction --------------------------------------------------------
     def create_parameter(
@@ -116,7 +120,20 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        self._adopt(str(name), sublayer)
         return sublayer
+
+    def _adopt(self, name, child):
+        """Name the child's scope after the attribute it is registered
+        under. A container with no forward of its own (LayerList,
+        LayerDict) is never called, so its children carry its name too:
+        `layers.3`."""
+        if type(self).forward is Layer.forward and self._scope_name:
+            name = f"{self._scope_name}.{name}"
+        child._scope_name = name
+        if type(child).forward is Layer.forward:
+            for n, c in child._sub_layers.items():
+                child._adopt(n, c)
 
     def register_buffer(self, name, tensor, persistable=True):
         self._buffers[name] = tensor
@@ -143,6 +160,7 @@ class Layer:
                 if d is not None:
                     d.pop(name, None)
             layers[name] = value
+            self._adopt(name, value)
         elif isinstance(value, Tensor) and buffers is not None and name in buffers:
             buffers[name] = value
         else:
@@ -262,17 +280,24 @@ class Layer:
 
     # -- call ----------------------------------------------------------------
     def __call__(self, *inputs, **kwargs):
-        """reference: layers.py:920 __call__ → _dygraph_call_func:887."""
-        for hook in self._forward_pre_hooks.values():
-            result = hook(self, inputs)
-            if result is not None:
-                inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
-        for hook in self._forward_post_hooks.values():
-            result = hook(self, inputs, outputs)
-            if result is not None:
-                outputs = result
-        return outputs
+        """reference: layers.py:920 __call__ → _dygraph_call_func:887.
+
+        Everything traced inside carries the layer's path in its op_name
+        (`forward/gpt/layers.3/attn/...`): the name this layer is registered
+        under in its parent, the class name for a root. The name stack is no
+        part of any cache key, so an eager call pays only the context
+        manager."""
+        with jax.named_scope(self._scope_name or type(self).__name__):
+            for hook in self._forward_pre_hooks.values():
+                result = hook(self, inputs)
+                if result is not None:
+                    inputs = result if isinstance(result, tuple) else (result,)
+            outputs = self.forward(*inputs, **kwargs)
+            for hook in self._forward_post_hooks.values():
+                result = hook(self, inputs, outputs)
+                if result is not None:
+                    outputs = result
+            return outputs
 
     def forward(self, *inputs, **kwargs):
         raise NotImplementedError

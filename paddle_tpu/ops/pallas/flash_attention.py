@@ -118,6 +118,7 @@ def _fwd(q, k, v, scale, causal, bq, bk):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
@@ -242,6 +243,7 @@ def _bwd(scale, causal, bq, bk, res, do):
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_bwd_dkv",
         grid=(bh, n_k, n_q),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, kk, j: (i, j, _0)),
@@ -272,6 +274,7 @@ def _bwd(scale, causal, bq, bk, res, do):
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_bwd_dq",
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),

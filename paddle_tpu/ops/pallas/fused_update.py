@@ -178,6 +178,7 @@ def _call(kernel, scalars, bufs, n_out, interpret):
                             memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
+        name="fused_update",
         grid=grid,
         in_specs=[scalar_spec] * len(scalars) + [buf_spec] * len(tiled),
         out_specs=[buf_spec] * n_out,

@@ -5,16 +5,17 @@ RecordEvent host annotation api → HostTracer host_event_recorder.h, CUPTI
 CudaTracer, ChromeTracingLogger chrome://tracing export; SURVEY.md §5).
 
 TPU-native: device-side tracing is jax.profiler (XPlane → TensorBoard/
-perfetto, replacing CUPTI), host annotations keep the RecordEvent API
-(lowering to jax.profiler.TraceAnnotation inside traces and wall-clock spans
-eagerly), and the chrome-trace export writes the host-span timeline JSON.
+perfetto, replacing CUPTI); host annotations keep the RecordEvent API, which
+is `span` (a jax.profiler.TraceAnnotation plus a flight-recorder event);
+`Profiler.summary()` reads the ring for the host view and the `.xplane.pb`
+it wrote for the device view; the chrome-trace export writes the ring.
 """
 from __future__ import annotations
 
-import contextlib
+import glob
 import json
 import os
-import threading
+import tempfile
 import time
 from collections.abc import Mapping as _MappingABC
 from enum import Enum
@@ -50,6 +51,7 @@ __all__ = [
     "ProfilerState",
     "ProfilerTarget",
     "RecordEvent",
+    "span",
     "make_scheduler",
     "export_chrome_tracing",
     "load_profiler_result",
@@ -95,55 +97,13 @@ class SummaryView(Enum):
     MemoryView = 6
 
 
-_host_events = []
-_events_lock = threading.Lock()
-
-
-class RecordEvent:
-    """Host-side annotation (reference: profiler/utils.py RecordEvent over
-    platform/profiler/event_tracing.h:47). Usable as context manager or
-    begin()/end(); inside jit traces it becomes an XLA TraceAnnotation."""
-
-    def __init__(self, name: str, event_type=None):
-        self.name = name
-        self._t0 = None
-        self._annot = None
-
-    def begin(self):
-        self._t0 = time.perf_counter_ns()
-        try:
-            self._annot = jax.profiler.TraceAnnotation(self.name)
-            self._annot.__enter__()
-        except Exception:
-            self._annot = None
-
-    def end(self):
-        if self._annot is not None:
-            self._annot.__exit__(None, None, None)
-            self._annot = None
-        if self._t0 is not None:
-            t1 = time.perf_counter_ns()
-            with _events_lock:
-                _host_events.append(
-                    {
-                        "name": self.name,
-                        "ph": "X",
-                        "ts": self._t0 / 1000.0,
-                        "dur": (t1 - self._t0) / 1000.0,
-                        "pid": os.getpid(),
-                        "tid": threading.get_ident() % 100000,
-                        "cat": "host",
-                    }
-                )
-            self._t0 = None
-
-    def __enter__(self):
-        self.begin()
-        return self
-
-    def __exit__(self, *exc):
-        self.end()
-        return False
+# One host-span primitive (profiler/trace.py): a TraceAnnotation on the
+# running trace's /host:CPU plane plus a ``span`` event in the flight
+# recorder's ring. RecordEvent (reference: profiler/utils.py RecordEvent over
+# platform/profiler/event_tracing.h:47) is the same object under its Paddle
+# name; its second positional argument (event_type) is accepted and unused.
+span = trace.span
+RecordEvent = span
 
 
 def make_scheduler(*, closed: int, ready: int, record: int, repeat: int = 0,
@@ -185,9 +145,10 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 class Profiler:
     """reference: profiler.py:43 Profiler — composes host + device tracers.
 
-    Device side: jax.profiler.start_trace/stop_trace writes XPlane data
-    (TensorBoard-loadable). Host side: RecordEvent spans collected into a
-    chrome-trace JSON.
+    Device side: jax.profiler.start_trace/stop_trace writes an
+    ``.xplane.pb`` (TensorBoard-loadable) that ``summary()`` reads back for
+    the device view. Host side: ``span`` / ``RecordEvent`` events, read from
+    the flight recorder's ring (bounded: a long profile keeps its tail).
     """
 
     def __init__(self, *, targets: Optional[Iterable] = None, scheduler=None,
@@ -205,32 +166,31 @@ class Profiler:
         self._state = ProfilerState.CLOSED
         self._device_dir = None
         self._tracing = False
+        self._started_ns = None  # summary() reads the spans from here on
+        self._view = None  # (trace directory, its device view), read once
 
     def start(self):
+        self._started_ns = time.time_ns()
         self._state = self._scheduler(self._step)
         if self._state in (ProfilerState.RECORD, ProfilerState.RECORD_AND_RETURN):
             self._start_device()
 
     def _start_device(self):
+        """A trace that cannot start raises: a profiler that silently
+        records nothing would read as an idle device."""
         if not self._tracing and not self._timer_only:
-            self._device_dir = os.path.join(
-                os.environ.get("PADDLE_PROFILER_DIR", "/tmp/paddle_tpu_prof"),
-                str(int(time.time())),
-            )
-            os.makedirs(self._device_dir, exist_ok=True)
-            try:
-                jax.profiler.start_trace(self._device_dir)
-                self._tracing = True
-            except Exception:
-                self._tracing = False
+            base = os.environ.get("PADDLE_PROFILER_DIR") or os.path.join(
+                tempfile.gettempdir(), "paddle_tpu_prof")
+            os.makedirs(base, exist_ok=True)
+            self._device_dir = tempfile.mkdtemp(
+                prefix=f"{int(time.time())}_", dir=base)
+            jax.profiler.start_trace(self._device_dir)
+            self._tracing = True
 
     def _stop_device(self):
         if self._tracing:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
             self._tracing = False
+            jax.profiler.stop_trace()
 
     def step(self, num_samples: Optional[int] = None):
         self._step += 1
@@ -260,18 +220,14 @@ class Profiler:
         return False
 
     def export(self, path: str, format: str = "json"):
-        """Write the merged chrome trace: RecordEvent host spans PLUS the
-        flight recorder's runtime events — instants on a dedicated lane
-        for flushes/captures/faults/ladder transitions, and per-request
-        async lanes (ph b/n/e keyed by request id) for serving, so a
+        """Write the merged chrome trace from the flight recorder's ring:
+        host spans (ph X), instants on a dedicated lane for
+        flushes/captures/faults/ladder transitions, and per-request async
+        lanes (ph b/n/e keyed by request id) for serving, so a
         continuous-batching interleave or a ladder demotion is visible on
         one timeline. Device XPlane dir noted in metadata."""
-        from . import trace as _trace
-
-        with _events_lock:
-            events = list(_host_events)
-        flight = _trace.events()
-        events = events + _trace.chrome_trace_events(flight)
+        flight = trace.events()
+        events = trace.chrome_trace_events(flight)
         # per-program counter lanes (attribution): every measured program
         # run is a "C" sample, so each program key's wall time plots as
         # its own lane next to the flight instants and request lanes
@@ -290,19 +246,43 @@ class Profiler:
             json.dump(doc, f, default=str)
         return path
 
+    def device_view(self):
+        """Where the device time of the traced stretch went, read from the
+        ``.xplane.pb`` this profiler wrote (statistic.device_view): seconds
+        by section, by layer path and by named kernel. None when nothing was
+        traced (``timer_only``, or no RECORD state reached yet)."""
+        from .statistic import device_view
+
+        if self._device_dir is None or self._tracing:
+            return None
+        if self._view is None or self._view[0] != self._device_dir:
+            paths = glob.glob(os.path.join(
+                self._device_dir, "plugins", "profile", "*", "*.xplane.pb"))
+            self._view = (self._device_dir,
+                          device_view(paths) if paths else None)
+        return self._view[1]
+
     def summary(self, sorted_by=SortedKeys.CPUTotal, op_detail=True,
                 thread_sep=False, time_unit="ms", views=None):
-        """reference: profiler_statistic.py — Overview + Operator report."""
-        from .statistic import build_summary_report
+        """reference: profiler_statistic.py — Overview + Operator report of
+        the host spans in the ring (those begun since ``start()``; all of
+        them for a profiler never started), then the device view (ModelView by
+        section and layer, KernelView by named kernel) of the traced
+        stretch. Prints the report and returns it."""
+        from .statistic import build_device_report, build_summary_report
 
-        with _events_lock:
-            events = list(_host_events)
+        events = [{"name": e.site, "dur": e.attrs["dur_ns"] / 1000.0}
+                  for e in trace.events(kind="span")
+                  if e.attrs["start_ns"] >= (self._started_ns or 0)]
         key = {
             SortedKeys.CPUTotal: "total",
             SortedKeys.CPUAvg: "avg",
             SortedKeys.CPUMax: "max",
         }.get(sorted_by, "total")
         table = build_summary_report(events, sorted_by=key, time_unit=time_unit)
+        view = self.device_view()
+        if view is not None:
+            table += "\n\n" + build_device_report(view)
         print(table)
         return table
 

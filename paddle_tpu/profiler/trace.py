@@ -6,7 +6,14 @@ A bounded in-memory ring of structured runtime events
 ChromeTracingLogger stack argues for, SURVEY.md §5):
 
   program          every device-program launch, by category
-                   (op/segment/backward/optimizer/captured)
+                   (op/segment/backward/optimizer/captured/compiled; the
+                   last is one ``compile_train_step`` call)
+  span             one closed host span (``span`` / ``RecordEvent``): name,
+                   start on the profiler's clock, duration, id, parent span
+  compile /        a program built (seconds: the backend compile, or the
+  cache_hit        fetch when a cache_hit came just before it) and a
+                   persistent-cache hit, from ``jax.monitoring``, sited at
+                   the span open around them
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that
@@ -32,6 +39,7 @@ crash postmortems dump the event tail plus the unified metrics snapshot to
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -40,10 +48,13 @@ import traceback as _tb
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from ..core import flags as _flags
 
 __all__ = [
     "TraceEvent",
+    "span",
     "add_stall_listener",
     "clear",
     "dump_postmortem",
@@ -74,8 +85,9 @@ _faults = None  # lazily bound resilience.faults (step auto-fill)
 
 class TraceEvent:
     """One flight-recorder event. ``ts`` is ``time.perf_counter_ns()`` at
-    emit (monotonic, directly comparable to RecordEvent's host spans);
-    ``wall_time`` derives the absolute time from the module anchor."""
+    emit (monotonic; for a ``span`` event that is the span's END);
+    ``wall_time`` derives the absolute time from the module anchor. A
+    ``span`` event also carries ``start_ns`` on the profiler's own clock."""
 
     __slots__ = ("ts", "kind", "site", "step", "attrs")
 
@@ -196,6 +208,114 @@ def clear():
     ring = _ring
     if ring is not None:
         ring.clear()
+
+
+# ---------------------------------------------------------------------------
+# Host spans. One primitive for the program's own spans and for the Paddle
+# API's RecordEvent: a jax.profiler.TraceAnnotation (so the span sits on the
+# /host:CPU plane of any running trace, whose clock the device planes share)
+# plus, on exit, one ``span`` event in the ring above, so that an untraced
+# run keeps the same spans in memory.
+# ---------------------------------------------------------------------------
+_span_ids = itertools.count(1)
+_tls = threading.local()
+
+
+def _open_spans() -> list:
+    try:
+        return _tls.open
+    except AttributeError:
+        _tls.open = []
+        return _tls.open
+
+
+class span:
+    """``with span("compile_train_step/launch"): ...`` or ``begin()`` /
+    ``end()``. ``ids`` become the annotation's arguments and the ring event's
+    attributes; ``step_num`` among them makes the annotation a
+    ``StepTraceAnnotation`` (the device planes then get a per-step line) and
+    is the event's ``step``.
+
+    The ring event (kind ``span``, site = name) carries ``start_ns`` =
+    ``time.time_ns()`` at entry, which is the clock ``.xplane.pb`` events are
+    on (``profile_start_time`` + an event's ``start_ns``), ``dur_ns`` from
+    the monotonic clock, ``id``, and ``parent`` = the id of the span open
+    around it on this thread (None for a root). With
+    ``FLAGS_trace_ring_size=0`` the ring half is one dict read; with no trace
+    running the annotation half is JAX's inactive TraceMe."""
+
+    __slots__ = ("name", "ids", "id", "parent", "_annot", "_t0", "_start")
+
+    def __init__(self, name: str, event_type=None, **ids):
+        self.name = name
+        self.ids = ids
+        self.id = self.parent = self._annot = self._t0 = None
+
+    def begin(self):
+        kind = (jax.profiler.StepTraceAnnotation if "step_num" in self.ids
+                else jax.profiler.TraceAnnotation)
+        self._annot = kind(self.name, **self.ids)
+        self._annot.__enter__()
+        if _ring_entry["value"]:
+            stack = _open_spans()
+            self.parent = stack[-1].id if stack else None
+            self.id = next(_span_ids)
+            stack.append(self)
+            self._start = time.time_ns()
+            self._t0 = time.perf_counter_ns()
+
+    def end(self):
+        if self._t0 is not None:
+            dur = time.perf_counter_ns() - self._t0
+            self._t0 = None
+            stack = _open_spans()
+            if stack and stack[-1] is self:
+                stack.pop()
+            elif self in stack:  # begin()/end() pairs closed out of order
+                stack.remove(self)
+            emit("span", site=self.name, step=self.ids.get("step_num"),
+                 start_ns=self._start, dur_ns=dur, id=self.id,
+                 parent=self.parent, **self.ids)
+        if self._annot is not None:
+            self._annot.__exit__(None, None, None)
+            self._annot = None
+
+    def __enter__(self):
+        self.begin()
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
+# ---------------------------------------------------------------------------
+# Which step compiled: jax.jit compiles inside the first launch, so without
+# these a recompile is only a long span. Counted in dispatch_counters() too.
+# jax times compile-or-fetch as one event: a `compile` that a `cache_hit`
+# precedes was a fetch from the persistent cache, and its seconds the fetch's.
+# ---------------------------------------------------------------------------
+def _on_compile_event(name, *args, **kw):
+    if name == "/jax/core/compile/backend_compile_duration":
+        kind, attrs = "compile", {"seconds": args[0]}
+        counts = (("backend_compiles", 1), ("backend_compile_s", args[0]))
+    elif name == "/jax/compilation_cache/cache_hits":
+        kind, attrs, counts = "cache_hit", {}, (("compile_cache_hits", 1),)
+    else:
+        return
+    from ..core import dispatch
+
+    for key, n in counts:  # a background compile thread may be the caller
+        dispatch._counter_add(key, n)
+    stack = _open_spans()
+    if stack:
+        emit(kind, site=stack[-1].name, span=stack[-1].id, **attrs)
+    else:
+        emit(kind, **attrs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+jax.monitoring.register_event_listener(_on_compile_event)
 
 
 # ---------------------------------------------------------------------------
@@ -480,9 +600,10 @@ def _watchdog_loop():
 # Chrome-trace conversion: flight events become instants on a dedicated
 # lane; serving events become per-request async lanes (ph b/n/e keyed by
 # request id), so a continuous-batching interleave or a ladder demotion is
-# visible on one timeline next to the RecordEvent host spans.
+# visible on one timeline next to the host spans (``span`` events, ph X).
 # ---------------------------------------------------------------------------
 _FLIGHT_TID = 1
+_SPAN_TID = 2
 _SERVE_END_PHASES = frozenset(("complete", "error", "reject", "shed",
                                "expire"))
 
@@ -540,6 +661,15 @@ def chrome_trace_events(evts: Optional[List[TraceEvent]] = None):
                     "tid": _FLIGHT_TID,
                     "args": args,
                 })
+            continue
+        if ev.kind == "span":  # a closed host span: ev.ts is its end
+            dur = attrs.pop("dur_ns")
+            out.append({
+                "name": ev.site, "cat": "host", "ph": "X",
+                "ts": (ev.ts - dur) / 1000.0, "dur": dur / 1000.0,
+                "pid": pid, "tid": _SPAN_TID,
+                "args": dict(attrs, step=ev.step),
+            })
             continue
         name = ev.kind if not ev.site else f"{ev.kind}:{ev.site}"
         out.append({
