@@ -14,6 +14,9 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
   cache_hit        fetch when a cache_hit came just before it) and a
                    persistent-cache hit, from ``jax.monitoring``, sited at
                    the span open around them
+  flash_tiles      the flash attention kernels were built for a shape: how
+                   many sub-tiles of a head's score square the causal walk
+                   runs, masks and skips (trace time, once per compile)
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that

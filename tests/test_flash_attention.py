@@ -4,6 +4,8 @@ Reference analogue: the fused_attention_op tests
 (test_fused_attention_op.py) which compare fused CUDA attention against a
 composed baseline — same strategy here, on CPU in interpret mode.
 """
+import importlib
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,9 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 from paddle_tpu.ops import nn_ops
 from paddle_tpu.ops.pallas import flash_attention
+
+# the package re-exports the flash_attention FUNCTION under the module's name
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 
 def dense_ref(q, k, v, causal):
@@ -30,22 +35,85 @@ def dense_ref(q, k, v, causal):
 
 
 @pytest.mark.parametrize(
-    "b,s,h,d,causal",
-    [(2, 256, 4, 64, True), (1, 128, 2, 32, False), (2, 384, 3, 64, True)],
+    "b,s,h,d,causal,blocks",
+    [
+        (2, 256, 4, 64, True, {}),
+        (1, 128, 2, 32, False, {}),
+        (2, 384, 3, 64, True, {}),
+        # more than one sub-tile each way inside one resident block
+        (1, 1024, 2, 64, True, {}),
+        (1, 1024, 2, 64, False, {}),
+        # unequal blocks: the grid-level skip and the sub-tile skip together
+        (1, 512, 1, 64, True, {"block_q": 256, "block_k": 512}),
+        (1, 512, 1, 64, True, {"block_q": 512, "block_k": 256}),
+        # no sub-tile size divides the block: one masked tile
+        (1, 200, 1, 64, True, {}),
+        # a 4 x 4 grid: the index maps' clamp on skipped steps
+        (1, 512, 1, 64, True, {"block_q": 128, "block_k": 128}),
+        # head_dim as wide as the MXU: dq, dk and dv as plain products
+        (1, 256, 1, 128, True, {}),
+    ],
 )
-def test_kernel_parity(b, s, h, d, causal):
+def test_kernel_parity(b, s, h, d, causal, blocks):
     rng = np.random.default_rng(0)
     q, k, v = [
         jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32) for _ in range(3)
     ]
-    out = flash_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, **blocks)
     ref = dense_ref(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
-    gf = jax.grad(lambda *a: (flash_attention(*a, causal=causal) ** 2).sum(), (0, 1, 2))(q, k, v)
+    gf = jax.grad(
+        lambda *a: (flash_attention(*a, causal=causal, **blocks) ** 2).sum(),
+        (0, 1, 2))(q, k, v)
     gr = jax.grad(lambda *a: (dense_ref(*a, causal) ** 2).sum(), (0, 1, 2))(q, k, v)
     for a, b_ in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-3)
+
+
+@pytest.mark.parametrize(
+    "seq,bq,bk,sq,sk",
+    [
+        (1024, 1024, 1024, 256, 256),
+        (1024, 1024, 1024, 128, 128),
+        (1024, 1024, 1024, 512, 128),
+        (1024, 1024, 1024, 128, 512),
+        (1024, 1024, 1024, 1024, 1024),
+        (4096, 1024, 1024, 256, 256),
+        (4096, 512, 1024, 256, 128),
+        (512, 256, 512, 128, 256),
+        (512, 512, 256, 256, 128),
+        (512, 128, 128, 128, 128),
+        (384, 384, 384, 128, 128),
+        (200, 200, 200, 200, 200),
+        (600, 600, 600, 600, 600),
+    ],
+)
+def test_causal_tile_counts(seq, bq, bk, sq, sk):
+    """(run, masked, total) against a count over positions, for the walk the
+    forward and dq kernels make and for the transposed one of dkv."""
+    allowed = np.tril(np.ones((seq, seq), bool))
+    tiles = allowed.reshape(seq // sq, sq, seq // sk, sk)
+    has_any = tiles.any(axis=(1, 3))
+    has_all = tiles.all(axis=(1, 3))
+    want = (int(has_any.sum()), int((has_any & ~has_all).sum()), has_any.size)
+    assert fa.causal_tile_counts(seq, bq, bk, sq, sk, True) == want
+    run, masked, total = fa.causal_tile_counts(seq, bq, bk, sq, sk, False)
+    assert (run, masked) == (total, 0) and total == want[2]
+
+    # dkv walks q sub-tiles for each key sub-tile: the same tiles, counted
+    # the other way round
+    n_q, n_sq = seq // bq, bq // sq
+    t_run = t_masked = 0
+    for kk in range(seq // bk):
+        j_first, _ = fa._query_walk(kk * bk, bk, 0, bq, n_q)
+        for j in range(j_first, n_q):
+            for c in range(bk // sk):
+                r_first, r_full = fa._query_walk(
+                    kk * bk + c * sk, sk, j * bq, sq, n_sq)
+                t_run += n_sq - r_first
+                t_masked += r_full - r_first
+    assert (t_run, t_masked) == want[:2]
 
 
 def test_functional_selects_flash_and_falls_back():

@@ -104,6 +104,25 @@ def test_flash_attention_kernels_are_named():
         assert name in str(jaxpr), name
 
 
+def test_flash_attention_leaves_one_flash_tiles_event_per_compile():
+    """The walk's tile counts reach the ring when the calls are built (trace
+    time), never from a step that runs the compiled program again."""
+    from paddle_tpu.ops.pallas import flash_attention
+
+    q = jnp.ones((1, 1024, 1, 64), jnp.float32)
+    fn = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    trace.clear()
+    fn(q, q, q).block_until_ready()
+    (ev,) = trace.events(kind="flash_tiles")
+    assert ev.site == "flash_attention"
+    a = ev.attrs
+    assert (a["seq"], a["block_q"], a["block_k"]) == (1024, 1024, 1024)
+    assert 0 < a["masked"] <= a["run"] < a["total"]
+    assert a["total"] == (1024 // a["sub_q"]) * (1024 // a["sub_k"])
+    fn(q, q, q).block_until_ready()
+    assert len(trace.events(kind="flash_tiles")) == 1
+
+
 def test_fused_update_kernel_is_named():
     from paddle_tpu.ops.pallas import fused_update
 

@@ -71,8 +71,9 @@ def _compiled_text(fn, *specs):
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (2, 4096, 16, 64)],
-                         ids=["b8s1024", "b2s4096"])
+@pytest.mark.parametrize(
+    "shape", [(8, 1024, 16, 64), (2, 4096, 16, 64), (4, 1024, 20, 64)],
+    ids=["b8s1024", "b2s4096", "b4s1024h20"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 def test_flash_attention_compiles_for_v5e(one_chip, compiled_kernels, shape,
                                           grad):
