@@ -339,6 +339,10 @@ def _reset_counters_locked():
         serve_expire_stages={},
         flush_reasons={},
         capture_fallback_reasons={},
+        # scaled_dot_product_attention calls that took the dense O(S^2)
+        # path with FLAGS_use_flash_attention on, and why (nn/functional)
+        flash_attention_fallbacks=0,
+        flash_attention_fallback_reasons={},
         fault_sites={},
         perf_regression_sites={},
         telemetry_spike_groups={},
@@ -356,6 +360,14 @@ def _count_program(kind: str = "op"):
         # per-op program launches make a step ineligible for whole-step
         # capture; the observer (when active) marks the step dirty
         _lazy._observe_op_program()
+
+
+def _count_flash_fallback(reason: str, q_shape, k_shape):
+    _counters["flash_attention_fallbacks"] += 1
+    fam = _counters["flash_attention_fallback_reasons"]
+    fam[reason] = fam.get(reason, 0) + 1
+    _emit("flash_fallback", site="scaled_dot_product_attention",
+          reason=reason, q_shape=q_shape, k_shape=k_shape)
 
 
 def dispatch_counters() -> Dict[str, Any]:

@@ -1,4 +1,23 @@
-"""Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts: two expert layers, for two uses.
+
+``MoELayer`` (below, with its gates) is the reference-shaped layer: a
+capacity-bucketed one-hot dispatch [T, E, C] that DROPS what overflows an
+expert's bucket, experts as a list of Layers vmapped over stacked weights. It
+stays for the reference API and its tests; its one-hots grow with T x E x C,
+so it is for small expert counts and short batches.
+
+``DroplessExperts`` (at the end of this file) is the layer a model on the
+chip uses: it is told which experts of ``num_experts`` it HOLDS (this chip's
+share of an expert-parallel deployment), routes over all of them, gathers the
+slots that fall on held experts sorted by expert, multiplies them as grouped
+matrix products (``jax.lax.ragged_dot``, a Mosaic kernel on the TPU) over
+stacked ``[held, ...]`` leaves, scatters back and combines. No slot is
+dropped whatever the imbalance, and every shape is static: the sorted slots
+go through a row buffer of fixed size in as many passes as the routed load
+needs (one, unless routing is badly skewed). On one chip it runs without its
+exchange; there is no ``ep`` mesh axis yet.
+
+What follows describes ``MoELayer``.
 
 Reference analogue:
   - python/paddle/incubate/distributed/models/moe/moe_layer.py:226 MoELayer
@@ -26,11 +45,13 @@ so the whole layer jits into one XLA program.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 import paddle_tpu as paddle
 
@@ -40,7 +61,8 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn.layer_base import Layer
 
-__all__ = ["BaseGate", "NaiveGate", "GShardGate", "SwitchGate", "MoELayer"]
+__all__ = ["BaseGate", "NaiveGate", "GShardGate", "SwitchGate", "MoELayer",
+           "DroplessExperts"]
 
 
 class BaseGate(Layer):
@@ -299,3 +321,223 @@ def global_gather(x, local_count, global_count, group=None,
         "incubate.moe.MoELayer (capacity-padded combine) or "
         "distributed.alltoall on equal splits"
     )
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer: held experts of a wider router, grouped products
+# ---------------------------------------------------------------------------
+def route_top_k(logits, top_k, renormalize):
+    """(weights [T, k] float32, expert ids [T, k]) of the ``top_k`` largest
+    of softmax(logits) in float32; ``renormalize``: the k weights sum to 1."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, idx = jax.lax.top_k(p, top_k)
+    if renormalize:
+        w = w / w.sum(-1, keepdims=True)
+    return w, idx.astype(jnp.int32)
+
+
+def sort_held_slots(idx, first, count):
+    """The T x k routing slots in the order the grouped products want them:
+    slots on held experts [first, first + count) first, sorted by expert, the
+    rest after. Returns (order, inverse, offsets): ``order[i]`` is the flat
+    slot at sorted place i, ``inverse`` its inverse permutation, and
+    ``offsets`` [count + 1] the sorted places at which each held expert's
+    slots start (``offsets[count]`` = slots routed to held experts)."""
+    local = idx.reshape(-1) - np.int32(first)
+    key = jnp.where((local >= 0) & (local < count), local, np.int32(count))
+    place = jnp.arange(key.shape[0], dtype=jnp.int32)
+    key_sorted, order = jax.lax.sort((key, place), num_keys=1)
+    _, inverse = jax.lax.sort((order, place), num_keys=1)
+    offsets = jnp.searchsorted(
+        key_sorted, jnp.arange(count + 1, dtype=jnp.int32), side="left")
+    return order, inverse, offsets.astype(jnp.int32)
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    return x[order]
+
+
+_permute.defvjp(lambda x, order, inverse: (x[order], (order, inverse)),
+                lambda res, g: (g[res[1]], None, None))
+
+
+def _swiglu_groups(xin, w_gate_up, w_down, sizes):
+    """silu(x W_g) * (x W_u), then W_d, each row under its own expert's
+    weights: rows sorted by expert, ``sizes`` rows for each."""
+    h = jax.lax.ragged_dot(xin, w_gate_up, sizes,
+                           preferred_element_type=jnp.float32)
+    gate, up = jnp.split(h, 2, axis=-1)
+    act = (jax.nn.silu(gate) * up).astype(xin.dtype)
+    return jax.lax.ragged_dot(act, w_down, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _pass_rows(c, rows, tok, wgt, offsets):
+    """Pass c of the row buffer: its slots' tokens and weights, which of its
+    rows hold a routed slot, and each held expert's rows inside it."""
+    lo = c * np.int32(rows)
+    at = lo + jnp.arange(rows, dtype=jnp.int32)
+    sizes = (jnp.clip(offsets[1:], lo, lo + rows)
+             - jnp.clip(offsets[:-1], lo, lo + rows))
+    return (jax.lax.dynamic_slice(tok, (lo,), (rows,)),
+            jax.lax.dynamic_slice(wgt, (lo,), (rows,)),
+            (at < offsets[-1])[:, None], sizes, lo)
+
+
+def _n_passes(offsets, rows):
+    return jnp.maximum((offsets[-1] + np.int32(rows - 1)) // np.int32(rows),
+                       np.int32(1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+    """sum over a token's slots on held experts of weight * expert(x): x
+    [T, h]; ``tok`` / ``wgt`` the token and weight of each slot in sorted
+    order, padded to a multiple of ``rows``; ``offsets`` from
+    ``sort_held_slots``. The sorted slots pass through a buffer of ``rows``
+    rows, ceil(routed / rows) times: the count is data, so the loop is a
+    while loop and the backward (which walks the same passes, recomputing
+    each pass's activations) is written out, not derived."""
+    def one_pass(c, y):
+        t, w, valid, sizes, _ = _pass_rows(c, rows, tok, wgt, offsets)
+        out = _swiglu_groups(x[t], w_gate_up, w_down, sizes)
+        return y.at[t].add(jnp.where(valid, out * w[:, None], 0.0))
+
+    y = jax.lax.fori_loop(np.int32(0), _n_passes(offsets, rows), one_pass,
+                          jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _held_fwd(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+    return (held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows),
+            (x, wgt, w_gate_up, w_down, tok, offsets))
+
+
+def _held_bwd(rows, res, dy):
+    x, wgt, w_gate_up, w_down, tok, offsets = res
+
+    def one_pass(c, carry):
+        dx, dwgt, dgu, dd = carry
+        t, w, valid, sizes, lo = _pass_rows(c, rows, tok, wgt, offsets)
+        out, vjp = jax.vjp(
+            lambda xin, a, b: _swiglu_groups(xin, a, b, sizes),
+            x[t], w_gate_up, w_down)
+        dyt = dy[t].astype(jnp.float32)
+        dxin, dgu_c, dd_c = vjp(jnp.where(valid, dyt * w[:, None], 0.0))
+        dw = jnp.where(valid[:, 0], (out * dyt).sum(-1), 0.0)
+        return (dx.at[t].add(jnp.where(valid, dxin.astype(jnp.float32), 0.0)),
+                jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
+                (dgu.astype(jnp.float32) + dgu_c).astype(dgu.dtype),
+                (dd.astype(jnp.float32) + dd_c).astype(dd.dtype))
+
+    dx, dwgt, dgu, dd = jax.lax.fori_loop(
+        np.int32(0), _n_passes(offsets, rows), one_pass,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros(wgt.shape, jnp.float32),
+         jnp.zeros_like(w_gate_up), jnp.zeros_like(w_down)))
+    return dx.astype(x.dtype), dwgt.astype(wgt.dtype), dgu, dd, None, None
+
+
+held_experts_apply.defvjp(_held_fwd, _held_bwd)
+
+
+# The row buffer holds 1.2 times the slots an even router sends to the held
+# experts, in whole 512-row tiles (the grouped product's own): on the chip at
+# 16,384 tokens, 64 of 512 held, ten a token, every step of every run took ONE
+# pass and the products ran over 0.18-0.20 more rows than were routed
+# (PERF.md section 5). Constants, not options: they fix the compiled shapes.
+ROW_SLACK = 1.2
+ROW_TILE = 512
+
+
+def row_buffer_rows(tokens, top_k, num_experts, held):
+    """Rows of the buffer the sorted slots pass through: ``ROW_SLACK`` times
+    the slots an even router sends to ``held`` of ``num_experts`` experts, up
+    to whole tiles, and never more than all the slots there are."""
+    even = tokens * top_k * held / num_experts
+    rows = -(-int(math.ceil(ROW_SLACK * even)) // ROW_TILE) * ROW_TILE
+    return max(8, min(rows, -(-tokens * top_k // 8) * 8))
+
+
+def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
+                     shared_down, shared_gate, *, first, top_k, renormalize,
+                     rows):
+    """The whole expert layer on [T, h] tokens: (y, routed_slots,
+    expert_rows). Routes over all of the router's experts; adds, for each
+    token, its held experts' weighted outputs and the gated shared expert.
+    What the absent experts would have added is left out."""
+    from ..profiler import trace
+
+    tokens, count = x.shape[0], w_gate_up.shape[0]
+    trace.emit("moe_route", site="dropless_experts", held=count,
+               num_experts=router.shape[1], top_k=top_k, buffer_rows=rows,
+               tokens=tokens)
+    with jax.named_scope("router"):
+        logits = jnp.matmul(x, router, preferred_element_type=jnp.float32)
+        w, idx = route_top_k(logits, top_k, renormalize)
+        order, inverse, offsets = sort_held_slots(idx, first, count)
+        pad = -(-order.shape[0] // rows) * rows - order.shape[0]
+        tok = jnp.pad(order // np.int32(top_k), (0, pad))
+        wgt = jnp.pad(_permute(w.reshape(-1), order, inverse), (0, pad))
+        routed = offsets[-1]
+    with jax.named_scope("experts"):
+        y = held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows)
+    with jax.named_scope("shared_expert"):
+        gate, up = jnp.split(jnp.matmul(x, shared_gate_up), 2, axis=-1)
+        shared = jnp.matmul(jax.nn.silu(gate) * up, shared_down)
+        open_ = jax.nn.sigmoid(jnp.matmul(
+            x, shared_gate, preferred_element_type=jnp.float32))
+        y = y + (shared * open_).astype(x.dtype)
+    return y, routed, _n_passes(offsets, rows) * np.int32(rows)
+
+
+class DroplessExperts(Layer):
+    """Sparse SwiGLU experts with a gated shared expert, dropless, for a
+    chip that holds ``held = (first, count)`` of ``num_experts`` experts.
+
+    Parameters: ``router`` [h, num_experts] (the published width, whatever
+    is held), ``w_gate_up`` [count, h, 2 d] and ``w_down`` [count, d, h]
+    (stacked leaves, not 3 x count arrays), ``shared_gate_up`` [h, 2 d_s],
+    ``shared_down`` [d_s, h], ``shared_gate`` [h, 1]. Two int32 buffers ride
+    a compiled step like a running statistic and hold, after each forward,
+    ``routed_slots`` (slots that fell on held experts) and ``expert_rows``
+    (rows of the row buffer the grouped products were given: passes x the
+    buffer's rows); one ``moe_route`` event is left in the flight recorder
+    per trace."""
+
+    def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
+                 d_shared=None, renormalize=True, weight_attr=None):
+        super().__init__()
+        first, count = held if held is not None else (0, num_experts)
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise ValueError(f"held {held} is no range of {num_experts}")
+        self.first, self.count = int(first), int(count)
+        self.num_experts, self.top_k = num_experts, top_k
+        self.renormalize = renormalize
+        d_shared = d_shared or d_expert
+
+        def make(*shape):
+            return self.create_parameter(shape=list(shape), attr=weight_attr)
+
+        self.router = make(d_model, num_experts)
+        self.w_gate_up = make(self.count, d_model, 2 * d_expert)
+        self.w_down = make(self.count, d_expert, d_model)
+        self.shared_gate_up = make(d_model, 2 * d_shared)
+        self.shared_down = make(d_shared, d_model)
+        self.shared_gate = make(d_model, 1)
+        self.register_buffer("routed_slots", Tensor(np.int32(0)))
+        self.register_buffer("expert_rows", Tensor(np.int32(0)))
+
+    def forward(self, x):
+        shape = list(x.shape)
+        flat = x.reshape([-1, shape[-1]])
+        rows = row_buffer_rows(flat.shape[0], self.top_k, self.num_experts,
+                               self.count)
+        y, routed, ran = apply(
+            dropless_experts, flat, self.router, self.w_gate_up, self.w_down,
+            self.shared_gate_up, self.shared_down, self.shared_gate,
+            first=self.first, top_k=self.top_k, renormalize=self.renormalize,
+            rows=rows, op_name="dropless_experts")
+        self.routed_slots._value = routed._value
+        self.expert_rows._value = ran._value
+        return y.reshape(shape)

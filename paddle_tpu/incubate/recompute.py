@@ -15,6 +15,7 @@ state by hand for this).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -23,7 +24,13 @@ from ..core.dispatch import apply, no_grad
 from ..core.tensor import Tensor
 from ..core import random as _random
 
-__all__ = ["recompute", "recompute_sequential"]
+__all__ = ["recompute", "recompute_sequential", "KEEP_NAME"]
+
+# A value an op tags jax.ad_checkpoint.checkpoint_name(x, KEEP_NAME) is kept
+# for the backward of a recomputed segment; everything else is made again.
+# (ops/linear_attention.py tags the chunk inverse: 64 KiB a chunk in bf16 and
+# a tenth of a linear-attention layer's forward to make.)
+KEEP_NAME = "recompute_keep"
 
 
 def recompute(function: Callable, *args, **kwargs):
@@ -47,7 +54,9 @@ def recompute(function: Callable, *args, **kwargs):
     tensor_args = [a for a in args if isinstance(a, Tensor)]
     n_params = len(seg_params)
 
-    @jax.checkpoint
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(KEEP_NAME))
     def ckpt(key, p_vals, arg_vals):
         rebuilt = []
         it = iter(arg_vals)

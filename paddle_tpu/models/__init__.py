@@ -16,3 +16,8 @@ from .gpt import (  # noqa: F401
     gpt2_345m,
 )
 from .bert import BertConfig, BertForPretraining, BertModel  # noqa: F401
+from .qwen3_next import (  # noqa: F401
+    Qwen3NextConfig,
+    Qwen3NextForCausalLM,
+    Qwen3NextModel,
+)

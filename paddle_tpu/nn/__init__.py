@@ -32,7 +32,7 @@ from .layer.loss import (  # noqa: F401
 )
 from .layer.norm import (  # noqa: F401
     BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D, GroupNorm,
-    InstanceNorm1D, InstanceNorm2D, InstanceNorm3D, LayerNorm,
+    InstanceNorm1D, InstanceNorm2D, InstanceNorm3D, LayerNorm, RMSNorm,
     LocalResponseNorm, SpectralNorm, SyncBatchNorm,
 )
 from .layer.pooling import (  # noqa: F401

@@ -674,6 +674,20 @@ def unfold(x, kernel_sizes, strides=1, paddings=0, dilations=1, name=None):
 
 
 # ----------------------------- attention -------------------------------------
+def rms_norm(x, weight, epsilon=1e-6, zero_centered=False, name=None):
+    """RMSNorm over the last axis; ``zero_centered``: the gain is 1 + weight."""
+    return apply(_nn.rms_norm, x, weight, epsilon=epsilon,
+                 zero_centered=zero_centered, op_name="rms_norm")
+
+
+def rotary_embedding(x, rotary_dim=None, theta=10000.0, name=None):
+    """Rotary positions on the first ``rotary_dim`` dims of each head of
+    ``x`` [batch, seq, heads, head_dim] (all of them by default)."""
+    return apply(_nn.rotary_embedding, x,
+                 rotary_dim=int(rotary_dim or x.shape[-1]), theta=float(theta),
+                 op_name="rotary_embedding")
+
+
 def scaled_dot_product_attention(
     query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
     training=True, name=None,
@@ -692,17 +706,26 @@ def scaled_dot_product_attention(
     # sharded programs keep the dense einsum path, which GSPMD partitions.
     _mesh = _topo.get_mesh()
     _single_device = _mesh is None or _mesh.devices.size == 1
-    if (
-        _flags.flag("use_flash_attention")
-        and _single_device
-        and attn_mask is None
-        and dropout_key is None
-        and _nn.flash_attention_eligible(query.shape, key.shape, value.shape)
-    ):
-        return apply(
-            _nn.flash_scaled_dot_product_attention, query, key, value,
-            is_causal=is_causal, op_name="flash_sdpa",
+    if _flags.flag("use_flash_attention"):
+        # why the dense O(S^2) path is taken, where it is: counted
+        # (`flash_attention_fallbacks`, by reason) and left in the flight
+        # recorder, so that a fallback at a long sequence cannot pass unseen
+        refusal = (
+            "multi_device_mesh" if not _single_device
+            else "attn_mask" if attn_mask is not None
+            else "attention_dropout" if dropout_key is not None
+            else _nn.flash_attention_refusal(query.shape, key.shape,
+                                             value.shape)
         )
+        if refusal is None:
+            return apply(
+                _nn.flash_scaled_dot_product_attention, query, key, value,
+                is_causal=is_causal, op_name="flash_sdpa",
+            )
+        from ...core import dispatch as _dispatch
+
+        _dispatch._count_flash_fallback(
+            refusal, tuple(query.shape), tuple(key.shape))
     return apply(
         _nn.scaled_dot_product_attention, query, key, value, attn_mask,
         dropout_key, is_causal=is_causal, dropout_p=dropout_p, op_name="sdpa",

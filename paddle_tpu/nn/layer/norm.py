@@ -90,6 +90,24 @@ class SyncBatchNorm(_BatchNormBase):
         return out
 
 
+class RMSNorm(Layer):
+    """x / rms(x) * gain over the last axis (statistics in float32).
+    ``zero_centered``: the parameter is stored round 0 and the gain is
+    ``1 + weight``."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, zero_centered=False,
+                 weight_attr=None, name=None):
+        super().__init__()
+        self._epsilon = epsilon
+        self._zero_centered = zero_centered
+        self.weight = self.create_parameter(
+            shape=[hidden_size], attr=weight_attr,
+            default_initializer=I.Constant(0.0 if zero_centered else 1.0))
+
+    def forward(self, x):
+        return F.rms_norm(x, self.weight, self._epsilon, self._zero_centered)
+
+
 class LayerNorm(Layer):
     def __init__(self, normalized_shape, epsilon=1e-05, weight_attr=None,
                  bias_attr=None, name=None):

@@ -433,9 +433,34 @@ def layer_norm(x, weight=None, bias=None, *, epsilon=1e-5, begin_norm_axis=-1):
     return out
 
 
-def rms_norm(x, weight, *, epsilon=1e-6):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + epsilon) * weight
+def rms_norm(x, weight, *, epsilon=1e-6, zero_centered=False):
+    """x / rms(x) * gain over the last axis, the statistics in float32
+    whatever ``x`` is held in. ``zero_centered``: the gain is ``1 + weight``
+    (the parameter is stored round 0, as the present-day decoders do)."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    gain = weight.astype(jnp.float32)
+    if zero_centered:
+        gain = 1.0 + gain
+    return (xf * jax.lax.rsqrt(var + epsilon) * gain).astype(x.dtype)
+
+
+def rotary_embedding(x, *, rotary_dim, theta=10000.0):
+    """Rotary positions on the first ``rotary_dim`` dims of each head of
+    ``x`` [batch, seq, heads, head_dim], the rest passed through: dim i is
+    paired with dim i + rotary_dim / 2 (the half-split convention), position
+    p (from 0) turns pair i by p * theta ** (-2 i / rotary_dim). Angles in
+    float32."""
+    s, half = x.shape[1], rotary_dim // 2
+    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32)
+                                * (2.0 / rotary_dim)))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([turned.astype(x.dtype), x[..., rotary_dim:]], -1)
 
 
 def group_norm(x, weight=None, bias=None, *, num_groups, epsilon=1e-5, data_format="NCHW"):
@@ -731,6 +756,10 @@ def scaled_dot_product_attention(
     qf = jnp.swapaxes(q, 1, 2)  # [b, h, s, d]
     kf = jnp.swapaxes(k, 1, 2)
     vf = jnp.swapaxes(v, 1, 2)
+    if kf.shape[1] != qf.shape[1]:  # grouped-query heads: head i on i // group
+        group = qf.shape[1] // kf.shape[1]
+        kf = jnp.repeat(kf, group, axis=1)
+        vf = jnp.repeat(vf, group, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * s
     if is_causal:
         ql, kl = logits.shape[-2], logits.shape[-1]
@@ -855,14 +884,29 @@ def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False):
     return _flash(q, k, v, scale=s, causal=is_causal)
 
 
-def flash_attention_eligible(q_shape, k_shape, v_shape) -> bool:
+def flash_attention_refusal(q_shape, k_shape, v_shape):
+    """Why the flash kernel cannot take these [batch, seq, heads, head_dim]
+    shapes, as a short reason, or None where it can. k and v may have fewer
+    heads than q (grouped-query heads: a divisor of q's)."""
     from .pallas.flash_attention import supports as _supports
 
-    return (
-        tuple(q_shape) == tuple(k_shape) == tuple(v_shape)
-        and len(q_shape) == 4
-        and _supports(q_shape[1], q_shape[3])
-    )
+    q_shape, k_shape, v_shape = map(tuple, (q_shape, k_shape, v_shape))
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        return "rank"
+    if k_shape != v_shape:
+        return "k_v_shapes_differ"
+    if (q_shape[0], q_shape[1], q_shape[3]) != (k_shape[0], k_shape[1],
+                                                k_shape[3]):
+        return "q_kv_lengths_differ"
+    if k_shape[2] == 0 or q_shape[2] % k_shape[2]:
+        return "kv_heads_do_not_divide_q_heads"
+    if not _supports(q_shape[1], q_shape[3]):
+        return "seq_or_head_dim_not_tiled"
+    return None
+
+
+def flash_attention_eligible(q_shape, k_shape, v_shape) -> bool:
+    return flash_attention_refusal(q_shape, k_shape, v_shape) is None
 
 
 # ---------------------------------------------------------------------------

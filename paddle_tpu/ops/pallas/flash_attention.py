@@ -150,13 +150,16 @@ def _compiler_params(dims):
     return pltpu.CompilerParams(dimension_semantics=dims)
 
 
-def _at_block_offset(n_q, n_k, q_axis, k_axis, bq, bk, causal, walk):
+def _at_block_offset(n_q, n_k, q_axis, k_axis, bq, bk, causal, walk,
+                     q_wraps=False):
     """Call walk(off) with off = the resident block's first row less its first
     column as a Python int, or None for a block wholly under the diagonal, so
     that every bound of the walk inside the block is static and the walk
     unrolls. Where the grid has several blocks there is one predicated copy
     of the walk for each offset at which the diagonal crosses a block, one
-    for all blocks wholly under it, and none for a block above it (skipped)."""
+    for all blocks wholly under it, and none for a block above it (skipped).
+    ``q_wraps``: the q axis walks the q blocks once for each query head of a
+    group (dkv under grouped-query heads), so the block is its index mod n_q."""
     if not causal:
         walk(None)
         return
@@ -164,7 +167,10 @@ def _at_block_offset(n_q, n_k, q_axis, k_axis, bq, bk, causal, walk):
     if len(offs) == 1:
         walk(0)
         return
-    off = pl.program_id(q_axis) * bq - pl.program_id(k_axis) * bk
+    j = pl.program_id(q_axis)
+    if q_wraps:
+        j = jax.lax.rem(j, np.int32(n_q))
+    off = j * bq - pl.program_id(k_axis) * bk
     for d in sorted(d for d in offs if -bq < d < bk - 1):
         pl.when(off == d)(functools.partial(walk, d))
     if max(offs) >= bk - 1:
@@ -197,15 +203,19 @@ def _k_strips(off, bq, bk, sq, sk):
             yield slice(c * sk, (c + 1) * sk), r_first * sq, r_full * sq
 
 
-def _kv_index(causal, bq, bk, n_k):
+def _kv_index(causal, bq, bk, n_k, group=1):
     """Index map of a k/v block under grid (head, q block j, k block kk). A
     step above the diagonal is skipped in the kernel: give it the index of
     the last step that runs, so that Pallas sees no change and copies
-    nothing."""
+    nothing. Under grouped-query heads (``group`` query heads on one KV
+    head) query head i reads KV head i // group: k and v are never copied
+    out to the query heads."""
     def index(i, j, kk):
         if causal and n_k > 1:
             _, k_steps = _key_walk(j * bq, bq, 0, bk, n_k, causal)
             kk = jnp.minimum(kk, k_steps - 1)
+        if group > 1:
+            i = jax.lax.div(i, np.int32(group))
         return (i, kk, _0)
     return index
 
@@ -327,10 +337,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                     slice(None))
 
 
-def _fwd(q, k, v, scale, causal, bq, bk, sq, sk):
+def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1):
     bh, s, d = q.shape
     n_q, n_k = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk, n_k)
+    kv_index = _kv_index(causal, bq, bk, n_k, group)
     # one k step: the running (m, l, acc) never leave the step's registers
     scratch = [] if n_k == 1 else [
         pltpu.VMEM((bq, 128), jnp.float32),
@@ -367,9 +377,10 @@ def _fwd(q, k, v, scale, causal, bq, bk, sq, sk):
 # ---------------------------------------------------------------------------
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *scratch,
-                    scale, causal, bq, bk, sq, sk, n_q, n_k):
+                    scale, causal, bq, bk, sq, sk, n_q, n_k, group=1):
     scale = np.float32(scale)
-    if scratch:  # (dk, dv) carried from one q step to the next
+    if scratch:  # (dk, dv) carried from one q step to the next, and from one
+        # query head of the group to the next: they come out summed over it
         dk_scr, dv_scr = scratch
 
         @pl.when(pl.program_id(2) == 0)
@@ -402,10 +413,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
                 dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
 
-    _at_block_offset(n_q, n_k, 2, 1, bq, bk, causal, walk)
+    _at_block_offset(n_q, n_k, 2, 1, bq, bk, causal, walk, q_wraps=group > 1)
 
     if scratch:
-        @pl.when(pl.program_id(2) == n_q - 1)
+        @pl.when(pl.program_id(2) == group * n_q - 1)
         def _():
             dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -447,8 +458,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk):
-    bh, s, d = q.shape
+def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
+    bh, s, d = k.shape  # the grid's heads are the KV heads
     n_q, n_k = s // bq, s // bk
 
     def q_index(kk, j):
@@ -459,21 +470,40 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk):
             j = jnp.maximum(j, j_first)
         return j
 
+    if group == 1:
+        def q_head(i, t):
+            return i
+
+        def q_block(kk, t):
+            return q_index(kk, t)
+    else:
+        # the last grid axis walks the group's query heads, each over its q
+        # blocks: KV head i is attended by query heads i * group + t // n_q
+        def q_head(i, t):
+            return i * np.int32(group) + jax.lax.div(t, np.int32(n_q))
+
+        def q_block(kk, t):
+            return q_index(kk, jax.lax.rem(t, np.int32(n_q)))
+
     # one q step: dk and dv never leave the step's registers
-    scratch = [] if n_q == 1 else [pltpu.VMEM((bk, d), jnp.float32),
-                                   pltpu.VMEM((bk, d), jnp.float32)]
+    scratch = [] if group * n_q == 1 else [pltpu.VMEM((bk, d), jnp.float32),
+                                           pltpu.VMEM((bk, d), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, group=group),
         name="flash_attention_bwd_dkv",
-        grid=(bh, n_k, n_q),
+        grid=(bh, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, kk, j: (i, q_index(kk, j), _0)),
+            pl.BlockSpec((1, bq, d),
+                         lambda i, kk, j: (q_head(i, j), q_block(kk, j), _0)),
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
-            pl.BlockSpec((1, bq, d), lambda i, kk, j: (i, q_index(kk, j), _0)),
-            pl.BlockSpec((1, 1, bq), lambda i, kk, j: (i, _0, q_index(kk, j))),
-            pl.BlockSpec((1, 1, bq), lambda i, kk, j: (i, _0, q_index(kk, j))),
+            pl.BlockSpec((1, bq, d),
+                         lambda i, kk, j: (q_head(i, j), q_block(kk, j), _0)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda i, kk, j: (q_head(i, j), _0, q_block(kk, j))),
+            pl.BlockSpec((1, 1, bq),
+                         lambda i, kk, j: (q_head(i, j), _0, q_block(kk, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
@@ -489,10 +519,10 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk):
     )(q, k, v, do, lse, delta)
 
 
-def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk):
+def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
     bh, s, d = q.shape
     n_q, n_k = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk, n_k)
+    kv_index = _kv_index(causal, bq, bk, n_k, group)
     scratch = [] if n_k == 1 else [pltpu.VMEM((bq, d), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq,
@@ -515,10 +545,11 @@ def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk):
     )(q, k, v, do, lse, delta)
 
 
-def _bwd(scale, causal, bq, bk, sq, sk, res, do):
+def _bwd(scale, causal, bq, bk, sq, sk, group, res, do):
     q, k, v, out, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None, :]
-    sizes = dict(scale=scale, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk)
+    sizes = dict(scale=scale, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
+                 group=group)
     dk, dv = _dkv(q, k, v, do, lse, delta, **sizes)
     dq = _dq(q, k, v, do, lse, delta, **sizes)
     return dq, dk, dv
@@ -527,14 +558,14 @@ def _bwd(scale, causal, bq, bk, sq, sk, res, do):
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, bq, bk, sq, sk):
-    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, scale, causal, bq, bk, sq, sk, group):
+    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk):
-    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk)
+def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk, group):
+    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group)
     return out, (q, k, v, out, lse)
 
 
@@ -563,6 +594,11 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None, block_k=1
     """Streaming attention over [batch, seq, heads, head_dim] inputs
     (paddle fused_attention layout, matching scaled_dot_product_attention).
 
+    Grouped-query heads: k and v may have fewer heads than q, a divisor of
+    q's; query head i attends KV head i // group through the kernels' index
+    maps (no copy of k and v per query head is made), and dk and dv come out
+    summed over the group.
+
     Default blocks and the sub-tiles inside them follow the shape; the chip
     readings they were chosen from are PERF.md §5, "flash attention sub-tile
     sweep". Each trace leaves one ``flash_tiles`` event in the flight
@@ -570,6 +606,12 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None, block_k=1
     how many of those with a mask.
     """
     b, s, h, d = q.shape
+    h_kv = k.shape[2]
+    if h % h_kv or v.shape != k.shape:
+        raise ValueError(
+            f"flash_attention: {h} query heads on k {tuple(k.shape)} / v "
+            f"{tuple(v.shape)}: the KV heads must divide the query heads")
+    group = h // h_kv
     if block_q is None:
         block_q = _default_block_q(s)
     bq = min(block_q, s)
@@ -591,8 +633,8 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None, block_k=1
                total=total)
 
     def to_bh(x):
-        return jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
+        return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], s, d)
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), np.float32(scale), bool(causal),
-                 bq, bk, sq, sk)
+                 bq, bk, sq, sk, group)
     return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)

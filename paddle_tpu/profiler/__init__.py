@@ -246,24 +246,27 @@ class Profiler:
             json.dump(doc, f, default=str)
         return path
 
-    def device_view(self):
+    def device_view(self, layer_depth=2):
         """Where the device time of the traced stretch went, read from the
         ``.xplane.pb`` this profiler wrote (statistic.device_view): seconds
-        by section, by layer path and by named kernel. None when nothing was
-        traced (``timer_only``, or no RECORD state reached yet)."""
+        by section, by layer path (``layer_depth`` names below the root
+        layer: 2 gives ``layers.*/attn``, 3 ``layers.*/mixer/short_conv``)
+        and by named kernel. None when nothing was traced (``timer_only``,
+        or no RECORD state reached yet)."""
         from .statistic import device_view
 
         if self._device_dir is None or self._tracing:
             return None
-        if self._view is None or self._view[0] != self._device_dir:
+        key = (self._device_dir, layer_depth)
+        if self._view is None or self._view[0] != key:
             paths = glob.glob(os.path.join(
                 self._device_dir, "plugins", "profile", "*", "*.xplane.pb"))
-            self._view = (self._device_dir,
-                          device_view(paths) if paths else None)
+            self._view = (key, device_view(paths, layer_depth)
+                          if paths else None)
         return self._view[1]
 
     def summary(self, sorted_by=SortedKeys.CPUTotal, op_detail=True,
-                thread_sep=False, time_unit="ms", views=None):
+                thread_sep=False, time_unit="ms", views=None, layer_depth=2):
         """reference: profiler_statistic.py — Overview + Operator report of
         the host spans in the ring (those begun since ``start()``; all of
         them for a profiler never started), then the device view (ModelView by
@@ -280,7 +283,7 @@ class Profiler:
             SortedKeys.CPUMax: "max",
         }.get(sorted_by, "total")
         table = build_summary_report(events, sorted_by=key, time_unit=time_unit)
-        view = self.device_view()
+        view = self.device_view(layer_depth)
         if view is not None:
             table += "\n\n" + build_device_report(view)
         print(table)

@@ -295,6 +295,7 @@ _DISPATCH_GAUGES = frozenset(("ckpt_auto_save_freq",))
 _DISPATCH_LABEL_KEYS = {
     "flush_reasons": "reason",
     "capture_fallback_reasons": "reason",
+    "flash_attention_fallback_reasons": "reason",
     "fault_sites": "site",
     "serve_shed_reasons": "reason",
     "serve_expire_stages": "stage",

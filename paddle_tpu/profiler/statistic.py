@@ -262,15 +262,20 @@ def _self_seconds(events):
     return out
 
 
-def classify_scope(op_name: str):
+def classify_scope(op_name: str, depth: int = _LAYER_DEPTH):
     """(section, layer path or None, direction) of one op_name.
 
     ``jit(step_fn)/transpose(jvp(forward))/GPT/layers.3/attn/jit(linear)/dot_general``
     -> ("backward", "layers.*/attn", "backward"). The section is the first
     component that names one of compile_train_step's scopes under any
     transforms; ``transpose`` among them means backward, a later
-    ``rematted_computation`` means recompute. The layer path is the run of
-    plain scope names that follows, below the root layer, indices collapsed."""
+    ``rematted_computation`` means recompute. The layer path is the plain
+    scope names that follow (a ``jit(...)``, ``checkpoint`` or
+    ``rematted_computation`` among them is stepped over, so that a scope
+    opened inside a jitted op, or a recomputed sub-layer, keeps its name;
+    where a section's name comes again, a trace inside names its path from
+    the root anew), below the root layer, indices collapsed, ``depth`` names
+    deep."""
     parts = op_name.rstrip(":").split("/")
     for i, part in enumerate(parts):
         transforms, inner = [], part
@@ -290,10 +295,16 @@ def classify_scope(op_name: str):
             section = direction
         layers = []
         for comp in rest:
-            if "(" in comp or comp in ("checkpoint", "rematted_computation"):
-                break
+            if "(" in comp:
+                while (m := _TRANSFORM.fullmatch(comp)):
+                    comp = m[2]
+                if comp in _SCOPES:  # a trace inside (a recomputed segment's
+                    layers = []      # backward) names its path from the root
+                continue
+            if comp in ("checkpoint", "rematted_computation"):
+                continue
             layers.append(re.sub(r"(^|\.)\d+$", r"\1*", comp))
-        below_root = layers[1:1 + _LAYER_DEPTH]
+        below_root = layers[1:1 + depth]
         path = "/".join(below_root) if below_root else (
             layers[0] if layers else None)
         return section, path, direction
@@ -328,9 +339,11 @@ def _op_lines(data, hlo_ops):
     return lines
 
 
-def device_view(paths: Iterable[str]) -> Optional[dict]:
-    """Seconds of device time by section, by layer path (forward / backward)
-    and by named kernel, over the ``.xplane.pb`` files of one traced stretch.
+def device_view(paths: Iterable[str],
+                layer_depth: int = _LAYER_DEPTH) -> Optional[dict]:
+    """Seconds of device time by section, by layer path (forward / backward;
+    ``layer_depth`` names below the root) and by named kernel, over the
+    ``.xplane.pb`` files of one traced stretch.
     Each op's self time is booked once, so the sections sum to ``busy_s``
     (chip-seconds, or thread-seconds on the CPU). None without device ops."""
     from jax.profiler import ProfileData
@@ -361,7 +374,7 @@ def device_view(paths: Iterable[str]) -> Optional[dict]:
                     sections["unscoped"] += secs
                     unscoped_ops[_stable_name(name)] += secs
                     continue
-                section, layer, direction = classify_scope(scope)
+                section, layer, direction = classify_scope(scope, layer_depth)
                 sections[section] += secs
                 if layer is not None:
                     layers[layer][direction] += secs

@@ -341,8 +341,12 @@ def test_device_view_of_a_tpu_shaped_trace(tmp_path):
      ("forward", "layers.*/attn", "forward")),
     ("jit(step_fn)/transpose(jvp(forward))/GPT/embeddings/word_embeddings/jit(embedding)/gather:",
      ("backward", "embeddings/word_embeddings", "backward")),
+    # a recomputed sub-layer keeps its name: checkpoint and
+    # rematted_computation are stepped over, as a jit(...) is
     ("jit(step_fn)/transpose(jvp(forward))/GPT/layers.0/checkpoint/rematted_computation/mlp/mul",
-     ("recompute", "layers.*", "backward")),
+     ("recompute", "layers.*/mlp", "backward")),
+    ("jit(step_fn)/jvp(forward)/LM/layers.1/mixer/jit(_gated_delta_mixer)/short_conv/mul",
+     ("forward", "layers.*/mixer", "forward")),
     ("jit(step_fn)/jvp(forward)/GPT/lm_head/jit(matmul)/dot_general",
      ("forward", "lm_head", "forward")),
     ("jit(step_fn)/jvp(forward)/Seq/0/jit(linear)/dot_general",
@@ -357,3 +361,19 @@ def test_device_view_of_a_tpu_shaped_trace(tmp_path):
 ])
 def test_classify_scope(op_name, want):
     assert statistic.classify_scope(op_name) == want
+
+
+def test_classify_scope_depth():
+    name = ("jit(step_fn)/transpose(jvp(forward))/LM/layers.1/"
+            "jit(recompute:mix)/checkpoint/rematted_computation/mixer/"
+            "jit(_gated_delta_mixer)/gated_delta_rule/dot_general")
+    assert statistic.classify_scope(name, 3) == (
+        "recompute", "layers.*/mixer/gated_delta_rule", "backward")
+    assert statistic.classify_scope(name, 1) == (
+        "recompute", "layers.*", "backward")
+    # the backward of a recomputed segment repeats the path inside itself
+    name = ("jit(step_fn)/transpose(jvp(forward))/LM/layers.1/jvp(forward)/"
+            "LM/layers.1/checkpoint/mixer/in_proj_qkvz/jit(linear)/"
+            "dot_general")
+    assert statistic.classify_scope(name, 3) == (
+        "backward", "layers.*/mixer/in_proj_qkvz", "backward")

@@ -1,0 +1,405 @@
+"""The sparse hybrid decoder (models/qwen3_next.py) and the layers it brought
+— the chunked gated delta rule, grouped-query flash attention, the dropless
+held-expert layer — against the plain reference kept with the benchmark
+(benchmark/lib/reference_qwen3_next.py: token-by-token recurrence, dense
+masked softmax, experts as masks), at small sizes on the CPU in float32."""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+import paddle_tpu.nn.functional as F
+from benchmark.lib import program_qwen3_next as prog
+from benchmark.lib import reference_qwen3_next as ref
+from benchmark.lib import weights_qwen3_next as weights
+from paddle_tpu.incubate import moe
+from paddle_tpu.models import GPTPretrainingCriterion
+from paddle_tpu.ops import linear_attention as la
+from paddle_tpu.profiler import trace
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+SIZES = dict(
+    num_hidden_layers=4, full_attention_interval=4, hidden_size=64,
+    vocab_size=512, num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+    partial_rotary_factor=0.25, rope_theta=1e7, linear_num_key_heads=2,
+    linear_num_value_heads=4, linear_key_head_dim=16,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4, num_experts=4,
+    router_experts=16, held_first=4, num_experts_per_tok=4,
+    moe_intermediate_size=32, shared_expert_intermediate_size=32,
+    norm_topk_prob=True, rms_norm_eps=1e-6, recompute_mixer=False)
+SEED = 2**31 + 11
+
+
+def batch(rows=2, seq=128, seed=0):
+    ids = np.random.default_rng(seed).integers(
+        0, SIZES["vocab_size"], (rows, seq + 1)).astype(np.int32)
+    return jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
+
+
+@pytest.fixture(scope="module")
+def both():
+    _, model = prog.build_model(SIZES)
+    prog.seed_weights(model, SIZES, SEED, "float32")
+    return model, weights.make(SIZES, SEED, "float32")
+
+
+# ---------------------------------------------------------------------------
+# the model against the reference
+# ---------------------------------------------------------------------------
+def test_logits_loss_and_every_leafs_gradient_agree(both):
+    model, w = both
+    x, y = batch()
+    out = model(paddle.Tensor(x))
+    want = ref.logits(w, x, SIZES)
+    assert float(jnp.abs(out._value - want).max()) < 2e-5
+    loss = GPTPretrainingCriterion()(out, paddle.Tensor(y))
+    ref_loss, grads = ref.loss_and_grads(w, x, y, SIZES)
+    assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+    loss.backward()
+    seen = set()
+    for name, p in model.named_parameters():
+        leaf = prog.flat_name(name)
+        seen.add(leaf)
+        g = grads[leaf]
+        scale = float(jnp.abs(g).max())
+        assert scale > 0, f"{leaf}: the reference gives it no gradient"
+        # float32 round-off: the decay's gradient is a sum of 1e-6 that
+        # cancels to 1e-9 in places
+        assert float(jnp.abs(p.grad._value - g).max()) < 2e-3 * scale + 1e-8, \
+            leaf
+    assert seen == set(grads)
+
+
+def test_pinned_routing_and_each_sides_choice(both):
+    """What ``benchmark/tools/routing.py`` stands on. In float32 the program's
+    routers (walked eagerly) and the reference's choose the same experts; the
+    reference pinned to its own choice is the reference; pinned to another
+    choice it runs those experts, under its own probabilities of them."""
+    model, w = both
+    ids = np.asarray(batch()[0])
+    x, y = batch()
+    own = ref.routed_experts(SIZES, SEED, ids, "float32")
+    chosen = prog.routed_experts(model, ids)
+    top_k = SIZES["num_experts_per_tok"]
+    assert len(own) == len(chosen) == SIZES["num_hidden_layers"]
+    for a, b in zip(chosen, own):
+        assert a.shape == b.shape == (ids.size, top_k)
+        # a near-tie may swap: all but a handful of slots are the same
+        assert (np.sort(a, -1) != np.sort(b, -1)).mean() < 2e-3
+    loss, grads = ref.loss_and_grads(w, x, y, SIZES)
+    pinned_loss, pinned = ref.loss_and_grads(w, x, y, SIZES, pinned=own)
+    assert float(pinned_loss) == pytest.approx(float(loss), rel=1e-6)
+    for leaf, g in grads.items():
+        np.testing.assert_allclose(
+            pinned[leaf], g, atol=1e-5 * float(jnp.abs(g).max()), err_msg=leaf)
+    # every token sent to experts 0..k-1: another loss
+    other = [jnp.broadcast_to(jnp.arange(top_k), a.shape) for a in own]
+    other_loss, _ = ref.loss_and_grads(w, x, y, SIZES, pinned=other)
+    assert abs(float(other_loss) - float(loss)) > 1e-6
+    layer0 = {k[:-2]: a for k, a in w.items() if k.endswith(".0")}
+    m = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (ids.size, SIZES["hidden_size"])), jnp.float32)
+    held = (SIZES["held_first"], SIZES["num_experts"])
+    we = ref.held_weights(m, layer0["router"], SIZES, held,
+                          ref.exact_operands, None, other[0])
+    assert we.shape == (ids.size, SIZES["num_experts"])
+    assert int((we > 0).sum()) == 0  # ids 0..3 lie under the held 4..7
+
+
+def test_recomputed_mixer_gives_the_same_step(both):
+    """``use_recompute`` drops the mixers' activations only: same loss, and
+    the counters still written once by the forward."""
+    x, y = batch()
+    losses = []
+    for recompute in (False, True):
+        _, model = prog.build_model(dict(SIZES, recompute_mixer=recompute))
+        prog.seed_weights(model, SIZES, SEED, "float32")
+        opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                     parameters=model.parameters())
+        crit = GPTPretrainingCriterion()
+        step = paddle.jit.compile_train_step(model, crit, opt)
+        losses.append([float(step(paddle.Tensor(x), paddle.Tensor(y)))
+                       for _ in range(2)])
+        assert all(r > 0 for _, r, _ in model.routed_load())
+    assert losses[0] == pytest.approx(losses[1], rel=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the chunked gated delta rule against the token recurrence
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("decay", [1e-3, 1.0, 12.0],
+                         ids=["near_one", "middling", "near_zero"])
+@pytest.mark.parametrize("seq,chunk", [(192, 64), (64, 16), (48, 64)])
+def test_chunked_delta_rule_matches_recurrence(seq, chunk, decay):
+    rng = np.random.default_rng(seq + chunk)
+    b, hk, hv, dk, dv = 2, 2, 4, 32, 16
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    q = la.l2_normalize(draw(b, seq, hk, dk)) / np.sqrt(dk)
+    k = la.l2_normalize(draw(b, seq, hk, dk))
+    v, ct = draw(b, seq, hv, dv), draw(b, seq, hv, dv)
+    g = -decay * jnp.asarray(rng.random((b, seq, hv)), jnp.float32)
+    beta = jnp.asarray(rng.random((b, seq, hv)), jnp.float32)
+
+    def recurrence(q, k, v, g, beta):
+        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
+        return ref.delta_rule(q, k, v, g, beta)
+
+    def chunked(*a):
+        return la.gated_delta_rule(*a, chunk=chunk)
+
+    args = (q, k, v, g, beta)
+    np.testing.assert_allclose(chunked(*args), recurrence(*args), atol=3e-6)
+    got = jax.grad(lambda *a: (chunked(*a) * ct).sum(), range(5))(*args)
+    want = jax.grad(lambda *a: (recurrence(*a) * ct).sum(), range(5))(*args)
+    for name, a, c in zip("q k v g beta".split(), got, want):
+        np.testing.assert_allclose(
+            a, c, atol=5e-5 * float(jnp.abs(c).max()) + 1e-7, err_msg=name)
+
+
+def test_short_conv_and_its_written_out_backward():
+    rng = np.random.default_rng(13)
+    x = jnp.asarray(rng.standard_normal((2, 37, 24)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((24, 4)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
+
+    def plain(x, w):  # as the reference writes it
+        taps = w.shape[-1]
+        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+        return jax.nn.silu(sum(padded[:, i:i + x.shape[1]] * w[:, i]
+                               for i in range(taps)))
+
+    np.testing.assert_allclose(la.short_conv_silu(x, w), plain(x, w),
+                               atol=1e-6)
+    got = jax.grad(lambda *a: (la.short_conv_silu(*a) * ct).sum(),
+                   (0, 1))(x, w)
+    want = jax.grad(lambda *a: (plain(*a) * ct).sum(), (0, 1))(x, w)
+    np.testing.assert_allclose(got[0], want[0], atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer
+# ---------------------------------------------------------------------------
+def expert_weights(seed=3, h=32, d=16, wide=16, held=4):
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, scale=0.3):
+        return jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+
+    return {"router": draw(h, wide, scale=1.0), "egu_w": draw(held, h, 2 * d),
+            "ed_w": draw(held, d, h), "sgu_w": draw(h, 2 * d),
+            "sd_w": draw(d, h), "sg_w": draw(h, 1)}
+
+
+def program_experts(x, w, first, top_k, rows):
+    return moe.dropless_experts(
+        x, w["router"], w["egu_w"], w["ed_w"], w["sgu_w"], w["sd_w"],
+        w["sg_w"], first=first, top_k=top_k, renormalize=True, rows=rows)
+
+
+def test_all_shares_add_up_to_the_uncut_layer():
+    """The routed parts that each of 4 chips computes for its own 4 of 16
+    experts, plus the shared expert counted once, are what the reference
+    gives for the whole layer."""
+    wide, held, top_k, tokens, h = 16, 4, 4, 96, 32
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
+    whole = expert_weights(held=wide)
+    sizes = dict(num_experts_per_tok=top_k, num_experts=wide)
+    uncut = ref.experts(x, whole, sizes, held=(0, wide))
+    shared_only = uncut - ref.experts(x, whole, sizes, held=(0, wide),
+                                      shared=False)
+    total, routed = shared_only, 0
+    for chip in range(wide // held):
+        first = chip * held
+        share = dict(whole, egu_w=whole["egu_w"][first:first + held],
+                     ed_w=whole["ed_w"][first:first + held])
+        y, n, _ = program_experts(x, share, first, top_k, rows=64)
+        total = total + (y - shared_only)
+        routed += int(n)
+    assert routed == tokens * top_k  # every slot is some chip's
+    np.testing.assert_allclose(total, uncut, atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["every_slot_held", "no_slot_held",
+                                  "one_expert_takes_all"])
+def test_routing_extremes_drop_nothing(case):
+    wide, held, top_k, tokens = 16, 4, 4, 64
+    w = expert_weights()
+    router = np.array(w["router"])
+    first = 4
+    if case == "every_slot_held":  # the 4 held experts are every token's 4
+        router[:] = 0.0
+        router[0, first:first + held] = 5.0
+    elif case == "no_slot_held":
+        router[:] = 0.0
+        router[0, first:first + held] = -5.0
+    else:  # every token's first choice is the one held expert
+        router[0, first] += 9.0
+    w["router"] = jnp.asarray(router)
+    rng = np.random.default_rng(7)
+    x = np.asarray(rng.standard_normal((tokens, 32)), np.float32)
+    x[:, 0] = np.abs(x[:, 0]) + 1.0
+    x = jnp.asarray(x)
+    sizes = dict(num_experts_per_tok=top_k, num_experts=held)
+    want = ref.experts(x, w, sizes, held=(first, held))
+    rows = 80  # 1.2 x the even load of 64 slots: the skewed cases pass twice
+    y, routed, ran = program_experts(x, w, first, top_k, rows)
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    if case == "every_slot_held":
+        assert int(routed) == tokens * top_k and int(ran) >= tokens * top_k
+    elif case == "no_slot_held":
+        assert int(routed) == 0
+        assert int(ran) == rows  # one pass of the empty buffer
+    else:
+        assert int(routed) >= tokens
+    # and the gradient of the skewed case, through more passes than one
+    ct = jnp.asarray(rng.standard_normal(y.shape), jnp.float32)
+    names = ["x"] + list(w)
+
+    def loss(fn, *a):
+        return (fn(a[0], dict(zip(list(w), a[1:]))) * ct).sum()
+
+    got = jax.grad(lambda *a: loss(
+        lambda x, w: program_experts(x, w, first, top_k, rows)[0], *a),
+        range(len(names)))(x, *w.values())
+    ref_grad = jax.grad(lambda *a: loss(
+        lambda x, w: ref.experts(x, w, sizes, held=(first, held)), *a),
+        range(len(names)))(x, *w.values())
+    for name, a, c in zip(names, got, ref_grad):
+        np.testing.assert_allclose(
+            a, c, atol=2e-5 * float(jnp.abs(c).max()) + 1e-6, err_msg=name)
+
+
+def test_counters_ride_the_compiled_step_and_one_event_a_compile(both):
+    """After a compiled step the two buffers of each expert layer hold what
+    a count made from the router's own output gives; each trace of the layer
+    leaves one ``moe_route`` event, later steps none."""
+    _, w = both
+    _, model = prog.build_model(SIZES)
+    prog.seed_weights(model, SIZES, SEED, "float32")
+    x, y = batch(rows=1, seq=192, seed=4)  # a shape no other test traces
+    first, held = SIZES["held_first"], SIZES["num_experts"]
+    tokens, top_k = x.size, SIZES["num_experts_per_tok"]
+
+    # the count, from the reference's routing of layer 0's input
+    hid = w["embed"][x]
+    l0 = {k[:-2]: a for k, a in w.items() if k.endswith(".0")}
+    hid = hid + ref.linear_attention(
+        ref.rms_norm(hid, 1.0 + l0["norm1"]), l0, SIZES)
+    m = ref.rms_norm(hid, 1.0 + l0["norm2"]).reshape(tokens, -1)
+    want = int((ref.held_weights(m, l0["router"], SIZES, (first, held),
+                                 ref.exact_operands, None) > 0).sum())
+
+    opt = paddle.optimizer.AdamW(learning_rate=0.0,
+                                 parameters=model.parameters())
+    step = paddle.jit.compile_train_step(model, GPTPretrainingCriterion(),
+                                         opt)
+    before = len(trace.events(kind="moe_route"))
+    step(paddle.Tensor(x), paddle.Tensor(y))
+    events = trace.events(kind="moe_route")[before:]
+    assert len(events) == 1  # four layers of one shape: one trace
+    rows = moe.row_buffer_rows(tokens, top_k, SIZES["router_experts"], held)
+    assert events[0].attrs == dict(
+        held=held, num_experts=SIZES["router_experts"], top_k=top_k,
+        buffer_rows=rows, tokens=tokens)
+    load = model.routed_load()
+    assert load[0][1] == want
+    assert all(ran % rows == 0 and ran >= routed for _, routed, ran in load)
+    step(paddle.Tensor(x), paddle.Tensor(y))
+    assert len(trace.events(kind="moe_route")) == before + 1
+
+
+# ---------------------------------------------------------------------------
+# grouped-query flash attention
+# ---------------------------------------------------------------------------
+def dense_attention(q, k, v):
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+
+
+@pytest.mark.parametrize("b,s,h,h_kv,d,blocks", [
+    (1, 256, 8, 1, 256, None),        # group 8 at head_dim 256
+    (2, 256, 4, 2, 32, (128, 128)),   # several q and k blocks: scratch path
+    (1, 200, 6, 3, 24, None)], ids=["g8d256", "tiled", "odd"])
+def test_grouped_query_flash_matches_dense(b, s, h, h_kv, d, blocks):
+    rng = np.random.default_rng(s + d)
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, s, h_kv, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, h_kv, d)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    kw = {} if blocks is None else dict(block_q=blocks[0], block_k=blocks[1])
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, **kw)
+
+    np.testing.assert_allclose(flash(q, k, v), dense_attention(q, k, v),
+                               atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * ct).sum(), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (dense_attention(*a) * ct).sum(),
+                    (0, 1, 2))(q, k, v)
+    for name, a, c in zip("qkv", got, want):
+        np.testing.assert_allclose(a, c, atol=3e-4, err_msg="d" + name)
+    # a query head of a group computes what the equal-headed kernel computes
+    # on k and v copied out to every query head, to the bit: the group only
+    # changes which block the index map names
+    group = h // h_kv
+    kr, vr = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    assert jnp.array_equal(flash(q, k, v), flash(q, kr, vr))
+    dq_equal = jax.grad(lambda q: (flash(q, kr, vr) * ct).sum())(q)
+    assert jnp.array_equal(got[0], dq_equal)
+
+
+def test_model_takes_the_flash_path_and_a_refusal_is_counted(both):
+    from paddle_tpu.ops import nn_ops
+
+    assert nn_ops.flash_attention_refusal(
+        (2, 8192, 16, 256), (2, 8192, 2, 256), (2, 8192, 2, 256)) is None
+    assert nn_ops.flash_attention_refusal(
+        (2, 8192, 16, 256), (2, 8192, 3, 256),
+        (2, 8192, 3, 256)) == "kv_heads_do_not_divide_q_heads"
+    assert nn_ops.flash_attention_refusal(
+        (1, 3000, 2, 24), (1, 3000, 2, 24),
+        (1, 3000, 2, 24)) == "seq_or_head_dim_not_tiled"
+    model, _ = both
+    x, _ = batch()
+    counters = paddle.profiler.dispatch_counters
+    before = counters()["flash_attention_fallbacks"]
+    model(paddle.Tensor(x))
+    assert counters()["flash_attention_fallbacks"] == before
+    # a shape the kernel cannot tile falls back, counted and named
+    q = paddle.Tensor(jnp.ones((1, 3000, 2, 24), jnp.float32))
+    seen = len(trace.events(kind="flash_fallback"))
+    F.scaled_dot_product_attention(q, q, q, is_causal=True)
+    after = counters()
+    assert after["flash_attention_fallbacks"] == before + 1
+    assert after["flash_attention_fallback_reasons"][
+        "seq_or_head_dim_not_tiled"] >= 1
+    event = trace.events(kind="flash_fallback")[seen]
+    assert event.attrs["reason"] == "seq_or_head_dim_not_tiled"
+    assert event.attrs["q_shape"] == (1, 3000, 2, 24)
+
+
+def test_rms_norm_and_rotary_helpers():
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.standard_normal((2, 16, 3, 32)), jnp.float32)
+    w = jnp.asarray(0.1 * rng.standard_normal(32), jnp.float32)
+    got = F.rms_norm(paddle.Tensor(x), paddle.Tensor(w), 1e-6, True)._value
+    np.testing.assert_allclose(got, ref.rms_norm(x, 1.0 + w), atol=1e-6)
+    got = F.rotary_embedding(paddle.Tensor(x), rotary_dim=8,
+                             theta=1e7)._value
+    np.testing.assert_allclose(got, ref.rotary(x, 8, 1e7), atol=1e-6)
+    layer = paddle.nn.RMSNorm(32)
+    np.testing.assert_allclose(layer(paddle.Tensor(x))._value,
+                               ref.rms_norm(x, 1.0), atol=1e-6)

@@ -26,6 +26,7 @@ import paddle_tpu as paddle
 # the package re-exports the flash_attention FUNCTION under the module's name
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 fu = importlib.import_module("paddle_tpu.ops.pallas.fused_update")
+la = importlib.import_module("paddle_tpu.ops.linear_attention")
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +59,7 @@ def compiled_kernels(monkeypatch):
     chip."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(fu, "_interpret", lambda: False)
+    monkeypatch.setattr(la, "_interpret", lambda: False)
 
 
 def _sds(shape, dtype, sharding):
@@ -195,3 +197,92 @@ def test_compile_train_step_program_compiles_for_v5e(one_chip,
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16 * 2**30, mem
+
+
+# ---------------------------------------------------------------------------
+# the sparse hybrid decoder's kernels and step, at the shapes of the cell
+# qwen3next-train-s8192 (batch 2 x 8,192; benchmark/configs/qwen3-next-*)
+# ---------------------------------------------------------------------------
+def test_grouped_query_flash_compiles_for_v5e(one_chip, compiled_kernels):
+    """16 query heads on 2 KV heads of 256 at s = 8,192, forward and
+    backward: the three kernels, k and v never copied out to the group."""
+    q = _sds((2, 8192, 16, 256), jnp.bfloat16, one_chip)
+    kv = _sds((2, 8192, 2, 256), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True).astype(
+                jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, q, kv, kv)
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert name in text
+    assert "bf16[2,8192,16,256]{3,2,1,0} broadcast" not in text
+
+
+def test_gated_delta_rule_compiles_for_v5e(one_chip, compiled_kernels):
+    """16 key and 32 value heads of 128 at s = 8,192, chunk 64, forward and
+    backward: the two state-pass kernels are in the compiled text."""
+    qk = _sds((2, 8192, 16, 128), jnp.bfloat16, one_chip)
+    v = _sds((2, 8192, 32, 128), jnp.bfloat16, one_chip)
+    gate = _sds((2, 8192, 32), jnp.float32, one_chip)
+
+    def fwd_bwd(q, k, v, g, beta):
+        def loss(*a):
+            return la.gated_delta_rule(*a).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    text = _compiled_text(fwd_bwd, qk, qk, v, gate, gate)
+    assert "gated_delta_rule_fwd" in text and "gated_delta_rule_bwd" in text
+
+
+def test_qwen3_next_train_step_compiles_for_v5e(one_chip, compiled_kernels,
+                                                monkeypatch):
+    """The whole compile_train_step program of the cell: published widths,
+    one period of four layers, 64 of 512 experts held, an eighth of the
+    vocabulary, AMP O2 bf16, AdamW, batch 2 x 8,192, mixers recomputed. It
+    fits the chip, and holds the flash kernels, the delta rule's kernels and
+    the grouped products: no dense attention, no capacity-bucketed dispatch.
+    (PERF.md section 4 has the memory it reads, and what it reads without
+    the recomputation.)"""
+    import paddle_tpu.nn.initializer as I
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models import (GPTPretrainingCriterion, Qwen3NextConfig,
+                                   Qwen3NextForCausalLM)
+
+    # shapes are all that matter here: skip drawing a billion normals
+    monkeypatch.setattr(I.Normal, "_generate",
+                        lambda self, shape, dtype: jnp.zeros(shape, dtype))
+    cfg = Qwen3NextConfig(vocab_size=18992, num_hidden_layers=4,
+                          held_experts=(0, 64), use_recompute=True)
+    model = paddle.amp.decorate(Qwen3NextForCausalLM(cfg), level="O2",
+                                dtype="bfloat16")
+    crit = GPTPretrainingCriterion()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, lb: crit(lg.astype("float32"), lb), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((2, 8192), jnp.int32, one_chip)
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), _random.next_key(),
+            jnp.asarray(1e-4, jnp.float32), ids, ids)
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    compiled = step._build().lower(*specs).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq", "gated_delta_rule_fwd",
+                 "gated_delta_rule_bwd", "ragged-dot"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 14.5 * 2**30, mem
+    assert len(step._params) == 62  # expert weights are stacked leaves
