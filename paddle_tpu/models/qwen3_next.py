@@ -158,12 +158,11 @@ def _gated_delta_mixer(qkvz, ba, conv_w, a_log, dt_bias, norm_w, *, hk, hv,
         qkv = _la.short_conv_silu(qkvz[..., :2 * n_qk + n_v], conv_w)
     z = qkvz[..., 2 * n_qk + n_v:].reshape(b, s, hv, dv)
     with jax.named_scope("gated_delta_rule"):
+        # q and k are normalised (l2, a head's row) and q scaled by
+        # d_k^-0.5 inside the rule's kernels
         q = qkv[..., :n_qk].reshape(b, s, hk, dk)
         k = qkv[..., n_qk:2 * n_qk].reshape(b, s, hk, dk)
         v = qkv[..., 2 * n_qk:].reshape(b, s, hv, dv)
-        q = (_la.l2_normalize(q).astype(jnp.float32)
-             * (dk ** -0.5)).astype(q.dtype)
-        k = _la.l2_normalize(k)
         g, beta = _la.decay_and_beta(ba[..., hv:], ba[..., :hv], a_log,
                                      dt_bias)
         o = _la.gated_delta_rule(q, k, v, g, beta)

@@ -12,21 +12,41 @@ products (the WY form): with gamma the running sum of g inside the chunk and
 A[i, j] = beta_i exp(gamma_i - gamma_j) k_i.k_j for j < i, the corrections of
 a chunk are U = (I + A)^-1 beta (V - exp(gamma) K S_0), its outputs
 exp(gamma) Q S_0 + (mask(Q K^T) exp(gamma_i - gamma_j)) U and its last state
-exp(gamma_C) S_0 + (exp(gamma_C - gamma) K)^T U. Everything that does not
-need S_0 is made for all chunks at once by XLA (``_chunk_operands``; plain
-jax.numpy, differentiated by JAX); what does is one pass over the chunks with
-the state held in VMEM: the Pallas kernels ``gated_delta_rule_fwd`` and
-``gated_delta_rule_bwd`` (``_state_pass``, a custom_vjp: the backward walks
-the chunks in reverse with dS in VMEM, from the states the forward kept).
+exp(gamma_C) S_0 + (exp(gamma_C - gamma) K)^T U.
+
+Everything a chunk needs is made in VMEM from the chunk's own rows of q, k,
+v, g, beta, by three Pallas kernels that read the heads where the layer left
+them ([batch, seq, heads * d]: a head is a block of lanes; key head
+``value head // rep`` by the index map) and walk a grid of (batch x key
+heads, blocks of chunks, the value heads a key head serves). The rows of q
+and k are taken to unit length there too (and q scaled by d_k^-0.5): done
+by XLA, each norm went through HBM as a float32 array of the rows' size.
+
+``gated_delta_rule_fwd_inverse``  T = (I + A)^-1 of every chunk, by doubling,
+    the chunks of a grid step level by level together (the products of one
+    inverse wait for each other; PERF.md section 6, PR 29); no state, so a
+    recomputed layer keeps T (``KEEP_NAME``) and skips it.
+``gated_delta_rule_fwd``  the decay tables, w = T beta exp(gamma) K, u0 = T
+    beta V, the masked scores, then the pass over the chunks with S in VMEM
+    scratch; writes o and, for the backward, U and the states the chunks
+    start from.
+``gated_delta_rule_bwd``  the chunks in reverse with dS in VMEM: makes the
+    chunk's operands again from T, carries the cotangents through them (dT
+    from dw and du0, dA = -T^T dT T^T, the decay table's to dg by the
+    reversed running sum of row sums minus column sums) and writes dq, dk
+    (summed over the value heads of a key head, then through the norm), dv,
+    dg, dbeta.
 
 Operands of the products are in the type the inputs come in (bf16 under
-AMP-O2), sums, gates, decays, the inverse and the state in float32 (the
-inverse's own products in three bf16 passes). Off the TPU the kernels run in
-interpret mode.
+AMP-O2), sums, gates, decays, the inverse and the state in float32. The
+inverse's own products (and dA's) are three bf16 passes, written out as a
+split into a high and a low half; float32 inputs get float32 products there.
+Off the TPU the kernels run in interpret mode.
 """
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,13 +58,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..incubate.recompute import KEEP_NAME
 
 F32 = jnp.float32
-# the inverse's products: three bf16 passes (an error near 2^-16 a product,
-# under the bf16 rounding of the inverse as it is handed out). On the chip the
-# products are HBM-bound, so HIGHEST's six passes would take the same time
-# (PERF.md section 5); the benchmark cell's limits were read at HIGH, and its
-# configuration's ``precision`` states HIGH
-INVERSE_PRECISION = jax.lax.Precision.HIGH
-STEP_ROWS = 512  # rows of a sequence one grid step of the state pass holds
+L2_EPSILON = 1e-6  # under the root of a q or k row's norm
+STEP_ROWS = 512  # rows of a sequence one grid step of the kernels holds
+_0 = np.int32(0)  # index-map literal; Python ints trace to i64 under x64
 
 
 def _interpret() -> bool:
@@ -96,7 +112,7 @@ def _short_conv_bwd(res, dy):
 short_conv_silu.defvjp(_short_conv_fwd, _short_conv_bwd)
 
 
-def l2_normalize(x, *, epsilon=1e-6):
+def l2_normalize(x, *, epsilon=L2_EPSILON):
     """x / |x| over the last axis, in float32."""
     xf = x.astype(F32)
     return (xf * jax.lax.rsqrt(jnp.square(xf).sum(-1, keepdims=True)
@@ -121,259 +137,436 @@ def gated_rms_norm(o, z, weight, *, epsilon=1e-6):
     return (y * jax.nn.silu(z.astype(F32))).astype(o.dtype)
 
 
-# ---------------------------------------------------------------------------
-# (I + A)^-1 for a strictly lower triangular A
-# ---------------------------------------------------------------------------
-def _mm_hi(a, b):
-    return jnp.matmul(a, b, precision=INVERSE_PRECISION)
-
-
-def _inverse_by_doubling(a):
-    c = a.shape[-1]
-    x = jnp.eye(c, dtype=a.dtype) - a
-    p, n = a, 2
-    while n < c:
-        p = _mm_hi(p, p)
-        x = x + _mm_hi(x, p)
-        n *= 2
-    return x
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def unit_lower_inverse(a, dtype):
-    """(I + a)^-1 in ``dtype`` for strictly lower triangular ``a`` [..., C,
-    C] float32: a is nilpotent, so the inverse is the finite sum of (-a)^k,
-    gathered by doubling, (I - a)(I + a^2)(I + a^4)...: 2 log2(C) - 2
-    products and no dependent loop over rows. The backward needs the inverse
-    alone, as it was handed out: it is tagged ``KEEP_NAME``, so a recomputed
-    layer does not make it twice."""
-    return _inverse_by_doubling(a).astype(dtype)
-
-
-def _inverse_fwd(a, dtype):
-    t = checkpoint_name(_inverse_by_doubling(a).astype(dtype), KEEP_NAME)
-    return t, t
-
-
-def _inverse_bwd(dtype, t, dt):
-    tt = jnp.swapaxes(t.astype(F32), -1, -2)
-    return (-_mm_hi(_mm_hi(tt, dt.astype(F32)), tt),)
-
-
-unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 # ---------------------------------------------------------------------------
-# the pass over the chunks, state in VMEM
+# what a chunk is made of, in VMEM
 # ---------------------------------------------------------------------------
-def _mm(a, b, dims=((1,), (0,))):
-    return jax.lax.dot_general(a, b, (dims, ((), ())),
-                               preferred_element_type=F32)
-
-
+_NN = ((1,), (0,))  # a @ b
 _NT = ((1,), (1,))  # a @ b.T
 _TN = ((0,), (0,))  # a.T @ b
 
 
-def _fwd_kernel(w_ref, u0_ref, qg_ref, attn_ref, kd_ref, dec_ref,
+def _mm(a, b, dims=_NN, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=F32)
+
+
+def _halves(x, cdt):
+    """float32 ``x`` as operands of the products' type that add up to it: a
+    high and a low half in bf16 (x to 2^-16), x itself in float32."""
+    if cdt == F32:
+        return (x,)
+    hi = x.astype(cdt)
+    return hi, (x - hi.astype(F32)).astype(cdt)
+
+
+def _mm_halves(a, b, dims=_NN):
+    """The product of two operands given as ``_halves``, gathered in float32:
+    three passes for two halves each (low x low, under 2^-16, is left out),
+    the small terms first. The configuration of the benchmark's cell states
+    three bf16 passes (``precision.gated_delta_rule``): not one, not six."""
+    precision = jax.lax.Precision.HIGHEST if a[0].dtype == F32 else None
+    total = None
+    for i, j in ((1, 0), (0, 1), (0, 0)):
+        if i < len(a) and j < len(b):
+            term = _mm(a[i], b[j], dims, precision)
+            total = term if total is None else total + term
+    return total
+
+
+def _unit_lower_inverses(many, eye, cdt):
+    """(I + a)^-1 in float32 for each strictly lower triangular a [C, C] of
+    ``many``: a is nilpotent, so the inverse is the finite sum of (-a)^k,
+    gathered by doubling, (I - a)(I + a^2)(I + a^4)...: 2 log2(C) - 2
+    products and no dependent loop over rows. The products of one a wait for
+    each other; those of several, written level by level, fill the wait."""
+    xs = [eye.astype(F32) - a for a in many]
+    ps, n = [_halves(a, cdt) for a in many], 2
+    while n < eye.shape[-1]:
+        ps = [_halves(_mm_halves(p, p), cdt) for p in ps]
+        xs = [x + _mm_halves(_halves(x, cdt), p) for x, p in zip(xs, ps)]
+        n *= 2
+    return xs
+
+
+def _to_col(row, eye):
+    """[1, C] -> [C, 1], exactly (no transpose unit, no product)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _to_row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+class _Gates(NamedTuple):
+    gam: jax.Array    # [C, 1] running sum of g inside the chunk
+    last: jax.Array   # [1, 1] its last entry
+    beta: jax.Array   # [C, 1]
+    decay: jax.Array  # [C, C] exp(gam_i - gam_j) on and under the diagonal
+    under: jax.Array  # [C, C] masks: on or under the diagonal,
+    eye: jax.Array    # the diagonal
+
+
+def _chunk_gates(g_row, beta_row):
+    """A chunk's gates from its log decays and betas, [1, C] float32 rows."""
+    c = g_row.shape[-1]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    under, eye = ci <= ri, ci == ri
+    gam = jnp.sum(jnp.where(under, g_row, 0.0), axis=1, keepdims=True)
+    gap = jnp.where(under, gam - _to_row(gam, eye), 0.0)
+    return _Gates(gam, jnp.sum(g_row, axis=1, keepdims=True),
+                  _to_col(beta_row, eye),
+                  jnp.where(under, jnp.exp(gap), 0.0), under, eye)
+
+
+def _strict(gt, x):
+    return jnp.where(gt.under & ~gt.eye, x, 0.0)
+
+
+def _unit_rows(x, scale=1.0):
+    """``l2_normalize(x)`` times ``scale`` for rows [rows, d], with the two
+    roundings to x's type the layer made on the way through HBM before."""
+    y = l2_normalize(x)
+    return y if scale == 1.0 else (y.astype(F32) * scale).astype(x.dtype)
+
+
+def _unit_rows_bwd(x, dy, scale=1.0):
+    """d loss / d x from d loss / d ``_unit_rows(x, scale)``, float32."""
+    xf, dy = x.astype(F32), dy * scale
+    r = jax.lax.rsqrt(jnp.sum(xf * xf, axis=1, keepdims=True) + L2_EPSILON)
+    y = xf * r
+    return r * (dy - y * jnp.sum(y * dy, axis=1, keepdims=True))
+
+
+class _Operands(NamedTuple):
+    """What a chunk needs beside the state it starts from; float32 where it
+    is a factor of sums, the products' type where it is a product's."""
+    e: jax.Array     # [C, 1] exp(gam)
+    el: jax.Array    # [C, 1] exp(last - gam)
+    kb: jax.Array    # [C, dk] beta exp(gam) K
+    vb: jax.Array    # [C, dv] beta V
+    w: jax.Array     # [C, dk] T kb
+    qk: jax.Array    # [C, C] Q K^T, float32
+    attn: jax.Array  # [C, C] decay Q K^T
+    qg: jax.Array    # [C, dk] exp(gam) Q
+    kd: jax.Array    # [C, dk] exp(last - gam) K
+
+
+def _chunk_operands(q, k, v, t, gt):
+    cdt = v.dtype
+    e, el = jnp.exp(gt.gam), jnp.exp(gt.last - gt.gam)
+    kf = k.astype(F32)
+    kb = (kf * (gt.beta * e)).astype(cdt)
+    qk = _mm(q, k, _NT)
+    return _Operands(
+        e, el, kb, (v.astype(F32) * gt.beta).astype(cdt),
+        _mm(t, kb).astype(cdt), qk, (qk * gt.decay).astype(cdt),
+        (q.astype(F32) * e).astype(cdt), (kf * el).astype(cdt))
+
+
+# ---------------------------------------------------------------------------
+# the three kernels
+# ---------------------------------------------------------------------------
+# A kernel holds a grid step's chunks unrolled, so that the scheduler can lay
+# one chunk's preparation under another's pass; what a chunk does is a jitted
+# function of values, traced once and not once a chunk (a step's trace is
+# part of every run's set-up).
+@jax.jit
+def _chunk_inverses(k, g_rows, beta_rows):
+    """T [m C, C] in k's type of the m chunks of a grid step, from their
+    rows of k [m C, dk] and their gates [m, C]."""
+    m, c = g_rows.shape
+    gates = [_chunk_gates(g_rows[i:i + 1], beta_rows[i:i + 1])
+             for i in range(m)]
+    rows = [_unit_rows(k[i * c:(i + 1) * c]) for i in range(m)]
+    many = [_strict(gt, gt.beta * gt.decay * _mm(ki, ki, _NT))
+            for gt, ki in zip(gates, rows)]
+    return jnp.concatenate(
+        _unit_lower_inverses(many, gates[0].eye, k.dtype),
+        axis=0).astype(k.dtype)
+
+
+@jax.jit
+def _chunk_forward(q, k, v, t, g_row, beta_row, s):
+    """(o, U, the state as the products see it, the next chunk's state) of
+    one chunk that starts from the float32 state ``s``."""
+    cdt = v.dtype
+    gt = _chunk_gates(g_row, beta_row)
+    op = _chunk_operands(_unit_rows(q, q.shape[-1] ** -0.5), _unit_rows(k),
+                         v, t, gt)
+    sb = s.astype(cdt)
+    u0 = _mm(t, op.vb).astype(cdt)
+    ub = (u0.astype(F32) - _mm(op.w, sb)).astype(cdt)
+    o = _mm(op.qg, sb) + _mm(op.attn, ub)
+    return (o.astype(cdt), ub, sb,
+            s * jnp.exp(gt.last) + _mm(op.kd, ub, _TN))
+
+
+@jax.jit
+def _chunk_backward(q, k, v, t, g_row, beta_row, s0, ub, dob, ds):
+    """One chunk's cotangents from d loss / d o (``dob``) and d loss / d the
+    state the NEXT chunk starts from (``ds``, float32): (dq, dk of the
+    normalised rows, float32; dv; dg and dbeta as [1, C] rows; d loss / d
+    the state this chunk starts from)."""
+    def rowsum(x):
+        return jnp.sum(x, axis=1, keepdims=True)
+
+    cdt = v.dtype
+    q, k = _unit_rows(q, q.shape[-1] ** -0.5), _unit_rows(k)
+    gt = _chunk_gates(g_row, beta_row)
+    op = _chunk_operands(q, k, v, t, gt)
+    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
+    dsb, dec = ds.astype(cdt), jnp.exp(gt.last)
+
+    # through the four products that carry the state
+    dub = (_mm(op.attn, dob, _TN) + _mm(op.kd, dsb)).astype(cdt)
+    dattn = _mm(dob, ub, _NT)
+    dqg = _mm(dob, s0, _NT)
+    dkd = _mm(ub, dsb, _NT)
+    dwb = (-_mm(dub, s0, _NT)).astype(cdt)
+    ddec = jnp.sum(rowsum(ds * s0.astype(F32)), axis=0, keepdims=True)
+    ds = _mm(op.qg, dob, _TN) + ds * dec - _mm(op.w, dub, _TN)
+
+    # through w = T kb and u0 = T vb, then T = (I + A)^-1:
+    # dA = -T^T dT T^T (T is exact in its own type: one half)
+    dt = _mm(dwb, op.kb, _NT) + _mm(dub, op.vb, _NT)
+    dkb, dvb = _mm(t, dwb, _TN), _mm(t, dub, _TN)
+    da = _mm_halves(_halves(_mm_halves((t,), _halves(dt, cdt), _TN), cdt),
+                    (t,), _NT)
+    dm = _strict(gt, -da)  # of beta decay K K^T, under the diagonal
+
+    # to the scores, the rows and the gates
+    kk = _mm(k, k, _NT)
+    dmd = dm * gt.decay
+    dkk = (dmd * gt.beta).astype(cdt)
+    dqk = (dattn * gt.decay).astype(cdt)
+    ddecay = (dm * gt.beta * kk + dattn * op.qk) * gt.decay
+    dq = dqg * op.e + _mm(dqk, k)
+    dk = (dkd * op.el + dkb * (gt.beta * op.e) + _mm(dqk, q, _TN)
+          + _mm(dkk, k) + _mm(dkk, k, _TN))
+    dkb_k = rowsum(dkb * kf)
+    dbeta = rowsum(dmd * kk) + dkb_k * op.e + rowsum(dvb * vf)
+    # gamma: from exp(gam), exp(last - gam), exp(last) and the table (row
+    # sums minus column sums); g from gamma by the reversed running sum
+    dkd_k = rowsum(dkd * kf) * op.el
+    dlast = jnp.sum(dkd_k, axis=0, keepdims=True) + ddec * dec
+    dgam = ((rowsum(dqg * qf) + dkb_k * gt.beta) * op.e - dkd_k
+            + rowsum(ddecay)
+            - _to_col(jnp.sum(ddecay, axis=0, keepdims=True), gt.eye))
+    dg = jnp.sum(jnp.where(gt.under, dgam, 0.0), axis=0, keepdims=True)
+    return (dq, dk, (dvb * gt.beta).astype(cdt), dg + dlast,
+            _to_row(dbeta, gt.eye), ds)
+
+
+def _inverse_kernel(k_ref, g_ref, beta_ref, t_ref, *, chunk, per_step):
+    t_ref[0] = _chunk_inverses(k_ref[0], g_ref[0], beta_ref[0])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref,
                 o_ref, u_ref, h_ref, s_scr, *, chunk, per_step):
+    head = pl.program_id(2)  # of the value heads this key head serves
+
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_scr[:] = jnp.zeros_like(s_scr)
+        s_scr[head] = jnp.zeros(s_scr.shape[1:], F32)
 
-    cdt = w_ref.dtype
-    s = s_scr[:]
+    s = s_scr[head]
     for c in range(per_step):
         rows = slice(c * chunk, (c + 1) * chunk)
-        sb = s.astype(cdt)
-        h_ref[0, c] = sb  # the state this chunk starts from, for the backward
-        u = u0_ref[0, rows, :].astype(F32) - _mm(w_ref[0, rows, :], sb)
-        ub = u.astype(cdt)
-        o = _mm(qg_ref[0, rows, :], sb) + _mm(attn_ref[0, rows, :], ub)
-        o_ref[0, rows, :] = o.astype(o_ref.dtype)
-        u_ref[0, rows, :] = ub
-        s = s * dec_ref[0, c:c + 1, :] + _mm(kd_ref[0, rows, :], ub, _TN)
-    s_scr[:] = s
+        # h: the state this chunk starts from, for the backward
+        o_ref[0, rows, :], u_ref[0, rows, :], h_ref[0, c], s = _chunk_forward(
+            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
+            t_ref[0, rows, :], g_ref[0, c:c + 1, :], beta_ref[0, c:c + 1, :],
+            s)
+    s_scr[head] = s
 
 
-def _bwd_kernel(w_ref, qg_ref, attn_ref, kd_ref, dec_ref, u_ref, h_ref,
-                do_ref, dw_ref, du0_ref, dqg_ref, dattn_ref, dkd_ref, ddec_ref,
-                ds_scr, *, chunk, per_step):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, u_ref, h_ref,
+                do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                ds_scr, dq_scr, dk_scr, *, chunk, per_step):
+    head, heads = pl.program_id(2), pl.num_programs(2)
+
     @pl.when(pl.program_id(1) == 0)
     def _():
-        ds_scr[:] = jnp.zeros_like(ds_scr)
+        ds_scr[head] = jnp.zeros(ds_scr.shape[1:], F32)
 
-    cdt = w_ref.dtype
-    ds = ds_scr[:]  # d loss / d (the state the NEXT chunk starts from)
+    @pl.when(head == 0)  # dq, dk: summed over the heads the key head serves
+    def _():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+
+    ds = ds_scr[head]
     for c in reversed(range(per_step)):
         rows = slice(c * chunk, (c + 1) * chunk)
-        s0 = h_ref[0, c]
-        dsb = ds.astype(cdt)
-        dob, ub = do_ref[0, rows, :], u_ref[0, rows, :]
-        du = _mm(attn_ref[0, rows, :], dob, _TN) + _mm(kd_ref[0, rows, :], dsb)
-        dub = du.astype(cdt)
-        dattn_ref[0, rows, :] = _mm(dob, ub, _NT).astype(dattn_ref.dtype)
-        dqg_ref[0, rows, :] = _mm(dob, s0, _NT).astype(dqg_ref.dtype)
-        dkd_ref[0, rows, :] = _mm(ub, dsb, _NT).astype(dkd_ref.dtype)
-        dw_ref[0, rows, :] = (-_mm(dub, s0, _NT)).astype(dw_ref.dtype)
-        du0_ref[0, rows, :] = dub
-        # column j of dec scales column j of the state
-        ddec_ref[0, c:c + 1, :] = jnp.sum(ds * s0.astype(F32), axis=0,
-                                          keepdims=True)
-        ds = (_mm(qg_ref[0, rows, :], dob, _TN) + ds * dec_ref[0, c:c + 1, :]
-              - _mm(w_ref[0, rows, :], dub, _TN))
-    ds_scr[:] = ds
+        (dq, dk, dv_ref[0, rows, :], dg_ref[0, c:c + 1, :],
+         dbeta_ref[0, c:c + 1, :], ds) = _chunk_backward(
+            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
+            t_ref[0, rows, :], g_ref[0, c:c + 1, :], beta_ref[0, c:c + 1, :],
+            h_ref[0, c], u_ref[0, rows, :], do_ref[0, rows, :], ds)
+        dq_scr[rows, :] += dq
+        dk_scr[rows, :] += dk
+    ds_scr[head] = ds
+
+    @pl.when(head == heads - 1)
+    def _():
+        q_scale = q_ref.shape[-1] ** -0.5
+        dq_ref[0] = _unit_rows_bwd(q_ref[0], dq_scr[:], q_scale).astype(
+            dq_ref.dtype)
+        dk_ref[0] = _unit_rows_bwd(k_ref[0], dk_scr[:]).astype(dk_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# their calls: one grid and one set of block maps for the three
+# ---------------------------------------------------------------------------
+class _Geometry(NamedTuple):
+    chunk: int
+    per_step: int     # chunks one grid step holds
+    rep: int          # value heads a key head serves
+    key_lanes: int    # heads side by side in the lanes of q, k: all, or 1
+    value_lanes: int  # and of v, o
+    dk: int
+    dv: int
 
 
 def _per_step(n_chunks, chunk):
     """Chunks one grid step holds: STEP_ROWS rows where that tiles the
-    sequence into whole (8, 128) blocks of the per-chunk decay, else all."""
+    sequence into whole (8, 128) blocks of the per-chunk gates, else all."""
     want = max(1, STEP_ROWS // chunk)
     return want if n_chunks % want == 0 and want % 8 == 0 else n_chunks
 
 
-_0 = np.int32(0)  # index-map literal; Python ints trace to i64 under x64
-
-
-def _specs(seq, chunk, per_step, dk, dv, reverse):
-    n_blocks = seq // (chunk * per_step)
+def _call(kernel, name, geo, ins, outs, scratch=(), reverse=False):
+    """``kernel`` over the grid (key heads of all batches, blocks of chunks,
+    value heads of the key head). ``ins`` are (kind, array) pairs, ``outs``
+    (kind, shape, dtype); a kind is the block a grid step gets: "key" and
+    "value" rows of one head out of [B, seq, lanes * d] (head n of all
+    batches is lane block n % lanes of row n // lanes), "t" rows of [value
+    heads, seq, chunk], "gate" of [value heads, chunks, chunk], "h" of
+    [value heads, chunks, dk, dv]."""
+    rows = geo.chunk * geo.per_step
+    key_heads = ins[0][1].shape[0] * geo.key_lanes
+    n_blocks = dict(ins)["gate"].shape[1] // geo.per_step
+    rep, key_lanes, value_lanes = (np.int32(x) for x in (
+        geo.rep, geo.key_lanes, geo.value_lanes))
 
     def block(j):
         return np.int32(n_blocks - 1) - j if reverse else j
 
-    rows = chunk * per_step
-    return n_blocks, {
-        "k": pl.BlockSpec((1, rows, dk), lambda i, j: (i, block(j), _0)),
-        "v": pl.BlockSpec((1, rows, dv), lambda i, j: (i, block(j), _0)),
-        "c": pl.BlockSpec((1, rows, chunk), lambda i, j: (i, block(j), _0)),
-        "dec": pl.BlockSpec((1, per_step, dv), lambda i, j: (i, block(j), _0)),
-        "h": pl.BlockSpec((1, per_step, dk, dv),
-                          lambda i, j: (i, block(j), _0, _0)),
+    def key(i, j, r):
+        return jax.lax.div(i, key_lanes), block(j), jax.lax.rem(i, key_lanes)
+
+    def value(i, j, r):
+        n = i * rep + r
+        return (jax.lax.div(n, value_lanes), block(j),
+                jax.lax.rem(n, value_lanes))
+
+    def head(i, j, r):
+        return i * rep + r, block(j), _0
+
+    specs = {
+        "key": pl.BlockSpec((1, rows, geo.dk), key),
+        "value": pl.BlockSpec((1, rows, geo.dv), value),
+        "t": pl.BlockSpec((1, rows, geo.chunk), head),
+        "gate": pl.BlockSpec((1, geo.per_step, geo.chunk), head),
+        "h": pl.BlockSpec((1, geo.per_step, geo.dk, geo.dv),
+                          lambda i, j, r: head(i, j, r) + (_0,)),
     }
-
-
-def _pass_fwd(w, u0, qg, attn, kd, dec, chunk):
-    bh, seq, dk = w.shape
-    dv = u0.shape[-1]
-    per_step = _per_step(seq // chunk, chunk)
-    n_blocks, sp = _specs(seq, chunk, per_step, dk, dv, reverse=False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, per_step=per_step),
-        name="gated_delta_rule_fwd",
-        grid=(bh, n_blocks),
-        in_specs=[sp["k"], sp["v"], sp["k"], sp["c"], sp["k"], sp["dec"]],
-        out_specs=[sp["v"], sp["v"], sp["h"]],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, seq, dv), u0.dtype),
-            jax.ShapeDtypeStruct((bh, seq, dv), u0.dtype),
-            jax.ShapeDtypeStruct((bh, seq // chunk, dk, dv), u0.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
+        functools.partial(kernel, chunk=geo.chunk, per_step=geo.per_step),
+        name=name,
+        grid=(key_heads, n_blocks, geo.rep),
+        in_specs=[specs[kind] for kind, _ in ins],
+        out_specs=[specs[kind] for kind, _, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(shape, dtype)
+                   for _, shape, dtype in outs],
+        scratch_shapes=list(scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
-    )(w, u0, qg, attn, kd, dec)
+    )(*(x for _, x in ins))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _state_pass(w, u0, qg, attn, kd, dec, chunk):
-    """o [BH, L, dv] from the chunks' operands, all [BH, L, .]: w, qg, kd
-    [.., dk], u0 [.., dv], attn [.., chunk]; dec [BH, L / chunk, dv] float32,
-    a chunk's whole decay repeated along the last axis."""
-    return _pass_fwd(w, u0, qg, attn, kd, dec, chunk)[0]
+# jitted: the model traces the rule once a layer and once more under its
+# recomputation, and a kernel of eight unrolled chunks is a long trace
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _inverse(k, g, beta, geo, dtype):
+    heads, n = g.shape[0], g.shape[1]
+    return _call(_inverse_kernel, "gated_delta_rule_fwd_inverse", geo,
+                 [("key", k), ("gate", g), ("gate", beta)],
+                 [("t", (heads, n * geo.chunk, geo.chunk), dtype)])[0]
 
 
-def _state_pass_fwd(w, u0, qg, attn, kd, dec, chunk):
-    o, u, h = _pass_fwd(w, u0, qg, attn, kd, dec, chunk)
-    return o, (w, qg, attn, kd, dec, u, h)
+@functools.partial(jax.jit, static_argnums=(6,))
+def _forward(q, k, v, g, beta, t, geo):
+    heads, n = g.shape[0], g.shape[1]
+    return _call(
+        _fwd_kernel, "gated_delta_rule_fwd", geo,
+        [("key", q), ("key", k), ("value", v), ("gate", g), ("gate", beta),
+         ("t", t)],
+        [("value", v.shape, v.dtype), ("value", v.shape, v.dtype),
+         ("h", (heads, n, geo.dk, geo.dv), v.dtype)],
+        [pltpu.VMEM((geo.rep, geo.dk, geo.dv), F32)])
 
 
-def _state_pass_bwd(chunk, res, do):
-    w, qg, attn, kd, dec, u, h = res
-    bh, seq, dk = w.shape
-    dv = u.shape[-1]
-    per_step = _per_step(seq // chunk, chunk)
-    n_blocks, sp = _specs(seq, chunk, per_step, dk, dv, reverse=True)
-    like = jax.ShapeDtypeStruct
-    dw, du0, dqg, dattn, dkd, ddec = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, per_step=per_step),
-        name="gated_delta_rule_bwd",
-        grid=(bh, n_blocks),
-        in_specs=[sp["k"], sp["k"], sp["c"], sp["k"], sp["dec"], sp["v"],
-                  sp["h"], sp["v"]],
-        out_specs=[sp["k"], sp["v"], sp["k"], sp["c"], sp["k"], sp["dec"]],
-        out_shape=[like(w.shape, w.dtype), like(u.shape, u.dtype),
-                   like(qg.shape, qg.dtype), like(attn.shape, attn.dtype),
-                   like(kd.shape, kd.dtype), like(dec.shape, F32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(w, qg, attn, kd, dec, u, h, do.astype(u.dtype))
-    return dw, du0, dqg, dattn, dkd, ddec
+@functools.partial(jax.jit, static_argnums=(9,))
+def _backward(q, k, v, g, beta, t, u, h, do, geo):
+    rows = geo.chunk * geo.per_step
+    return tuple(_call(
+        _bwd_kernel, "gated_delta_rule_bwd", geo,
+        [("key", q), ("key", k), ("value", v), ("gate", g), ("gate", beta),
+         ("t", t), ("value", u), ("h", h), ("value", do)],
+        [("key", q.shape, q.dtype), ("key", k.shape, k.dtype),
+         ("value", v.shape, v.dtype), ("gate", g.shape, F32),
+         ("gate", beta.shape, F32)],
+        [pltpu.VMEM((geo.rep, geo.dk, geo.dv), F32),
+         pltpu.VMEM((rows, geo.dk), F32), pltpu.VMEM((rows, geo.dk), F32)],
+        reverse=True))
 
 
-_state_pass.defvjp(_state_pass_fwd, _state_pass_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, g, beta, geo):
+    """o as v, from q, k [B, seq, lanes * dk], v [B', seq, lanes * dv] and
+    the gates [value heads of all batches, chunks, chunk] float32."""
+    t = _inverse(k, g, beta, geo, v.dtype)
+    return _forward(q, k, v, g, beta, t, geo)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, geo):
+    # the name is on the residual itself: a recomputed layer keeps the
+    # inverse and makes only the pass over the chunks again
+    t = checkpoint_name(_inverse(k, g, beta, geo, v.dtype), KEEP_NAME)
+    o, u, h = _forward(q, k, v, g, beta, t, geo)
+    return o, (q, k, v, g, beta, t, u, h)
+
+
+def _rule_bwd(geo, res, do):
+    q, k, v, g, beta, t, u, h = res
+    return _backward(q, k, v, g, beta, t, u, h, do.astype(v.dtype), geo)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
-def _chunk_operands(q, k, v, g, beta, chunk):
-    """What a chunk needs beside the state it starts from, for all chunks at
-    once: (w, u0, qg, attn, kd, dec), each [batch * value heads, seq, .]."""
-    b, seq, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
-    n, rep, cdt = seq // chunk, hv // hk, v.dtype
-
-    def chunks(x):  # [b, seq, heads, d] -> [b, heads, n, chunk, d]
-        x = jnp.moveaxis(x, 2, 1)
-        return x.reshape(b, x.shape[1], n, chunk, *x.shape[3:])
-
-    qc, kc, vc = chunks(q), chunks(k), chunks(v)
-    gam = jnp.cumsum(chunks(g.astype(F32)), axis=-1)  # [b, hv, n, chunk]
-    bet = chunks(beta.astype(F32))
-    on_or_under = jnp.tril(jnp.ones((chunk, chunk), bool))
-    gap = gam[..., :, None] - gam[..., None, :]
-    decay = jnp.where(on_or_under,
-                      jnp.exp(jnp.where(on_or_under, gap, 0.0)), 0.0)
-
-    def scores(x, y):  # per key head, shared by the value heads it serves
-        s = jnp.einsum("bhnid,bhnjd->bhnij", x, y, preferred_element_type=F32)
-        return jnp.repeat(s, rep, axis=1)
-
-    a = jnp.tril(bet[..., :, None] * decay * scores(kc, kc), -1)
-    t = unit_lower_inverse(a, cdt)
-    kv = jnp.repeat(kc, rep, axis=1).astype(F32)
-    qv = jnp.repeat(qc, rep, axis=1).astype(F32)
-    egam, last = jnp.exp(gam), gam[..., -1:]
-
-    def times_t(x):
-        return jnp.einsum("bhnij,bhnjd->bhnid", t, x.astype(cdt),
-                          preferred_element_type=F32).astype(cdt)
-
-    w = times_t(kv * (bet * egam)[..., None])
-    u0 = times_t(vc.astype(F32) * bet[..., None])
-    attn = (scores(qc, kc) * decay).astype(cdt)
-    qg = (qv * egam[..., None]).astype(cdt)
-    kd = (kv * jnp.exp(last - gam)[..., None]).astype(cdt)
-    dec = jnp.broadcast_to(jnp.exp(last), (b, hv, n, dv))
-
-    def flat(x):
-        return x.reshape(b * hv, seq, x.shape[-1])
-
-    return (flat(w), flat(u0), flat(qg), flat(attn), flat(kd),
-            dec.reshape(b * hv, n, dv))
-
-
 def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
     """The gated delta rule over whole sequences, state zero at the start.
 
-    q, k [batch, seq, key heads, d_k] (normalised and scaled by the caller),
-    v [batch, seq, value heads, d_v], g (log decay, <= 0) and beta [batch,
-    seq, value heads]; key head i serves value heads i * rep .. (i+1) * rep-1.
+    q, k [batch, seq, key heads, d_k] as the conv leaves them: the kernels
+    take each head's row to unit length (``l2_normalize``, in VMEM) and
+    scale q by d_k^-0.5. v [batch, seq, value heads, d_v], g (log decay,
+    <= 0) and beta [batch, seq, value heads]; key head i serves value heads
+    i * rep .. (i+1) * rep-1.
     Returns o [batch, seq, value heads, d_v] in v's type. ``seq`` must be a
-    multiple of ``chunk`` (or shorter than it)."""
+    multiple of ``chunk`` (or shorter than it).
+
+    Heads whose widths are whole blocks of 128 lanes are read where they lie
+    and o is written so; other widths are moved to [heads, seq, d] first (a
+    copy each way). Each trace leaves one ``gdn_chunks`` event in the flight
+    recorder."""
     b, seq, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     chunk = min(chunk, seq)
@@ -381,5 +574,26 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
         raise ValueError(
             f"gated_delta_rule: seq {seq} is not a multiple of chunk {chunk}, "
             f"or {hv} value heads are not a multiple of {hk} key heads")
-    o = _state_pass(*_chunk_operands(q, k, v, g, beta, chunk), chunk)
+    n = seq // chunk
+    in_lanes = dk % 128 == 0 and dv % 128 == 0
+    geo = _Geometry(chunk, _per_step(n, chunk), hv // hk,
+                    hk if in_lanes else 1, hv if in_lanes else 1, dk, dv)
+
+    from ..profiler import trace
+    trace.emit("gdn_chunks", site="gated_delta_rule", seq=seq, chunk=chunk,
+               chunks_per_step=geo.per_step, rep=geo.rep,
+               heads_in_lanes=in_lanes, prepared="vmem")
+
+    def heads(x):  # [b, seq, h, d] -> [B, seq, lanes * d]
+        if in_lanes:
+            return x.reshape(b, seq, -1)
+        return jnp.moveaxis(x, 2, 1).reshape(-1, seq, x.shape[-1])
+
+    def gate(x):  # [b, seq, hv] -> [b * hv, chunks, chunk]
+        x = x.astype(F32).reshape(b, n, chunk, hv)
+        return jnp.moveaxis(x, 3, 1).reshape(b * hv, n, chunk)
+
+    o = _rule(heads(q), heads(k), heads(v), gate(g), gate(beta), geo)
+    if in_lanes:
+        return o.reshape(b, seq, hv, dv)
     return jnp.moveaxis(o.reshape(b, hv, seq, dv), 1, 2)

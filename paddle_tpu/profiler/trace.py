@@ -17,6 +17,9 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
   flash_tiles      the flash attention kernels were built for a shape: how
                    many sub-tiles of a head's score square the causal walk
                    runs, masks and skips (trace time, once per compile)
+  gdn_chunks       the gated delta rule's kernels were built for a shape:
+                   chunk, chunks a grid step, value heads a key head, where
+                   the chunks are prepared (trace time, once per trace)
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that

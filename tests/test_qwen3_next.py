@@ -131,36 +131,113 @@ def test_recomputed_mixer_gives_the_same_step(both):
 # ---------------------------------------------------------------------------
 # the chunked gated delta rule against the token recurrence
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("decay", [1e-3, 1.0, 12.0],
-                         ids=["near_one", "middling", "near_zero"])
-@pytest.mark.parametrize("seq,chunk", [(192, 64), (64, 16), (48, 64)])
-def test_chunked_delta_rule_matches_recurrence(seq, chunk, decay):
-    rng = np.random.default_rng(seq + chunk)
-    b, hk, hv, dk, dv = 2, 2, 4, 32, 16
+SMALL_HEADS = (2, 2, 4, 32, 16)  # batch, key heads, value heads, d_k, d_v
+# the cell's head geometry (qwen3next-train-s8192: heads of 128 that the
+# kernels read as blocks of lanes, two value heads to a key head), over two
+# grid steps of eight chunks: the carried state and the reversed walk cross
+CELL_HEADS = (1, 1, 2, 128, 128)
+
+
+def delta_rule_inputs(seq, heads, decay, seed):
+    rng = np.random.default_rng(seed)
+    b, hk, hv, dk, dv = heads
 
     def draw(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.float32)
 
-    q = la.l2_normalize(draw(b, seq, hk, dk)) / np.sqrt(dk)
-    k = la.l2_normalize(draw(b, seq, hk, dk))
+    q, k = draw(b, seq, hk, dk), 3.0 * draw(b, seq, hk, dk)
     v, ct = draw(b, seq, hv, dv), draw(b, seq, hv, dv)
     g = -decay * jnp.asarray(rng.random((b, seq, hv)), jnp.float32)
     beta = jnp.asarray(rng.random((b, seq, hv)), jnp.float32)
+    return (q, k, v, g, beta), ct
 
-    def recurrence(q, k, v, g, beta):
-        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
-        return ref.delta_rule(q, k, v, g, beta)
 
-    def chunked(*a):
-        return la.gated_delta_rule(*a, chunk=chunk)
+def token_recurrence(q, k, v, g, beta):
+    """The rule on rows of q and k normalised as its kernels do it."""
+    rep = v.shape[2] // q.shape[2]
+    q = la.l2_normalize(q) * q.shape[-1] ** -0.5
+    k = la.l2_normalize(k)
+    q, k = (jnp.repeat(a, rep, axis=2) for a in (q, k))
+    return ref.delta_rule(q, k, v, g, beta)
 
-    args = (q, k, v, g, beta)
-    np.testing.assert_allclose(chunked(*args), recurrence(*args), atol=3e-6)
-    got = jax.grad(lambda *a: (chunked(*a) * ct).sum(), range(5))(*args)
-    want = jax.grad(lambda *a: (recurrence(*a) * ct).sum(), range(5))(*args)
-    for name, a, c in zip("q k v g beta".split(), got, want):
+
+def output_and_gradients(rule, args, ct):
+    """o and d (o . ct) / d (q, k, v, g, beta), float32."""
+    def dot(*a):
+        return (rule(*a).astype(jnp.float32) * ct).sum()
+
+    return (rule(*args).astype(jnp.float32),) + jax.grad(dot, range(5))(*args)
+
+
+@pytest.mark.parametrize("decay", [1e-3, 1.0, 12.0],
+                         ids=["near_one", "middling", "near_zero"])
+@pytest.mark.parametrize("seq,chunk,heads", [
+    (192, 64, SMALL_HEADS), (64, 16, SMALL_HEADS), (48, 64, SMALL_HEADS),
+    (1024, 64, CELL_HEADS)], ids=["192-64", "64-16", "48-64", "cell"])
+def test_chunked_delta_rule_matches_recurrence(seq, chunk, heads, decay):
+    args, ct = delta_rule_inputs(seq, heads, decay, seq + chunk)
+    got = output_and_gradients(
+        lambda *a: la.gated_delta_rule(*a, chunk=chunk), args, ct)
+    want = output_and_gradients(jax.jit(token_recurrence), args, ct)
+    np.testing.assert_allclose(got[0], want[0], atol=3e-6)
+    for name, a, c in zip("q k v g beta".split(), got[1:], want[1:]):
         np.testing.assert_allclose(
             a, c, atol=5e-5 * float(jnp.abs(c).max()) + 1e-7, err_msg=name)
+
+
+def test_bf16_delta_rule_stays_within_its_rounding_of_the_recurrence():
+    """bf16 q, k, v as under AMP-O2 (the inverse then takes its three bf16
+    passes; state, gates and sums stay float32), against the float32
+    recurrence on the same rounded inputs: o and the five gradients within
+    a few roundings to 8 bits (2^-8 = 0.004) by norm."""
+    (q, k, v, g, beta), ct = delta_rule_inputs(1024, CELL_HEADS, 1.0, 5)
+    args = tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
+    got = output_and_gradients(la.gated_delta_rule, args, ct)
+    want = output_and_gradients(
+        jax.jit(token_recurrence),
+        tuple(a.astype(jnp.float32) for a in args), ct)
+    for name, a, c in zip("o q k v g beta".split(), got, want):
+        gap = float(jnp.linalg.norm(a.astype(jnp.float32) - c)
+                    / jnp.linalg.norm(c))
+        assert gap < 0.008, (name, gap)
+
+
+def test_inverse_products_take_three_bf16_passes():
+    """A float32 product from bf16 halves: high x high alone is one rounding
+    to 8 bits off, with the two cross terms 2^-16; float32 operands are not
+    split."""
+    rng = np.random.default_rng(17)
+    a, b = (jnp.asarray(rng.standard_normal((64, 64)), jnp.float32)
+            for _ in range(2))
+    exact = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+
+    def gap(x):
+        return float(np.linalg.norm(x - exact) / np.linalg.norm(exact))
+
+    ha, hb = la._halves(a, jnp.bfloat16), la._halves(b, jnp.bfloat16)
+    assert [x.dtype for x in ha] == [jnp.bfloat16] * 2
+    assert 1e-3 < gap(la._mm_halves(ha[:1], hb[:1])) < 5e-3
+    assert gap(la._mm_halves(ha, hb)) < 2e-5
+    assert gap(la._mm_halves(la._halves(a, jnp.float32),
+                             la._halves(b, jnp.float32))) < 1e-6
+
+
+def test_each_trace_of_the_rule_leaves_one_gdn_chunks_event():
+    """At the sizes the cell rehearses with (2 x 128 tokens, 2 key and 4
+    value heads of 16): forward and backward traced together leave ONE
+    event, the chunks prepared in VMEM; a call that replays the program
+    leaves none."""
+    args, ct = delta_rule_inputs(128, (2, 2, 4, 16, 16), 1.0, 9)
+    fn = jax.jit(jax.grad(
+        lambda *a: (la.gated_delta_rule(*a) * ct).sum(), range(5)))
+    before = len(trace.events(kind="gdn_chunks"))
+    jax.block_until_ready(fn(*args))
+    (event,) = trace.events(kind="gdn_chunks")[before:]
+    assert event.site == "gated_delta_rule"
+    assert event.attrs == dict(seq=128, chunk=64, chunks_per_step=2, rep=2,
+                               heads_in_lanes=False, prepared="vmem")
+    jax.block_until_ready(fn(*args))
+    assert len(trace.events(kind="gdn_chunks")) == before + 1
 
 
 def test_short_conv_and_its_written_out_backward():
