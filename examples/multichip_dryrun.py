@@ -1,7 +1,7 @@
 """Multichip dryrun builders for the sharding analyzer / graph_lint --mesh.
 
-The CPU-simulated hybrid-parallel GPT step at dryrun shapes — the same
-model/mesh family the MULTICHIP_r0*.json snapshots exercise — exposed as
+The CPU-simulated hybrid-parallel GPT step at dryrun shapes — the
+model/mesh family of `__graft_entry__.dryrun_multichip` — exposed as
 graph_lint model builders so the static analysis suite (per-shard memory,
 donation proofs, collective cost, resharding lints) can gate it in CI
 without compiling or running a step:

@@ -831,8 +831,7 @@ def plan_block_pool(trace_thunk, *, block_bytes: int,
 def captured_step_plans():
     """(donation-credited plan, no-donation plan) of the most recently
     replayed captured whole-step program on this thread, or None — the
-    shared recipe behind bench.py's memory trajectory and
-    paddle.profiler.measure_programs."""
+    recipe behind paddle.profiler.measure_programs's `_memory` entry."""
     from ..core import lazy
 
     prog = lazy.captured_step_program()

@@ -873,7 +873,9 @@ def sharded_step_context(step, batch_specs, *, memory_budget_mb=None,
         raise ValueError("sharded_step_context needs a step with a mesh")
     states = step._opt_state
     if states is None:
-        states = step._init_state()
+        from ..jit.step import init_opt_state
+
+        states = init_opt_state(step.optimizer, step._params)
     p_sh, st_sh, b_sh, batch_sh = step._shardings(states)
     batch_sds = _norm_batch_specs(batch_specs)
     step_fn, in_sh, out_sh = step._step_parts(len(batch_sds), states)

@@ -220,9 +220,6 @@ define_flag(
     "path is testable on CPU (slow; parity/debugging only)",
 )
 define_flag(
-    "use_standalone_executor", True, "use the compiled whole-program executor path"
-)
-define_flag(
     "check_programs", 0,
     "run the paddle_tpu.analysis verifier over every program at compile "
     "time (Executor.run) and at lazy-segment flush: 0 = off, 1 = report "
@@ -700,16 +697,11 @@ define_flag(
     "CheckFreq discipline: let the previous action's effect land in the "
     "measured signals before proposing another",
 )
-define_flag("max_inplace_grad_add", 0, "grad accumulation chunking (compat)")
 define_flag(
     "use_flash_attention",
     True,
     "route scaled_dot_product_attention through the Pallas flash kernel "
     "when shapes/mask allow (fused_attention_op.cu analogue)",
-)
-define_flag("init_allocated_mem", False, "compat: poison fresh allocations")
-define_flag(
-    "allocator_strategy", "auto_growth", "compat: allocator strategy name (XLA owns HBM)"
 )
 define_flag(
     "fraction_of_gpu_memory_to_use", 0.92,
@@ -717,5 +709,3 @@ define_flag(
     "no explicit budget is given (analysis.memory.plan_block_pool sizes the "
     "serving KV pool against it); the rest stays free for other programs",
 )
-define_flag("cudnn_deterministic", False, "compat: deterministic kernels")
-define_flag("embedding_deterministic", 0, "compat: deterministic embedding grad")

@@ -2045,7 +2045,7 @@ def captured_step_program():
     recently replayed captured whole-step executable on this thread, or
     None when no capture has replayed yet (or its cache entry has been
     evicted and collected). Trace-only (no compile) — feeds the
-    paddle_tpu.analysis.memory planner, bench.py's memory trajectory, and
+    paddle_tpu.analysis.memory planner and
     paddle.profiler.measure_programs."""
     ref = getattr(_tls, "last_capture_entry", None)
     entry = ref() if ref is not None else None
@@ -2702,8 +2702,8 @@ def reset_serve_programs(owner=None):
 
 
 def serve_capture_state() -> Dict[str, Any]:
-    """Snapshot of the decode-mode capture cache (bench.py's serving record
-    and tests read this)."""
+    """Snapshot of the decode-mode capture cache (`Engine.stats()` and the
+    diag server's /statusz read this)."""
     return {
         "cached_programs": len(_serve_cache),
         "built_programs": sum(
@@ -2730,7 +2730,7 @@ def step_signature_id() -> Optional[int]:
 
 def step_capture_state() -> Dict[str, Any]:
     """Snapshot of this thread's whole-step capture controller (for
-    bench.py's capture-state line and paddle.profiler.measure_programs)."""
+    paddle.profiler.measure_programs's `_capture_state` entry)."""
     obs = getattr(_tls, "observer", None)
     tier_info = getattr(_tls, "capture_tier", None) or {}
     return {

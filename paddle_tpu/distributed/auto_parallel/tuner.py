@@ -194,8 +194,7 @@ class ProfileTuner:
                     out = step(*batch)
                 float(out)  # sync
                 # min-of-iters: ambient load only ever slows an iteration,
-                # so the minimum is the honest cost (same estimator as
-                # bench.py's _best_window)
+                # so the minimum is the honest cost
                 dt = float("inf")
                 for _ in range(self.iters):
                     t0 = time.perf_counter()

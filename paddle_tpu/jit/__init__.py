@@ -19,14 +19,15 @@ forward. Data-dependent Python control flow must use static shapes /
 lax.cond-style ops, mirroring the reference's ProgramTranslator constraints.
 
 `compile_train_step` goes further: forward + backward + optimizer update in
-one donated-buffer XLA program — the performance path used by hapi, bench,
-and the distributed engine.
+one donated-buffer XLA program — the performance path used by hapi, the
+benchmark's cells, and the distributed engine. The step program itself (loss,
+gradient, clip, update) is `jit/step.py`, shared with the mesh builders.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +39,8 @@ from ..core.dispatch import apply, no_grad
 from ..core.tensor import Tensor
 from ..nn.layer_base import Layer
 from ..profiler import trace as _trace
+from . import step as _step_core
+from .step import _bind_values
 
 __all__ = [
     "to_static",
@@ -66,18 +69,6 @@ class InputSpec:
 # ---------------------------------------------------------------------------
 # functional bridge: run a stateful Layer with swapped-in (traced) values
 # ---------------------------------------------------------------------------
-@contextlib.contextmanager
-def _bind_values(tensors: Sequence[Tensor], values: Sequence[Any]):
-    saved = [t._value for t in tensors]
-    for t, v in zip(tensors, values):
-        t._value = v
-    try:
-        yield
-    finally:
-        for t, s in zip(tensors, saved):
-            t._value = s
-
-
 def functional_call(layer: Layer, params: Dict[str, Any], *args, rngs=None, **kwargs):
     """Run `layer` with parameter/buffer values from `params` (a dict from
     state_dict-style names to arrays/tracers). Tape recording is disabled —
@@ -370,7 +361,6 @@ class CompiledTrainStep:
         self._opt_state = None
         self._params = [p for p in model.parameters() if not p.stop_gradient]
         self._buffers = [b for _, b in model.named_buffers()]
-        self._hyper = optimizer._hyper()
         # batch positions to ALSO differentiate: their grads come back to
         # the caller instead of an optimizer (the PS sparse path — pulled
         # embedding rows are step inputs, their grads push to the host
@@ -392,47 +382,12 @@ class CompiledTrainStep:
             # arrays — anything the offload scheduler parked must come home
             # before the program takes ownership
             sched.ensure_resident(self.optimizer, self._params)
-        states = []
-        for p in self._params:
-            st = self.optimizer._accumulators.get(id(p))
-            if st is None:
-                st = self.optimizer._create_state(p)
-                self.optimizer._accumulators[id(p)] = st
-            states.append(st)
-        return states
+        return _step_core.init_opt_state(self.optimizer, self._params)
 
     def _make_loss_core(self):
-        """The pure loss path `(p_vals, diff_vals, b_vals, key, batch_vals)
-        -> (loss, new_buffers)` — every array input explicit (no tracer
-        closure), so the remat planner can trace it standalone, slice it
-        into jax.checkpoint stages, and substitute the planned callable
-        into the step with identical semantics."""
-        model = self.model
-        loss_fn = self.loss_fn
-        params = self._params
-        buffers = self._buffers
-        gidx = self._grad_input_idx
-
-        def loss_core(p_vals, diff_vals, b_vals, key, batch_vals):
-            full = list(batch_vals)
-            for i, v in zip(gidx, diff_vals):
-                full[i] = v
-            ins = [Tensor(v, stop_gradient=True) for v in full]
-            with _bind_values(params + buffers, list(p_vals) + list(b_vals)), \
-                    no_grad(), _random.rng_scope(key):
-                # the sections the profiler's device view splits by; the
-                # backward of each reads transpose(jvp(forward))/... by itself
-                with jax.named_scope("forward"):
-                    out = model(*ins[:-1]) if len(ins) > 1 else model(ins[0])
-                with jax.named_scope("loss"):
-                    loss = (loss_fn(out, ins[-1]) if loss_fn is not None
-                            else out)
-                # buffer values after forward (BN running stats updates)
-                new_b = tuple(b._value for b in buffers)
-            lv = loss._value if isinstance(loss, Tensor) else loss
-            return lv, new_b
-
-        return loss_core
+        return _step_core.make_loss_core(
+            self.model, self.loss_fn, self._params, self._buffers,
+            grad_input_idx=self._grad_input_idx)
 
     def _wrap_flat_loss(self, flat_fn):
         """Adapt a planned flat callable (the sliced loss jaxpr's invars in
@@ -449,60 +404,11 @@ class CompiledTrainStep:
         return planned_loss
 
     def _make_step_fn(self, planned_loss=None):
-        opt = self.optimizer
-        params = self._params
-        hyper = self._hyper
-        rule = type(opt)._update
-
-        # static per-parameter hyper overrides (e.g. AdamW's
-        # apply_decay_param_fun excluding biases from weight decay)
-        per_hyper = [dict(hyper, **opt._per_param_hyper(p)) for p in params]
-        grad_clip = opt._grad_clip
-        # ASP masks (incubate/asp.py): pruned params must stay n:m sparse
-        # through the compiled update too — fold the mask into the new
-        # param value (mask is a traced constant; prune BEFORE building)
-        from ..incubate import asp as _asp
-
-        asp_masks = [_asp._mask_for(p) for p in params]
-
-        gidx = self._grad_input_idx
-        loss_core = planned_loss if planned_loss is not None \
-            else self._make_loss_core()
-
-        def step_fn(p_vals, opt_states, b_vals, key, lr, *batch_vals):
-            def loss_of(p_vals, diff_vals):
-                return loss_core(p_vals, diff_vals, b_vals, key,
-                                 tuple(batch_vals))
-
-            (loss, new_b), (grads, in_grads) = jax.value_and_grad(
-                loss_of, argnums=(0, 1), has_aux=True
-            )(tuple(p_vals), tuple(batch_vals[i] for i in gidx))
-            if grad_clip is not None:
-                # the clip objects are pure jnp math on Tensor wrappers —
-                # tracer-safe, so the eager clip semantics apply unchanged
-                with jax.named_scope("grad_clip"):
-                    pairs = grad_clip(
-                        [
-                            (Tensor(pv, stop_gradient=True), Tensor(gv, stop_gradient=True))
-                            for pv, gv in zip(p_vals, grads)
-                        ]
-                    )
-                grads = [g._value for _, g in pairs]
-            new_p, new_s = [], []
-            with jax.named_scope("optimizer"):
-                for pv, gv, st, h, mask in zip(
-                    p_vals, grads, opt_states, per_hyper, asp_masks
-                ):
-                    if gv.dtype != pv.dtype:
-                        gv = gv.astype(pv.dtype)
-                    np_, ns_ = rule(opt, pv, gv, lr, st, **h)
-                    if mask is not None:
-                        np_ = np_ * mask.astype(np_.dtype)
-                    new_p.append(np_)
-                    new_s.append(ns_)
-            return loss, in_grads, tuple(new_p), tuple(new_s), new_b
-
-        return step_fn
+        return _step_core.make_step_fn(
+            planned_loss if planned_loss is not None
+            else self._make_loss_core(),
+            self.optimizer, self._params,
+            grad_input_idx=self._grad_input_idx)
 
     def _batch_shardings(self, n_batch):
         """One jax Sharding (or None = uncommitted) per batch argument,
@@ -768,19 +674,10 @@ class CompiledTrainStep:
                 # the frame's teardown, freeing ~5 arrays a parameter would be
                 # host time of the step that no span covers
                 del args
-                for p, v in zip(self._params, new_p):
-                    p._value = v
-                for b, v in zip(self._buffers, new_b):
-                    b._value = v
-                self._opt_state = list(new_s)
-                for p, st in zip(self._params, self._opt_state):
-                    self.optimizer._accumulators[id(p)] = st
-                self.optimizer._step_count += 1
-                loss_t = Tensor(loss, stop_gradient=True)
-                if self._grad_input_idx:
-                    return loss_t, [Tensor(g, stop_gradient=True)
-                                    for g in in_grads]
-                return loss_t
+                self._opt_state = _step_core.write_back(
+                    self.optimizer, self._params, self._buffers,
+                    new_p, new_s, new_b)
+                return _step_core.step_result(loss, in_grads)
 
     def _gather_args(self, batch):
         """The jitted step's arguments for this call; (re)builds the step

@@ -32,9 +32,12 @@ def make_fused_update(opt, params, sentinel=False, telemetry=False):
     (new_ps, new_states)` over `opt`'s rule for `params`.
 
     The ONE definition of the traced optimizer math shared by the eager
-    fused step (`_apply_fused`) and the whole-step capture trace
-    (core/lazy.py `_build_captured_step`): same rule, same static global +
-    per-param hyper merge, same grad-dtype cast. The rule is bound to a
+    fused step (`_apply_fused`), the whole-step capture trace
+    (core/lazy.py `_build_captured_step`) and every compiled builder
+    (jit/step.py `make_step_fn`, the pipelined step's two appliers): same
+    rule, same static global + per-param hyper merge (e.g. AdamW's
+    apply_decay_param_fun excluding biases from weight decay), same
+    grad-dtype cast. The rule is bound to a
     bare shim carrying just `_weight_decay` — NOT the live optimizer — so
     callers can cache the (jitted) closure without pinning the instance
     and its accumulators.
@@ -64,13 +67,17 @@ def make_fused_update(opt, params, sentinel=False, telemetry=False):
     changes. The enablement is part of both compile-cache keys
     (_apply_fused's and the capture controller's), so flipping the flag
     retraces instead of replaying a stale program."""
-    from ..ops.pallas import fused_update as _pfu
-
     rule = type(opt)._update
     hypers = [dict(opt._hyper(), **opt._per_param_hyper(p)) for p in params]
     ctx = object.__new__(type(opt))
     ctx._weight_decay = opt._weight_decay
-    kind = _pfu.rule_kind(type(opt)) if _pfu.enabled() else None
+    kind = None
+    if _flags.flag("pallas_fused_update"):
+        # imported only where the flag asks for it: the default path of
+        # every step builder stays clear of Pallas
+        from ..ops.pallas import fused_update as _pfu
+
+        kind = _pfu.rule_kind(type(opt)) if _pfu.enabled() else None
 
     def apply_update(p_vals, g_vals, lr, states):
         bad = None

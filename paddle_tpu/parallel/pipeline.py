@@ -42,8 +42,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .._jax_compat import shard_map
 
+from ..core import random as _random
 from ..core.dispatch import no_grad
 from ..core.tensor import Tensor
+from ..jit.step import _bind_values, clip_grads
+from ..optimizer.optimizer import make_fused_update
 from .topology import axis_size as _mesh_axis_size, get_mesh
 
 __all__ = ["gpipe_loss", "PipelinedTrainStep", "pipelined_train_step"]
@@ -194,7 +197,6 @@ class PipelinedTrainStep:
                 f"(running statistics) yet: {names[:5]} — use LayerNorm/"
                 "GroupNorm in the pipelined middle or pp_degree=1"
             )
-        self._hyper = optimizer._hyper()
         self._step = None
         self._loss_program = None  # forward GPipe loss (for the analyzer)
         self._stacked = None      # list of [L, ...] arrays, one per block param
@@ -302,22 +304,17 @@ class PipelinedTrainStep:
         """(step_fn, in_shardings, out_shardings) pre-jit — the sharding
         analyzer traces step_fn at per-shard shapes without compiling;
         _build wraps the same triple in jax.jit."""
-        from ..jit import _bind_values
-        from ..core import random as _random
-
         mesh, S, M = self.mesh, self.S, self.M
-        model, loss_fn, opt = self.model, self.loss_fn, self.optimizer
+        loss_fn, opt = self.loss_fn, self.optimizer
         template_params = self.block_param_objs[0]
         t_objs = _named_params(self.template)
         repl_params, buffers = self._repl_params, self._buffers
         pre_fn, post_fn = self.pre_fn, self.post_fn
         L_per = len(self.blocks) // S
-        hyper = self._hyper
-        per_hyper_stack = [
-            dict(hyper, **opt._per_param_hyper(p)) for p in template_params
-        ]
-        per_hyper_repl = [dict(hyper, **opt._per_param_hyper(p)) for p in repl_params]
-        rule = type(opt)._update
+        # the stacked [L, ...] arrays update under the template block's
+        # per-parameter hypers: the blocks are homogeneous
+        update_repl = make_fused_update(opt, repl_params)
+        update_stacked = make_fused_update(opt, template_params)
         grad_clip = opt._grad_clip
         remat = self.remat
 
@@ -404,38 +401,19 @@ class PipelinedTrainStep:
                 smapped, argnums=(0, 1)
             )(tuple(repl_vals), tuple(stacked_vals), tuple(b_vals), key, x_mb, y_mb)
 
-            if grad_clip is not None:
-                # one global clip over replicated + stacked grads (the
-                # stacked arrays already hold all layers, so the global norm
-                # matches the unstacked model's)
-                n_r = len(repl_vals)
-                pairs = grad_clip(
-                    [
-                        (Tensor(pv, stop_gradient=True), Tensor(gv, stop_gradient=True))
-                        for pv, gv in zip(
-                            list(repl_vals) + list(stacked_vals),
-                            list(g_repl) + list(g_stacked),
-                        )
-                    ]
-                )
-                clipped = [g._value for _, g in pairs]
-                g_repl, g_stacked = clipped[:n_r], clipped[n_r:]
-
-            new_repl, new_rs = [], []
-            for pv, gv, st, h in zip(repl_vals, g_repl, repl_states, per_hyper_repl):
-                if gv.dtype != pv.dtype:
-                    gv = gv.astype(pv.dtype)
-                np_, ns_ = rule(opt, pv, gv, lr, st, **h)
-                new_repl.append(np_)
-                new_rs.append(ns_)
-            new_stacked, new_ss = [], []
-            for pv, gv, st, h in zip(stacked_vals, g_stacked, stacked_states,
-                                     per_hyper_stack):
-                if gv.dtype != pv.dtype:
-                    gv = gv.astype(pv.dtype)
-                np_, ns_ = rule(opt, pv, gv, lr, st, **h)
-                new_stacked.append(np_)
-                new_ss.append(ns_)
+            # one global clip over replicated + stacked grads (the
+            # stacked arrays already hold all layers, so the global norm
+            # matches the unstacked model's)
+            n_r = len(repl_vals)
+            clipped = clip_grads(
+                grad_clip, list(repl_vals) + list(stacked_vals),
+                list(g_repl) + list(g_stacked))
+            g_repl, g_stacked = clipped[:n_r], clipped[n_r:]
+            with jax.named_scope("optimizer"):
+                new_repl, new_rs = update_repl(
+                    repl_vals, g_repl, lr, repl_states)
+                new_stacked, new_ss = update_stacked(
+                    stacked_vals, g_stacked, lr, stacked_states)
             return loss, tuple(new_repl), tuple(new_stacked), tuple(new_rs), tuple(new_ss)
 
         repl_sh = tuple(NamedSharding(mesh, s) for s in repl_specs)
@@ -494,8 +472,6 @@ class PipelinedTrainStep:
     # ---- call -------------------------------------------------------------
     @no_grad()
     def __call__(self, x, y) -> Tensor:
-        from ..core import random as _random
-
         if self._step is None:
             self._stacked = self._init_stacked()
             self._stacked_state = self._init_stacked_state()

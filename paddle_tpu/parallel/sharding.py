@@ -22,8 +22,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core import random as _random
 from ..core.dispatch import no_grad
 from ..core.tensor import Tensor
+from ..jit import step as _step_core
 from .topology import get_mesh
 
 ShardingSpec = P
@@ -231,27 +233,20 @@ class ShardedTrainStep:
     def __init__(self, model, loss_fn, optimizer, mesh=None, zero_stage=0,
                  batch_axes=("dp", "sharding"), forward_ctx=None,
                  accumulate_steps=1, loss_scale=1.0, grad_input_idx=()):
-        # batch positions to ALSO differentiate — their grads return to the
-        # caller (the PS sparse path: pulled rows in, row grads out, pushed
-        # to the host table; reference: distributed_push_sparse)
-        self.grad_input_idx = tuple(int(i) for i in grad_input_idx)
-        if self.grad_input_idx and int(accumulate_steps) > 1:
-            raise ValueError(
-                "grad_input_idx is not supported with compiled gradient "
-                "merge (the per-microbatch input grads would need their "
-                "own accumulation contract)"
-            )
+        # grad_input_idx: batch positions to ALSO differentiate — their
+        # grads return to the caller (the PS sparse path: pulled rows in, row
+        # grads out, pushed to the host table; reference:
+        # distributed_push_sparse). accumulate_steps > 1 = compiled gradient
+        # merge: the leading batch dim must divide into that many
+        # microbatches (strategy.gradient_merge)
+        self.grad_input_idx, self.accumulate_steps = _step_core.check_merge(
+            grad_input_idx, accumulate_steps)
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer
         # zero-arg context-manager factory wrapped around the traced forward
         # (fleet wires strategy.amp through here as an auto_cast factory)
         self.forward_ctx = forward_ctx
-        # >1 = compiled gradient merge: the leading batch dim must divide
-        # into accumulate_steps microbatches (strategy.gradient_merge)
-        self.accumulate_steps = int(accumulate_steps)
-        if self.accumulate_steps < 1:
-            raise ValueError("accumulate_steps must be >= 1")
         # static loss scaling for pure-fp16 compute (1.0 = off); grads are
         # unscaled before clipping/update inside the compiled step
         self.loss_scale = float(loss_scale)
@@ -262,19 +257,8 @@ class ShardedTrainStep:
         )
         self._params = [p for p in model.parameters() if not p.stop_gradient]
         self._buffers = [b for _, b in model.named_buffers()]
-        self._hyper = optimizer._hyper()
         self._step = None
         self._opt_state = None
-
-    def _init_state(self):
-        states = []
-        for p in self._params:
-            st = self.optimizer._accumulators.get(id(p))
-            if st is None:
-                st = self.optimizer._create_state(p)
-                self.optimizer._accumulators[id(p)] = st
-            states.append(st)
-        return states
 
     def _shardings(self, opt_state=None):
         mesh = self.mesh
@@ -304,22 +288,7 @@ class ShardedTrainStep:
         (analysis.sharding.check_sharded_step) traces step_fn at per-shard
         shapes without paying the XLA compile; _build wraps the same triple
         in jax.jit."""
-        from ..jit import _bind_values
-        from ..core import random as _random
-
-        model, loss_fn, opt = self.model, self.loss_fn, self.optimizer
-        params, buffers = self._params, self._buffers
-        hyper = self._hyper
-        per_hyper = [dict(hyper, **opt._per_param_hyper(p)) for p in params]
-        rule = type(opt)._update
-        grad_clip = opt._grad_clip
-
-        import contextlib
-
-        fwd_ctx = self.forward_ctx or contextlib.nullcontext
-
-        accum_k = self.accumulate_steps
-        loss_scale = self.loss_scale
+        params = self._params
         # hybrid dp×sharding + ZeRO: GSPMD cannot partition the weight-grad
         # dots when the grad's zero-spec (sharded over 'sharding', replicated
         # over 'dp') propagates into batch-sharded activations that span
@@ -338,107 +307,27 @@ class ShardedTrainStep:
         # stages pin when both axes are real
         hybrid_zero = (self.zero_stage in (1, 2, 3) and axes.get("dp", 1) > 1
                        and axes.get("sharding", 1) > 1)
+        pin_grads = None
         if hybrid_zero:
             grad_pin = [
                 NamedSharding(self.mesh, param_spec(p, 0, self.mesh))
                 for p in params
             ]
 
-        gidx = self.grad_input_idx
-
-        def step_fn(p_vals, opt_states, b_vals, key, lr, *batch_vals):
-            def loss_of(p_vals, b_vals, key, batch_vals, diff_vals=()):
-                batch_vals = list(batch_vals)
-                for i, v in zip(gidx, diff_vals):
-                    batch_vals[i] = v
-                ins = [Tensor(v, stop_gradient=True) for v in batch_vals]
-                with _bind_values(params + buffers, list(p_vals) + list(b_vals)), \
-                        no_grad(), _random.rng_scope(key), fwd_ctx():
-                    out = model(*ins[:-1]) if len(ins) > 1 else model(ins[0])
-                    loss = loss_fn(out, ins[-1]) if loss_fn is not None else out
-                    new_b = tuple(b._value for b in buffers)
-                lv = loss._value if isinstance(loss, Tensor) else loss
-                if loss_scale != 1.0:
-                    lv = lv * loss_scale
-                return lv, new_b
-
-            if accum_k > 1:
-                # compiled gradient merge (reference: GradientMergeOptimizer
-                # program rewrite): split the global batch into k chunks and
-                # lax.scan value_and_grad over them, accumulating fp32 grads
-                # — peak activation memory is one microbatch's, the update
-                # applies ONCE on the averaged gradient
-                chunks = tuple(
-                    v.reshape((accum_k, v.shape[0] // accum_k) + v.shape[1:])
-                    for v in batch_vals
-                )
-                keys = jax.random.split(key, accum_k)
-
-                def scan_body(carry, xs):
-                    g_acc, b_cur = carry
-                    k_i, chunk = xs[0], xs[1:]
-                    (lv, new_b), gs = jax.value_and_grad(
-                        loss_of, has_aux=True)(tuple(p_vals), b_cur, k_i, chunk)
-                    g_acc = tuple(
-                        a + g.astype(jnp.float32) for a, g in zip(g_acc, gs)
-                    )
-                    return (g_acc, new_b), lv
-
-                g0 = tuple(
-                    jnp.zeros(p.shape, jnp.float32) for p in p_vals
-                )
-                (g_acc, new_b), losses = jax.lax.scan(
-                    scan_body, (g0, tuple(b_vals)), (keys,) + chunks
-                )
-                grads = tuple(
-                    (g / accum_k).astype(p.dtype)
-                    for g, p in zip(g_acc, p_vals)
-                )
-                loss = jnp.mean(losses)
-                in_grads = ()  # gidx is rejected with gradient merge
-            elif gidx:
-                (loss, new_b), (grads, in_grads) = jax.value_and_grad(
-                    loss_of, argnums=(0, 4), has_aux=True
-                )(tuple(p_vals), tuple(b_vals), key, tuple(batch_vals),
-                  tuple(batch_vals[i] for i in gidx))
-            else:
-                in_grads = ()
-                (loss, new_b), grads = jax.value_and_grad(
-                    loss_of, has_aux=True
-                )(tuple(p_vals), tuple(b_vals), key, tuple(batch_vals))
-            if hybrid_zero:
-                grads = tuple(
+            def pin_grads(grads):
+                return tuple(
                     jax.lax.with_sharding_constraint(g, s)
                     for g, s in zip(grads, grad_pin)
                 )
-            if loss_scale != 1.0:
-                loss = loss / loss_scale
-                grads = tuple(
-                    (g.astype(jnp.float32) / loss_scale).astype(g.dtype)
-                    for g in grads
-                )
-                # input grads ship to the caller (PS push): they must be
-                # unscaled exactly like the param grads
-                in_grads = tuple(
-                    (g.astype(jnp.float32) / loss_scale).astype(g.dtype)
-                    for g in in_grads
-                )
-            if grad_clip is not None:
-                pairs = grad_clip(
-                    [
-                        (Tensor(pv, stop_gradient=True), Tensor(gv, stop_gradient=True))
-                        for pv, gv in zip(p_vals, grads)
-                    ]
-                )
-                grads = [g._value for _, g in pairs]
-            new_p, new_s = [], []
-            for pv, gv, st, h in zip(p_vals, grads, opt_states, per_hyper):
-                if gv.dtype != pv.dtype:
-                    gv = gv.astype(pv.dtype)
-                np_, ns_ = rule(opt, pv, gv, lr, st, **h)
-                new_p.append(np_)
-                new_s.append(ns_)
-            return loss, tuple(in_grads), tuple(new_p), tuple(new_s), new_b
+
+        gidx = self.grad_input_idx
+        step_fn = _step_core.make_step_fn(
+            _step_core.make_loss_core(
+                self.model, self.loss_fn, params, self._buffers,
+                grad_input_idx=gidx, forward_ctx=self.forward_ctx),
+            self.optimizer, params, grad_input_idx=gidx,
+            accumulate_steps=self.accumulate_steps,
+            loss_scale=self.loss_scale, pin_grads=pin_grads)
 
         p_sh, st_sh, b_sh, batch_sh = self._shardings(opt_state)
         repl = NamedSharding(self.mesh, P())
@@ -496,7 +385,8 @@ class ShardedTrainStep:
             # match declarations. Separate from the compile so a tuner can
             # reset state on an already-compiled winner (trial steps
             # mutate it) without paying the XLA compile twice.
-            self._opt_state = self._init_state()
+            self._opt_state = _step_core.init_opt_state(
+                self.optimizer, self._params)
             _, st_sh, _, _ = self._shardings()
             self._opt_state = [
                 {k: jax.device_put(v, sh[k]) for k, v in st.items()}
@@ -515,28 +405,13 @@ class ShardedTrainStep:
         p_vals = tuple(p._value for p in self._params)
         b_vals = tuple(b._value for b in self._buffers)
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        key = _next_key()
+        key = _random.next_key()
         loss, in_grads, new_p, new_s, new_b = self._step(
             p_vals, tuple(self._opt_state), b_vals, key, lr, *batch_vals
         )
-        for p, v in zip(self._params, new_p):
-            p._value = v
-        for b, v in zip(self._buffers, new_b):
-            b._value = v
-        self._opt_state = list(new_s)
-        for p, st in zip(self._params, self._opt_state):
-            self.optimizer._accumulators[id(p)] = st
-        self.optimizer._step_count += 1
-        loss_t = Tensor(loss, stop_gradient=True)
-        if self.grad_input_idx:
-            return loss_t, [Tensor(g, stop_gradient=True) for g in in_grads]
-        return loss_t
-
-
-def _next_key():
-    from ..core import random as _random
-
-    return _random.next_key()
+        self._opt_state = _step_core.write_back(
+            self.optimizer, self._params, self._buffers, new_p, new_s, new_b)
+        return _step_core.step_result(loss, in_grads)
 
 
 def sharded_train_step(model, loss_fn, optimizer, mesh=None, zero_stage=0,

@@ -736,8 +736,8 @@ def telemetry_record_cost_ms() -> Optional[float]:
 
 def measure_record_cost_ms(names=None, n: int = 500, reps: int = 3) -> float:
     """Tight-loop microbenchmark of one ``record_telemetry`` call (min of
-    ``reps`` windows) — the analytic telemetry-overhead numerator the
-    obs_probe triage gate and bench.py share: marginal record cost × one
+    ``reps`` windows) — the analytic telemetry-overhead numerator of
+    tools/obs_probe.py's triage gate: marginal record cost × one
     record/step over step time, same discipline as the flight-recorder
     per-emit bound (a wall-clock A/B at 1% resolution does not replicate
     on a shared box). MUTATES telemetry state (ring/gauges/counters) —

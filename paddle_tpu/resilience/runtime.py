@@ -219,7 +219,7 @@ def on_step_end(source: str = "train"):
 
 def state() -> dict:
     """Snapshot of the resilience runtime (profiler.measure_programs's
-    `_resilience` entry and bench.py's resilience block read this)."""
+    `_resilience` entry reads this)."""
     return {
         "step": faults.current_step(),
         "fault_inject": str(flags.flag("fault_inject")),

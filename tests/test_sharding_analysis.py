@@ -332,8 +332,7 @@ def test_gpt_dp2mp2_estimate_matches_measured_per_device(gpt_dp2mp2):
     dev0 = jax.devices()[0]
     measured, seen = 0, set()
     for leaf in jax.tree_util.tree_leaves(
-            (step._params, step._buffers, step._opt_state, step._hyper,
-             x, y, loss)):
+            (step._params, step._buffers, step._opt_state, x, y, loss)):
         # Tensor._value is the jax array; but on a raw jax ArrayImpl
         # ._value is a numpy conversion, so prefer the leaf itself
         arr = leaf if hasattr(leaf, "addressable_shards") \

@@ -113,8 +113,7 @@ def _scrape_build_p50():
 def measure_scrape_latency(addr, n=30, timeout=5.0):
     """`n` sequential /metrics scrapes against a live diag server:
     client-side p50/p99 round-trip ms plus the server-side build p50 —
-    the ONE scrape-latency definition bench.py's observability block and
-    the diag-server scenario share."""
+    the scrape-latency definition of the diag-server scenario."""
     lats = []
     for _ in range(n):
         t0 = time.perf_counter()
