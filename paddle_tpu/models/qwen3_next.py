@@ -155,20 +155,21 @@ def _gated_delta_mixer(qkvz, ba, conv_w, a_log, dt_bias, norm_w, *, hk, hv,
     b, s = qkvz.shape[0], qkvz.shape[1]
     n_qk, n_v = hk * dk, hv * dv
     with jax.named_scope("short_conv"):
-        qkv = _la.short_conv_silu(qkvz[..., :2 * n_qk + n_v], conv_w)
-    z = qkvz[..., 2 * n_qk + n_v:].reshape(b, s, hv, dv)
+        # over qkvz's first 2 n_qk + n_v lanes, read where they lie; q, k
+        # and v each written where the rule reads it
+        q, k, v = _la.short_conv_silu(qkvz, conv_w, (n_qk, n_qk, n_v))
     with jax.named_scope("gated_delta_rule"):
         # q and k are normalised (l2, a head's row) and q scaled by
         # d_k^-0.5 inside the rule's kernels
-        q = qkv[..., :n_qk].reshape(b, s, hk, dk)
-        k = qkv[..., n_qk:2 * n_qk].reshape(b, s, hk, dk)
-        v = qkv[..., 2 * n_qk:].reshape(b, s, hv, dv)
+        q, k = q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk)
+        v = v.reshape(b, s, hv, dv)
         g, beta = _la.decay_and_beta(ba[..., hv:], ba[..., :hv], a_log,
                                      dt_bias)
         o = _la.gated_delta_rule(q, k, v, g, beta)
     with jax.named_scope("gated_norm"):
-        y = _la.gated_rms_norm(o, z, norm_w, epsilon=eps)
-    return y.reshape(b, s, n_v)
+        # the gate z is qkvz's last n_v lanes
+        return _la.gated_rms_norm(o.reshape(b, s, n_v), qkvz, norm_w,
+                                  epsilon=eps)
 
 
 class Qwen3NextGatedDeltaNet(nn.Layer):

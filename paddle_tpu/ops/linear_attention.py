@@ -1,5 +1,6 @@
 """Recurrent-state ("linear attention") layers: the gated delta rule as a
-chunked scan, the short causal convolution before it, the gated norm after.
+chunked scan, the short causal convolution before it, the gated norm after,
+seven Pallas kernels in all.
 
 The gated delta rule keeps one [d_k, d_v] float32 state S per head:
 
@@ -37,6 +38,32 @@ by XLA, each norm went through HBM as a float32 array of the rows' size.
     (summed over the value heads of a key head, then through the norm), dv,
     dg, dbeta.
 
+Round the rule, two passes over rows, each as a forward and a backward
+kernel on (rows, 128 x n) lane blocks of [batch, seq, lanes], read where the
+projection or the rule left them (the conv's 8,192 channels and the gate's
+4,096 out of the 12,288-wide projection by lane-block offset), all
+per-channel and per-head work in VMEM in float32, each result written once:
+
+``short_conv_silu_fwd``  y_t = silu(sum_i w[:, i] x_(t-3+i)), zeros before a
+    SEQUENCE's start; the three rows before a block come from an 8-row block
+    of the same array, not from a padded copy.
+``short_conv_silu_bwd``  the conv's sums again, dc = dy silu'(c), dx = the
+    same taps read ahead (three rows of dc after the block made from 8-row
+    blocks of x and dy), dw summed in float32 in a block that stays resident
+    over batch and rows.
+``gated_rms_norm_fwd``  a head is one block of 128 lanes: mean of squares,
+    rsqrt, gain and silu(z) in registers; no norm factor leaves VMEM.
+``gated_rms_norm_bwd``  do, dz and the gain's float32 partial sums from o,
+    z, dy in one pass.
+
+Shapes that are not whole blocks of 128 lanes and rows of 8 take the same
+arithmetic as ``jax.numpy`` expressions (``mixer_pass.path``: "xla"). Under
+the two scopes XLA keeps: the conv's taps cast and turned to [4, channels],
+the gain as [1, 128], the last sums of dw ([32, channels] -> [channels, 4])
+and of the gain's gradient ([8, lanes] -> [128]), and the concatenation and
+``pad``s that take the pieces of dx and dz back to the projection's width,
+which it fuses with their sum.
+
 Operands of the products are in the type the inputs come in (bf16 under
 AMP-O2), sums, gates, decays, the inverse and the state in float32. The
 inverse's own products (and dA's) are three bf16 passes, written out as a
@@ -70,6 +97,358 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------------------
 # the layers round the rule
 # ---------------------------------------------------------------------------
+# The short conv and the gated norm are passes over rows: every channel (conv)
+# or head of 128 lanes (norm) for itself. Their kernels take (ROW_BLOCK,
+# LANE_BLOCK) blocks of [batch, seq, lanes] where the projection or the rule
+# left them and work through a block in strips of some rows of one 128-lane
+# block, which stay in registers; the conv's three rows before a block (and,
+# in its backward, after) come from 8-row blocks of the same array.
+ROW_BLOCK = 512
+LANE_BLOCK = 1024
+CONV_STRIP = 64   # rows: the conv's backward keeps eight strips' worth live
+NORM_STRIP = 128  # the norm's sums over 128 lanes wait on the cross-lane
+#                   unit, and a longer strip has more to do meanwhile
+HALO = 8
+
+
+class _Walk(NamedTuple):
+    rows: int   # of a grid step's block,
+    lanes: int  # its lanes,
+    sub: int    # and the rows of a strip
+
+
+def _walk(seq, strip, lanes, *starts):
+    """(how the kernels walk ``lanes`` lanes of rows [batch, seq, wider] that
+    are read or written from the lanes ``starts`` on, None), or (None, why
+    they cannot)."""
+    if lanes % 128 or any(start % 128 for start in starts):
+        return None, "lanes_not_blocks_of_128"
+    if seq % HALO:
+        return None, "seq_not_rows_of_8"
+    rows = max(r for r in range(HALO, min(seq, ROW_BLOCK) + 1, HALO)
+               if seq % r == 0)
+    lane_block = max(n for n in range(128, LANE_BLOCK + 1, 128)
+                     if not any(x % n for x in (lanes, *starts)))
+    sub = max(n for n in (8, 16, 32, strip) if rows % n == 0)
+    return _Walk(rows, lane_block, sub), None
+
+
+def _pass_event(site, walk, why, batch, seq, lanes):
+    from ..profiler import trace
+    trace.emit("mixer_pass", site=site, path="xla" if why else "vmem",
+               rows=batch * seq, lanes=lanes,
+               row_block=walk.rows if walk else 0,
+               **({"why": why} if why else {}))
+
+
+def _over_strips(walk, strip):
+    """``strip(lanes)`` for each 128-lane block of the grid step's block,
+    as a loop: the strip's work is traced once a kernel, not once a block
+    (a step's trace is part of every run's set-up)."""
+    def body(_, k):
+        strip(pl.ds(pl.multiple_of(k * 128, 128), 128))
+        return k + np.int32(1)
+
+    jax.lax.fori_loop(0, walk.lanes // 128, body, _0)
+
+
+def _strip_rows(walk, j):
+    return pl.ds(pl.multiple_of(j * walk.sub, walk.sub), walk.sub)
+
+
+def _over_strip_rows(walk, step, carry, start=0):
+    """``carry = step(j, carry)`` for the strips ``start``.. of a block, j an
+    int32 of the loop's own (under x64 fori_loop's index is an i64 in the
+    jaxpr and an i32 in Mosaic)."""
+    def body(_, counted):
+        j, carry = counted
+        return j + np.int32(1), step(j, carry)
+
+    return jax.lax.fori_loop(start, walk.rows // walk.sub, body,
+                             (np.int32(start), carry))[1]
+
+
+def _sigmoid(x):
+    """1 / (1 + exp(-x)) as one pass through the transcendental unit and two
+    vector operations (the quotient written out costs ten)."""
+    return 0.5 * jnp.tanh(0.5 * x) + 0.5
+
+
+def _silu_slope(x, sig):
+    """d silu(x) / dx from x and sigmoid(x)."""
+    return sig * (1.0 + x * (1.0 - sig))
+
+
+def _fold(x):
+    """[n, 128] -> [8, 128]: the sum of its 8-row tiles (a sum over rows
+    that is finished outside the kernel)."""
+    total = x[:8]
+    for i in range(8, x.shape[0], 8):
+        total = total + x[i:i + 8]
+    return total
+
+
+def _behind(before, x, by):
+    """Row t of ``x`` [n, 128] replaced by row t - by, the first rows by the
+    last of ``before`` [8, 128]; float32 (odd shifts of packed rows are
+    awkward)."""
+    if by == 0:
+        return x
+    return pltpu.roll(jnp.concatenate([before, x], axis=0), np.int32(by),
+                      axis=0)[HALO:]
+
+
+def _ahead(x, after, by):
+    """Row t of ``x`` replaced by row t + by, the last by the first of
+    ``after`` [8, 128]."""
+    if by == 0:
+        return x
+    n = x.shape[0]
+    return pltpu.roll(jnp.concatenate([x, after], axis=0),
+                      np.int32(n + HALO - by), axis=0)[:n]
+
+
+def _conv_sums(before, x, w):
+    """(the causal conv's sums for rows ``x`` that follow ``before``, the
+    operand of each tap); ``w`` the taps as [1, 128] rows."""
+    k = len(w)
+    operands = [_behind(before, x, k - 1 - i) for i in range(k)]
+    total = operands[0] * w[0]
+    for operand, wi in zip(operands[1:], w[1:]):
+        total = total + operand * wi
+    return total, operands
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, walk):
+    start = pl.program_id(2) == 0  # of a sequence: zeros before it
+
+    def strip(lanes):
+        w = [w_ref[i:i + 1, lanes] for i in range(w_ref.shape[0])]
+
+        def step(j, before):
+            rows = _strip_rows(walk, j)
+            x = x_ref[0, rows, lanes].astype(F32)
+            c = _conv_sums(before, x, w)[0]
+            y_ref[0, rows, lanes] = (c * _sigmoid(c)).astype(y_ref.dtype)
+            return x[-HALO:]
+
+        _over_strip_rows(walk, step, jnp.where(
+            start, 0.0, before_ref[0, :, lanes].astype(F32)))
+
+    _over_strips(walk, strip)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     w_ref, dx_ref, dw_ref, *, walk):
+    """dx of a strip's rows needs dc three rows ahead: the walk writes the
+    rows of strip j - 1 when it has made dc of strip j, and those of the
+    last from the 8 rows after the block."""
+    batch, block, last = (pl.program_id(1), pl.program_id(2),
+                          pl.num_programs(2) - 1)
+    k, steps = w_ref.shape[0], walk.rows // walk.sub
+
+    @pl.when((batch == 0) & (block == 0))
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    def strip(lanes):
+        w = [w_ref[i:i + 1, lanes] for i in range(k)]
+
+        def dconv(before, x, dy):
+            c, operands = _conv_sums(before, x, w)
+            return dy * _silu_slope(c, _sigmoid(c)), operands
+
+        def read(ref, j):
+            return ref[0, _strip_rows(walk, j), lanes].astype(F32)
+
+        def write_dx(j, dc, dc_after):
+            dx = _ahead(dc, dc_after, k - 1) * w[0]
+            for i in range(1, k):
+                dx = dx + _ahead(dc, dc_after, k - 1 - i) * w[i]
+            dx_ref[0, _strip_rows(walk, j), lanes] = dx.astype(dx_ref.dtype)
+
+        def first(j, before):
+            x = read(x_ref, j)
+            dc, operands = dconv(before, x, read(dy_ref, j))
+            return x[-HALO:], dc, [_fold(dc * o) for o in operands]
+
+        def step(j, carry):
+            before, dc_behind, sums = carry
+            before, dc, more = first(j, before)
+            write_dx(j - 1, dc_behind, dc[:HALO])
+            return before, dc, [s + m for s, m in zip(sums, more)]
+
+        before, dc, sums = _over_strip_rows(walk, step, first(_0, jnp.where(
+            block == 0, 0.0, before_ref[0, :, lanes].astype(F32))), start=1)
+        dc_after = jnp.where(
+            block == last, 0.0,
+            dconv(before, after_ref[0, :, lanes].astype(F32),
+                  dy_after_ref[0, :, lanes].astype(F32))[0])
+        write_dx(np.int32(steps - 1), dc, dc_after)
+        for i, total in enumerate(sums):
+            dw_ref[i * 8:(i + 1) * 8, lanes] += total
+
+    _over_strips(walk, strip)
+
+
+def _norm_parts(o_ref, z_ref, rows, lanes, eps):
+    """(o, its rows' 1 / rms, z, sigmoid(z)) of a strip of one head, float32."""
+    of = o_ref[0, rows, lanes].astype(F32)
+    zf = z_ref[0, rows, lanes].astype(F32)
+    r = jax.lax.rsqrt(jnp.mean(of * of, axis=1, keepdims=True) + eps)
+    return of, r, zf, _sigmoid(zf)
+
+
+def _norm_fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, walk, eps):
+    w = w_ref[...]
+
+    def strip(lanes):
+        def step(j, carry):
+            rows = _strip_rows(walk, j)
+            of, r, zf, sig = _norm_parts(o_ref, z_ref, rows, lanes, eps)
+            y_ref[0, rows, lanes] = (of * r * w * (zf * sig)).astype(
+                y_ref.dtype)
+            return carry
+
+        _over_strip_rows(walk, step, _0)
+
+    _over_strips(walk, strip)
+
+
+def _norm_bwd_kernel(o_ref, z_ref, dy_ref, w_ref, do_ref, dz_ref, dw_ref, *,
+                     walk, eps):
+    @pl.when((pl.program_id(1) == 0) & (pl.program_id(2) == 0))
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
+
+    w = w_ref[...]
+
+    def strip(lanes):
+        def step(j, total):
+            rows = _strip_rows(walk, j)
+            of, r, zf, sig = _norm_parts(o_ref, z_ref, rows, lanes, eps)
+            dy = dy_ref[0, rows, lanes].astype(F32)
+            unit = of * r
+            dn = dy * (zf * sig)  # of the normalised rows times the gain
+            dunit = dn * w
+            # r is a row's own factor, so the second mean is taken of the
+            # rows as they came and does not wait for the first
+            along = r * jnp.mean(dunit * of, axis=1, keepdims=True)
+            do_ref[0, rows, lanes] = (r * (dunit - unit * along)).astype(
+                do_ref.dtype)
+            dz_ref[0, rows, lanes] = (
+                dy * (unit * w) * _silu_slope(zf, sig)).astype(dz_ref.dtype)
+            return total + _fold(dn * unit)
+
+        dw_ref[:, lanes] += _over_strip_rows(
+            walk, step, jnp.zeros((8, 128), F32))
+
+    _over_strips(walk, strip)
+
+
+def _row_call(kernel, name, walk, shape, ins, outs, sums=False):
+    """``kernel`` over the grid (lane blocks, batch, row blocks) of ``shape``
+    [batch, seq, lanes]. ``ins`` are (kind, array, its first lane), ``outs``
+    (kind, shape, dtype); a kind is the block a grid step gets: "rows" its
+    (rows, lanes) block, "before" and "after" the 8 rows on either side of
+    it (the sequence's own first or last 8 at its ends, where the kernels
+    put zeros), "lane" all rows of its lanes out of a small [n, lanes]
+    array, "all" a small array whole. With ``sums`` the "lane" outputs stay
+    resident over batch and rows and gather sums."""
+    batch, seq, lanes = shape
+    per, n_halos = np.int32(walk.rows // HALO), np.int32(seq // HALO)
+
+    def spec(kind, of, offset=0):
+        off = np.int32(offset // walk.lanes)
+        if kind == "rows":
+            return pl.BlockSpec((1, walk.rows, walk.lanes),
+                                lambda c, b, r: (b, r, c + off))
+        if kind == "before":
+            return pl.BlockSpec(
+                (1, HALO, walk.lanes),
+                lambda c, b, r: (b, jnp.maximum(r * per - 1, 0), c + off))
+        if kind == "after":
+            return pl.BlockSpec(
+                (1, HALO, walk.lanes),
+                lambda c, b, r: (b, jnp.minimum((r + 1) * per, n_halos - 1),
+                                 c + off))
+        if kind == "lane":
+            return pl.BlockSpec((of[0], walk.lanes),
+                                lambda c, b, r: (_0, c + off))
+        return pl.BlockSpec(of, lambda c, b, r: (_0, _0))
+
+    return pl.pallas_call(
+        kernel, name=name,
+        grid=(lanes // walk.lanes, batch, seq // walk.rows),
+        in_specs=[spec(kind, x.shape, offset) for kind, x, offset in ins],
+        out_specs=[spec(kind, of) for kind, of, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct(of, dtype) for _, of, dtype in outs],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            ("arbitrary" if sums else "parallel",) * 3)),
+        interpret=_interpret(),
+    )(*(x for _, x, _ in ins))
+
+
+# jitted, as the rule's calls are: the model traces a mixer once a layer
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _conv_forward(x, taps, walk, start, width):
+    """The conv of x's lanes ``start``.. by the same lanes of ``taps``
+    [kernel, channels] float32, ``width`` of them."""
+    shape = (x.shape[0], x.shape[1], width)
+    return _row_call(
+        functools.partial(_conv_fwd_kernel, walk=walk), "short_conv_silu_fwd",
+        walk, shape,
+        [("rows", x, start), ("before", x, start), ("lane", taps, start)],
+        [("rows", shape, x.dtype)])[0]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _conv_backward(x, taps, dy, walk, start):
+    """(dx, d taps [kernel, width] float32) of the lanes ``dy`` is the
+    cotangent of."""
+    k, shape = taps.shape[0], dy.shape
+    dx, dw = _row_call(
+        functools.partial(_conv_bwd_kernel, walk=walk), "short_conv_silu_bwd",
+        walk, shape,
+        [("rows", x, start), ("before", x, start), ("after", x, start),
+         ("rows", dy, 0), ("after", dy, 0), ("lane", taps, start)],
+        [("rows", shape, x.dtype), ("lane", (8 * k, shape[2]), F32)],
+        sums=True)
+    # the kernel leaves each tap's sum as 8 partial rows
+    return dx, dw.reshape(k, 8, shape[2]).sum(1)
+
+
+def _pieces(widths):
+    """(first lane, width) of each."""
+    return list(zip(np.cumsum((0,) + widths[:-1]).tolist(), widths))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv_vmem(x, weight, walk, widths):
+    taps = weight.astype(F32).T
+    return tuple(_conv_forward(x, taps, walk, start, width)
+                 for start, width in _pieces(widths))
+
+
+def _conv_vmem_fwd(x, weight, walk, widths):
+    return _conv_vmem(x, weight, walk, widths), (x, weight)
+
+
+def _conv_vmem_bwd(walk, widths, res, dys):
+    x, weight = res
+    taps = weight.astype(F32).T
+    dxs, dws = zip(*(_conv_backward(x, taps, dy, walk, start)
+                     for (start, _), dy in zip(_pieces(widths), dys)))
+    # x's further lanes (what the caller keeps beside the conv's channels)
+    # have no part in it
+    dx = jnp.pad(jnp.concatenate(dxs, axis=-1),
+                 ((0, 0), (0, 0), (0, x.shape[2] - weight.shape[0])))
+    return dx, jnp.concatenate(dws, axis=-1).T.astype(weight.dtype)
+
+
+_conv_vmem.defvjp(_conv_vmem_fwd, _conv_vmem_bwd)
+
+
 def _causal_taps(x, weight, ahead=False):
     """sum_i weight[:, i] * x shifted: behind by K-1-i rows (the causal conv),
     or ``ahead`` by as many (its transpose), zeros past the ends; one fused
@@ -82,25 +461,20 @@ def _causal_taps(x, weight, ahead=False):
 
 
 @jax.custom_vjp
-def short_conv_silu(x, weight):
-    """silu(causal depthwise conv) over [batch, seq, channels] with
-    ``weight`` [channels, kernel], no bias: y_t = silu(sum_i w[:, i]
-    x_(t-K+1+i)), zeros before the sequence's start. Sums in float32. The
-    backward is written out (the conv is made again, its transpose is the
-    same taps read ahead): derived, it is a pad and a reduction per tap."""
+def _conv_xla(x, weight):
     return jax.nn.silu(_causal_taps(x, weight)).astype(x.dtype)
 
 
-def _short_conv_fwd(x, weight):
-    return short_conv_silu(x, weight), (x, weight)
+def _conv_xla_fwd(x, weight):
+    return _conv_xla(x, weight), (x, weight)
 
 
-def _short_conv_bwd(res, dy):
+def _conv_xla_bwd(res, dy):
     x, weight = res
     k, seq = weight.shape[-1], x.shape[1]
     c = _causal_taps(x, weight)
     sig = jax.nn.sigmoid(c)
-    dc = (dy.astype(F32) * sig * (1.0 + c * (1.0 - sig))).astype(x.dtype)
+    dc = (dy.astype(F32) * _silu_slope(c, sig)).astype(x.dtype)
     dx = _causal_taps(dc, weight, ahead=True).astype(x.dtype)
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     dw = jnp.stack([jnp.einsum("bsc,bsc->c", dc, xp[:, i:i + seq],
@@ -109,7 +483,41 @@ def _short_conv_bwd(res, dy):
     return dx, dw.astype(weight.dtype)
 
 
-short_conv_silu.defvjp(_short_conv_fwd, _short_conv_bwd)
+_conv_xla.defvjp(_conv_xla_fwd, _conv_xla_bwd)
+
+
+def short_conv_silu(x, weight, splits=None):
+    """silu(causal depthwise conv) over the first ``channels`` lanes of x
+    [batch, seq, channels or more] with ``weight`` [channels, kernel], no
+    bias: y_t = silu(sum_i w[:, i] x_(t-K+1+i)), zeros before the sequence's
+    start; y [batch, seq, channels] in x's type, sums in float32. With
+    ``splits`` (widths that add up to ``channels``) y comes as that many
+    arrays [batch, seq, width], side by side.
+
+    Where the channels (and the splits) are whole blocks of 128 lanes and
+    the sequence whole rows of 8, two kernels do it, ``short_conv_silu_fwd``
+    and ``_bwd`` (which makes the conv again, reads the same taps ahead for
+    dx and sums dw in float32): x is read where the projection left it, each
+    piece of y written where its reader takes it and each piece's cotangent
+    read where it was left, so XLA slices nothing out and puts nothing
+    together. Other shapes take the same arithmetic as ``jax.numpy`` (a
+    padded copy, and a written-out backward: derived, it is a pad and a
+    reduction per tap). Each trace leaves one ``mixer_pass`` event."""
+    batch, seq, channels = x.shape[0], x.shape[1], weight.shape[0]
+    widths = tuple(splits or (channels,))
+    pieces = _pieces(widths)
+    if weight.shape[1] > HALO + 1:
+        walk, why = None, "taps_over_8_rows"
+    else:
+        walk, why = _walk(seq, CONV_STRIP, channels,
+                          *(start for start, _ in pieces))
+    _pass_event("short_conv_silu", walk, why, batch, seq, channels)
+    if walk is None:
+        y = _conv_xla(x[..., :channels], weight)
+        ys = tuple(y[..., start:start + width] for start, width in pieces)
+    else:
+        ys = _conv_vmem(x, weight, walk, widths)
+    return ys if splits else ys[0]
 
 
 def l2_normalize(x, *, epsilon=L2_EPSILON):
@@ -128,15 +536,77 @@ def decay_and_beta(a, b, a_log, dt_bias):
     return g, jax.nn.sigmoid(b.astype(F32))
 
 
-def gated_rms_norm(o, z, weight, *, epsilon=1e-6):
-    """o / rms(o) * weight * silu(z) over the last axis (one head), in
-    float32; the gain is not zero-centred."""
-    of = o.astype(F32)
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _norm_forward(o, z, weight, walk, eps):
+    return _row_call(
+        functools.partial(_norm_fwd_kernel, walk=walk, eps=eps),
+        "gated_rms_norm_fwd", walk, o.shape,
+        [("rows", o, 0), ("rows", z, z.shape[2] - o.shape[2]),
+         ("all", weight.astype(F32)[None], 0)],
+        [("rows", o.shape, o.dtype)])[0]
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _norm_backward(o, z, weight, dy, walk, eps):
+    lanes, gate = o.shape[2], z.shape[2] - o.shape[2]
+    do, dz, dw = _row_call(
+        functools.partial(_norm_bwd_kernel, walk=walk, eps=eps),
+        "gated_rms_norm_bwd", walk, o.shape,
+        [("rows", o, 0), ("rows", z, gate), ("rows", dy, 0),
+         ("all", weight.astype(F32)[None], 0)],
+        [("rows", o.shape, o.dtype), ("rows", o.shape, z.dtype),
+         ("lane", (8, lanes), F32)], sums=True)
+    # the gain's sum is left as 8 partial rows of every head; the lanes of z
+    # before the gate (what the caller keeps there) have no part in it
+    return (do, jnp.pad(dz, ((0, 0), (0, 0), (gate, 0))),
+            dw.reshape(-1, weight.shape[0]).sum(0).astype(weight.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _norm_vmem(o, z, weight, walk, eps):
+    return _norm_forward(o, z, weight, walk, eps)
+
+
+def _norm_vmem_fwd(o, z, weight, walk, eps):
+    return _norm_vmem(o, z, weight, walk, eps), (o, z, weight)
+
+
+def _norm_vmem_bwd(walk, eps, res, dy):
+    return _norm_backward(*res, dy, walk, eps)
+
+
+_norm_vmem.defvjp(_norm_vmem_fwd, _norm_vmem_bwd)
+
+
+def _norm_xla(o, z, weight, epsilon):
+    of = o.astype(F32).reshape(*o.shape[:-1], -1, weight.shape[0])
     var = jnp.mean(jnp.square(of), axis=-1, keepdims=True)
     y = of * jax.lax.rsqrt(var + epsilon) * weight.astype(F32)
-    return (y * jax.nn.silu(z.astype(F32))).astype(o.dtype)
+    return (y * jax.nn.silu(z.astype(F32).reshape(of.shape))).astype(
+        o.dtype).reshape(o.shape)
 
 
+def gated_rms_norm(o, z, weight, *, epsilon=1e-6):
+    """o / rms(o) * weight * silu(z), head by head: o [batch, seq, heads *
+    d] as the rule leaves it, a head being ``d = len(weight)`` lanes; z the
+    LAST heads * d lanes of [batch, seq, heads * d or more]. Statistics and
+    the gate in float32; the gain is not zero-centred.
+
+    Where d is one block of 128 lanes and the sequence whole rows of 8,
+    two kernels do it (``gated_rms_norm_fwd``, ``_bwd``: do, dz and the
+    gain's gradient in one pass), a head's mean of squares staying in VMEM.
+    Other shapes take the ``jax.numpy`` expression over [.., heads, d].
+    Each trace leaves one ``mixer_pass`` event."""
+    batch, seq, lanes = o.shape
+    d, gate = weight.shape[0], z.shape[2] - lanes
+    if d != 128:
+        walk, why = None, "head_not_128_lanes"
+    else:
+        walk, why = _walk(seq, NORM_STRIP, lanes, gate)
+    _pass_event("gated_rms_norm", walk, why, batch, seq, lanes)
+    if walk is None:
+        return _norm_xla(o, z[..., gate:], weight, epsilon)
+    return _norm_vmem(o, z, weight, walk, float(epsilon))
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,10 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
   gdn_chunks       the gated delta rule's kernels were built for a shape:
                    chunk, chunks a grid step, value heads a key head, where
                    the chunks are prepared (trace time, once per trace)
+  mixer_pass       the short conv or the gated norm round the rule was
+                   traced for a shape: its Pallas kernels ("vmem") or the
+                   jax.numpy expression ("xla", with why), rows, lanes and
+                   the rows of a grid step (trace time, once per trace)
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that

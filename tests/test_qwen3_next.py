@@ -240,25 +240,171 @@ def test_each_trace_of_the_rule_leaves_one_gdn_chunks_event():
     assert len(trace.events(kind="gdn_chunks")) == before + 1
 
 
+def plain_conv(x, w):
+    """The reference's short conv over x's first channels, in float32."""
+    x, w = x[..., :w.shape[0]].astype(jnp.float32), w.astype(jnp.float32)
+    taps = w.shape[-1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return jax.nn.silu(sum(padded[:, i:i + x.shape[1]] * w[:, i]
+                           for i in range(taps)))
+
+
 def test_short_conv_and_its_written_out_backward():
     rng = np.random.default_rng(13)
     x = jnp.asarray(rng.standard_normal((2, 37, 24)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((24, 4)), jnp.float32)
     ct = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
 
-    def plain(x, w):  # as the reference writes it
-        taps = w.shape[-1]
-        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-        return jax.nn.silu(sum(padded[:, i:i + x.shape[1]] * w[:, i]
-                               for i in range(taps)))
-
-    np.testing.assert_allclose(la.short_conv_silu(x, w), plain(x, w),
+    np.testing.assert_allclose(la.short_conv_silu(x, w), plain_conv(x, w),
                                atol=1e-6)
     got = jax.grad(lambda *a: (la.short_conv_silu(*a) * ct).sum(),
                    (0, 1))(x, w)
-    want = jax.grad(lambda *a: (plain(*a) * ct).sum(), (0, 1))(x, w)
+    want = jax.grad(lambda *a: (plain_conv(*a) * ct).sum(), (0, 1))(x, w)
     np.testing.assert_allclose(got[0], want[0], atol=1e-5)
     np.testing.assert_allclose(got[1], want[1], atol=1e-4)
+
+
+def plain_norm(o, z, gain, eps=1e-6):
+    """The reference's gated norm, z the last columns, in float32."""
+    heads = o.astype(jnp.float32).reshape(*o.shape[:2], -1, gain.shape[0])
+    gate = z[..., z.shape[-1] - o.shape[-1]:].astype(jnp.float32)
+    unit = heads * jax.lax.rsqrt(
+        jnp.mean(jnp.square(heads), -1, keepdims=True) + eps)
+    return ((unit * gain.astype(jnp.float32)).reshape(o.shape)
+            * jax.nn.silu(gate))
+
+
+def mixer_pass_inputs(seq, dtype, seed=21):
+    """Lane-aligned and small: 256 channels out of a 512-wide projection,
+    2 heads of 128 gated by its last 256 columns."""
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, scale=1.0, shift=0.0):
+        return jnp.asarray(shift + scale * rng.standard_normal(shape), dtype)
+
+    return dict(x=draw(2, seq, 512), w=draw(256, 4, scale=0.5),
+                o=draw(2, seq, 256), gain=draw(128, scale=0.2, shift=1.0),
+                ct=draw(2, seq, 256))
+
+
+def assert_close(got, want, dtype, what):
+    """To the rounding of ONE result in ``dtype`` (the kernels sum in
+    float32 and round once), relative to the largest entry."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape, what
+    tol = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
+    assert np.abs(got - want).max() <= tol * np.abs(want).max(), what
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("seq,row_block",
+                         [(64, None), (72, None), (72, 24), (1536, None)],
+                         ids=["s64", "s72", "s72_blocks_of_24", "s1536"])
+@pytest.mark.parametrize("op", ["short_conv_silu", "short_conv_silu_in_two",
+                                "gated_rms_norm"])
+def test_mixer_pass_kernels_match_the_plain_expression(op, seq, row_block,
+                                                       dtype, monkeypatch):
+    """Forward and every gradient of the four kernels (interpret mode)
+    against the plain float32 expression on the same inputs; a sequence of
+    one row block and of three (1,536 rows; 72 rows in blocks of 24); the
+    conv's result as one array and as two."""
+    if row_block:
+        monkeypatch.setattr(la, "ROW_BLOCK", row_block)
+    a = mixer_pass_inputs(seq, dtype)
+    before = len(trace.events(kind="mixer_pass"))
+    if op == "short_conv_silu":
+        args, got_fn, want_fn = (a["x"], a["w"]), la.short_conv_silu, plain_conv
+    elif op == "short_conv_silu_in_two":  # each half written for itself
+        def got_fn(x, w):
+            halves = la.short_conv_silu(x, w, (128, 128))
+            assert [h.shape[-1] for h in halves] == [128, 128]
+            return jnp.concatenate(halves, axis=-1)
+
+        args, want_fn = (a["x"], a["w"]), plain_conv
+    else:
+        args, got_fn, want_fn = ((a["o"], a["x"], a["gain"]),
+                                 la.gated_rms_norm, plain_norm)
+    ct = a["ct"].astype(jnp.float32)
+
+    def both(fn, *args):
+        return jax.value_and_grad(
+            lambda *b: (fn(*b).astype(jnp.float32) * ct).sum(),
+            tuple(range(len(args))))(*args)
+
+    assert_close(got_fn(*args), want_fn(*args), dtype, "forward")
+    event = trace.events(kind="mixer_pass")[before]
+    assert op.startswith(event.site) and event.attrs["path"] == "vmem"
+    assert event.attrs["row_block"] == (row_block or min(seq, 512))
+    (_, got), (_, want) = both(got_fn, *args), both(
+        want_fn, *(x.astype(jnp.float32) for x in args))
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == args[i].dtype
+        assert_close(g, w, dtype, f"gradient {i}")
+    if op == "gated_rms_norm":  # z's columns before the gate take no part
+        assert not np.asarray(got[1][..., :256], np.float32).any()
+    else:
+        assert not np.asarray(got[0][..., 256:], np.float32).any()
+
+
+@pytest.mark.parametrize("row_block", [None, 8], ids=["one_block", "blocks_of_8"])
+def test_short_conv_kernels_do_not_read_across_the_batch(row_block,
+                                                         monkeypatch):
+    """The first three rows of the SECOND batch entry see zeros before them,
+    not the first entry's last rows, and the last three rows of the FIRST
+    entry's dx no dc of the second: each entry alone gives the same bits."""
+    if row_block:
+        monkeypatch.setattr(la, "ROW_BLOCK", row_block)
+    a = mixer_pass_inputs(64, jnp.bfloat16, seed=22)
+
+    def run(x, ct):
+        y, pull = jax.vjp(la.short_conv_silu, x, a["w"])
+        return y, pull(ct)[0]
+
+    y, dx = run(a["x"], a["ct"])
+    for i in range(2):
+        y_alone, dx_alone = run(a["x"][i:i + 1], a["ct"][i:i + 1])
+        np.testing.assert_array_equal(np.asarray(y[i], np.float32),
+                                      np.asarray(y_alone[0], np.float32))
+        np.testing.assert_array_equal(np.asarray(dx[i], np.float32),
+                                      np.asarray(dx_alone[0], np.float32))
+    # and the rows do depend on what is before them inside an entry
+    assert np.asarray(y[1, 3:6], np.float32).any()
+
+
+def test_published_widths_take_the_vmem_path_and_narrow_heads_say_why():
+    """Under a shape-only trace at the cell's sizes both passes leave a
+    ``mixer_pass`` event with ``path == "vmem"`` (the conv writing q, k and
+    v apart, as the model asks it to); heads of 16 (the rehearsal's) leave
+    ``"xla"`` with the reason."""
+    def events(seq, wide, channels, lanes, d, splits=None):
+        x, w, o, gain = (jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in (
+            (2, seq, wide), (channels, 4), (2, seq, lanes), (d,)))
+        before = len(trace.events(kind="mixer_pass"))
+        ys = jax.eval_shape(lambda x, w: la.short_conv_silu(x, w, splits),
+                            x, w)
+        assert [y.shape for y in jax.tree_util.tree_leaves(ys)] == [
+            (2, seq, width) for width in splits or (channels,)]
+        assert jax.eval_shape(la.gated_rms_norm, o, x, gain).shape == o.shape
+        return [(e.site, e.attrs)
+                for e in trace.events(kind="mixer_pass")[before:]]
+
+    assert events(8192, 12288, 8192, 4096, 128, (2048, 2048, 4096)) == [
+        ("short_conv_silu", dict(path="vmem", rows=16384, lanes=8192,
+                                 row_block=512)),
+        ("gated_rms_norm", dict(path="vmem", rows=16384, lanes=4096,
+                                row_block=512))]
+    assert events(128, 192, 128, 64, 16) == [
+        ("short_conv_silu", dict(path="vmem", rows=256, lanes=128,
+                                 row_block=128)),
+        ("gated_rms_norm", dict(path="xla", rows=256, lanes=64, row_block=0,
+                                why="head_not_128_lanes"))]
+    assert events(100, 160, 96, 64, 16)[0] == (
+        "short_conv_silu", dict(path="xla", rows=200, lanes=96, row_block=0,
+                                why="lanes_not_blocks_of_128"))
+    assert events(100, 384, 256, 128, 128)[1] == (
+        "gated_rms_norm", dict(path="xla", rows=200, lanes=128, row_block=0,
+                               why="seq_not_rows_of_8"))
 
 
 # ---------------------------------------------------------------------------

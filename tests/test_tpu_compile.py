@@ -259,7 +259,8 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, compiled_kernels,
     one period of four layers, 64 of 512 experts held, an eighth of the
     vocabulary, AMP O2 bf16, AdamW, batch 2 x 8,192, mixers recomputed. It
     fits the chip, and holds the flash kernels, the delta rule's kernels and
-    the grouped products: no dense attention, no capacity-bucketed dispatch.
+    the grouped products, the short conv and the gated norm as their four
+    kernels: no dense attention, no capacity-bucketed dispatch.
     (PERF.md section 4 has the memory it reads, and what it reads without
     the recomputation.)"""
     import paddle_tpu.nn.initializer as I
@@ -292,8 +293,15 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, compiled_kernels,
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq", "gated_delta_rule_fwd",
-                 "gated_delta_rule_bwd", "ragged-dot"):
+                 "gated_delta_rule_bwd", "ragged-dot",
+                 "short_conv_silu_fwd", "short_conv_silu_bwd",
+                 "gated_rms_norm_fwd", "gated_rms_norm_bwd"):
         assert name in text, name
+    # the conv reads no padded copy (3 rows more than the sequence) and the
+    # gated norm writes no float32 factor of o's size (PR 31)
+    assert "8195" not in text
+    assert not [line for line in text.split("\n") if "gated_norm" in line
+                and re.search(r"= f32\[2,8192,(4096|32,128)\]", line)]
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
