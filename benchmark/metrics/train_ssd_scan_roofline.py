@@ -1,0 +1,32 @@
+"""Least time the chip could take for the state-space scan of the Mamba-2
+layers (``lib/counts_granite_hybrid.py``: the recurrence's operations, x, B,
+C, dt and y read and written once; the forward twice where the mixer is made
+again in the backward, the backward once), over ALL device seconds under the
+``ssd_scan`` scope of the traced steps (``window.scope_seconds``, which the
+driver reads from the trace with the program's own ``profiler/statistic.py``):
+the kernels that carry the state AND whatever XLA does round them (dt's
+softplus, the decay, the gates' transposes, the skip). Numerator and
+denominator cover the same work whatever implements it. ``None`` without
+such a scope."""
+from ..lib import counts_granite_hybrid as counts
+from ..lib import peaks
+
+SCOPE = "/ssd_scan"
+
+
+def read(record):
+    steps = record["window"].get("traced_steps")
+    scopes = record["window"].get("scope_seconds")
+    sizes = record["sizes"]
+    if not scopes or not steps or "mamba_n_heads" not in sizes:
+        return None
+    spent = sum(s for path, s in scopes.items() if path.endswith(SCOPE))
+    if spent <= 0:
+        return None
+    mix = record["traffic"]
+    mamba, _ = counts.layer_kinds(sizes)
+    least = counts.scan_roofline(
+        sizes, mix["batch"], mix["seq"],
+        peaks.peaks_for(record["device"]["kind"]),
+        forwards=2 if sizes.get("recompute_mixer") else 1)
+    return 100.0 * least * mamba * steps / spent

@@ -343,6 +343,10 @@ def _reset_counters_locked():
         # path with FLAGS_use_flash_attention on, and why (nn/functional)
         flash_attention_fallbacks=0,
         flash_attention_fallback_reasons={},
+        # traces of ops.state_space.ssd_scan that took the jax.numpy form
+        # because the kernels refuse the shape, and why
+        ssd_scan_fallbacks=0,
+        ssd_scan_fallback_reasons={},
         fault_sites={},
         perf_regression_sites={},
         telemetry_spike_groups={},
@@ -368,6 +372,17 @@ def _count_flash_fallback(reason: str, q_shape, k_shape):
     fam[reason] = fam.get(reason, 0) + 1
     _emit("flash_fallback", site="scaled_dot_product_attention",
           reason=reason, q_shape=q_shape, k_shape=k_shape)
+
+
+def _count_ssd_chunks(why, **shape):
+    """One ``ssd_chunks`` event a trace of the state-space scan; a shape the
+    kernels refuse (``why``) is counted beside it."""
+    if why is not None:
+        _counters["ssd_scan_fallbacks"] += 1
+        fam = _counters["ssd_scan_fallback_reasons"]
+        fam[why] = fam.get(why, 0) + 1
+    _emit("ssd_chunks", site="ssd_scan", path="xla" if why else "vmem",
+          **shape, **({"why": why} if why else {}))
 
 
 def dispatch_counters() -> Dict[str, Any]:

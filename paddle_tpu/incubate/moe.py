@@ -464,7 +464,8 @@ def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
                      rows):
     """The whole expert layer on [T, h] tokens: (y, routed_slots,
     expert_rows). Routes over all of the router's experts; adds, for each
-    token, its held experts' weighted outputs and the gated shared expert.
+    token, its held experts' weighted outputs and the shared expert, gated
+    by sigmoid(x ``shared_gate``) or, with ``shared_gate`` None, as it is.
     What the absent experts would have added is left out."""
     from ..profiler import trace
 
@@ -485,9 +486,11 @@ def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
     with jax.named_scope("shared_expert"):
         gate, up = jnp.split(jnp.matmul(x, shared_gate_up), 2, axis=-1)
         shared = jnp.matmul(jax.nn.silu(gate) * up, shared_down)
-        open_ = jax.nn.sigmoid(jnp.matmul(
-            x, shared_gate, preferred_element_type=jnp.float32))
-        y = y + (shared * open_).astype(x.dtype)
+        if shared_gate is not None:
+            open_ = jax.nn.sigmoid(jnp.matmul(
+                x, shared_gate, preferred_element_type=jnp.float32))
+            shared = (shared * open_).astype(x.dtype)
+        y = y + shared
     return y, routed, _n_passes(offsets, rows) * np.int32(rows)
 
 
@@ -498,7 +501,8 @@ class DroplessExperts(Layer):
     Parameters: ``router`` [h, num_experts] (the published width, whatever
     is held), ``w_gate_up`` [count, h, 2 d] and ``w_down`` [count, d, h]
     (stacked leaves, not 3 x count arrays), ``shared_gate_up`` [h, 2 d_s],
-    ``shared_down`` [d_s, h], ``shared_gate`` [h, 1]. Two int32 buffers ride
+    ``shared_down`` [d_s, h], ``shared_gate`` [h, 1] (none with
+    ``shared_gate=False``: the shared expert is added ungated). Two int32 buffers ride
     a compiled step like a running statistic and hold, after each forward,
     ``routed_slots`` (slots that fell on held experts) and ``expert_rows``
     (rows of the row buffer the grouped products were given: passes x the
@@ -506,7 +510,8 @@ class DroplessExperts(Layer):
     per trace."""
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
-                 d_shared=None, renormalize=True, weight_attr=None):
+                 d_shared=None, renormalize=True, weight_attr=None,
+                 shared_gate=True):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
@@ -524,7 +529,7 @@ class DroplessExperts(Layer):
         self.w_down = make(self.count, d_expert, d_model)
         self.shared_gate_up = make(d_model, 2 * d_shared)
         self.shared_down = make(d_shared, d_model)
-        self.shared_gate = make(d_model, 1)
+        self.shared_gate = make(d_model, 1) if shared_gate else None
         self.register_buffer("routed_slots", Tensor(np.int32(0)))
         self.register_buffer("expert_rows", Tensor(np.int32(0)))
 

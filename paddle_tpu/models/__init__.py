@@ -21,3 +21,8 @@ from .qwen3_next import (  # noqa: F401
     Qwen3NextForCausalLM,
     Qwen3NextModel,
 )
+from .granite_hybrid import (  # noqa: F401
+    GraniteHybridConfig,
+    GraniteHybridForCausalLM,
+    GraniteHybridModel,
+)

@@ -43,6 +43,8 @@ from ..incubate.moe import DroplessExperts
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops import nn_ops as _nn
+from ._hybrid import linear as _linear
+from ._hybrid import residual_mixer, routed_load
 
 
 @dataclass
@@ -76,11 +78,6 @@ class Qwen3NextConfig:
 
     def is_full_attention(self, layer: int) -> bool:
         return (layer + 1) % self.full_attention_interval == 0
-
-
-def _linear(cfg, n_in, n_out):
-    return nn.Linear(n_in, n_out, bias_attr=False,
-                     weight_attr=I.Normal(0.0, cfg.initializer_range))
 
 
 def _norm(cfg, width=None):
@@ -227,19 +224,8 @@ class Qwen3NextDecoderLayer(nn.Layer):
             weight_attr=I.Normal(0.0, cfg.initializer_range))
 
     def forward(self, x):
-        def mix(x):
-            return x + self.mixer(self.norm1(x))
-
-        if self.cfg.use_recompute:
-            # the mixer's activations are dropped and made again in the
-            # backward; the experts stay outside, so that their counters are
-            # written once, by the forward
-            from ..incubate.recompute import _ChunkParams, recompute
-
-            mix.__self__ = _ChunkParams([self.norm1, self.mixer])
-            x = recompute(mix, x)
-        else:
-            x = mix(x)
+        x = residual_mixer(x, self.norm1, self.mixer,
+                           recompute=self.cfg.use_recompute)
         return x + self.experts(self.norm2(x))
 
 
@@ -281,6 +267,4 @@ class Qwen3NextForCausalLM(nn.Layer):
 
     def routed_load(self):
         """[(layer, routed_slots, expert_rows)] of the last forward."""
-        return [(i, int(l.experts.routed_slots._value),
-                 int(l.experts.expert_rows._value))
-                for i, l in enumerate(self.model.layers)]
+        return routed_load(self.model.layers)

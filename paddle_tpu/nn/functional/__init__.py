@@ -690,8 +690,12 @@ def rotary_embedding(x, rotary_dim=None, theta=10000.0, name=None):
 
 def scaled_dot_product_attention(
     query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
-    training=True, name=None,
+    training=True, name=None, scale=None,
 ):
+    # ``scale`` multiplies q k^T in place of head_dim ** -0.5 (a model with
+    # an attention multiplier of its own); without it the ops are called as
+    # they always were
+    scaled = {} if scale is None else {"scale": float(scale)}
     dropout_key = (
         _random.next_key() if (dropout_p > 0.0 and training) else None
     )
@@ -720,7 +724,7 @@ def scaled_dot_product_attention(
         if refusal is None:
             return apply(
                 _nn.flash_scaled_dot_product_attention, query, key, value,
-                is_causal=is_causal, op_name="flash_sdpa",
+                is_causal=is_causal, op_name="flash_sdpa", **scaled,
             )
         from ...core import dispatch as _dispatch
 
@@ -729,6 +733,7 @@ def scaled_dot_product_attention(
     return apply(
         _nn.scaled_dot_product_attention, query, key, value, attn_mask,
         dropout_key, is_causal=is_causal, dropout_p=dropout_p, op_name="sdpa",
+        **scaled,
     )
 
 

@@ -208,18 +208,21 @@ def _ahead(x, after, by):
                       np.int32(n + HALO - by), axis=0)[:n]
 
 
-def _conv_sums(before, x, w):
+def _conv_sums(before, x, w, biased=False):
     """(the causal conv's sums for rows ``x`` that follow ``before``, the
-    operand of each tap); ``w`` the taps as [1, 128] rows."""
-    k = len(w)
+    operand of each tap); ``w`` the taps as [1, 128] rows and, ``biased``,
+    the bias as one more."""
+    k = len(w) - biased
     operands = [_behind(before, x, k - 1 - i) for i in range(k)]
     total = operands[0] * w[0]
-    for operand, wi in zip(operands[1:], w[1:]):
+    for operand, wi in zip(operands[1:], w[1:k]):
         total = total + operand * wi
+    if biased:
+        total = total + w[k]
     return total, operands
 
 
-def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, walk):
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, walk, biased=False):
     start = pl.program_id(2) == 0  # of a sequence: zeros before it
 
     def strip(lanes):
@@ -228,7 +231,7 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, walk):
         def step(j, before):
             rows = _strip_rows(walk, j)
             x = x_ref[0, rows, lanes].astype(F32)
-            c = _conv_sums(before, x, w)[0]
+            c = _conv_sums(before, x, w, biased)[0]
             y_ref[0, rows, lanes] = (c * _sigmoid(c)).astype(y_ref.dtype)
             return x[-HALO:]
 
@@ -239,23 +242,24 @@ def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, walk):
 
 
 def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
-                     w_ref, dx_ref, dw_ref, *, walk):
+                     w_ref, dx_ref, dw_ref, *, walk, biased=False):
     """dx of a strip's rows needs dc three rows ahead: the walk writes the
     rows of strip j - 1 when it has made dc of strip j, and those of the
-    last from the 8 rows after the block."""
+    last from the 8 rows after the block. ``biased``: the last row of
+    ``w_ref`` is the bias, and the last sums of ``dw_ref`` its gradient's."""
     batch, block, last = (pl.program_id(1), pl.program_id(2),
                           pl.num_programs(2) - 1)
-    k, steps = w_ref.shape[0], walk.rows // walk.sub
+    k, steps = w_ref.shape[0] - biased, walk.rows // walk.sub
 
     @pl.when((batch == 0) & (block == 0))
     def _():
         dw_ref[...] = jnp.zeros(dw_ref.shape, F32)
 
     def strip(lanes):
-        w = [w_ref[i:i + 1, lanes] for i in range(k)]
+        w = [w_ref[i:i + 1, lanes] for i in range(w_ref.shape[0])]
 
         def dconv(before, x, dy):
-            c, operands = _conv_sums(before, x, w)
+            c, operands = _conv_sums(before, x, w, biased)
             return dy * _silu_slope(c, _sigmoid(c)), operands
 
         def read(ref, j):
@@ -270,7 +274,8 @@ def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
         def first(j, before):
             x = read(x_ref, j)
             dc, operands = dconv(before, x, read(dy_ref, j))
-            return x[-HALO:], dc, [_fold(dc * o) for o in operands]
+            return x[-HALO:], dc, ([_fold(dc * o) for o in operands]
+                                   + [_fold(dc)] * biased)
 
         def step(j, carry):
             before, dc_behind, sums = carry
@@ -390,25 +395,28 @@ def _row_call(kernel, name, walk, shape, ins, outs, sums=False):
 
 
 # jitted, as the rule's calls are: the model traces a mixer once a layer
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def _conv_forward(x, taps, walk, start, width):
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _conv_forward(x, taps, walk, start, width, biased=False):
     """The conv of x's lanes ``start``.. by the same lanes of ``taps``
-    [kernel, channels] float32, ``width`` of them."""
+    [kernel, channels] float32 (``biased``: and the bias, one row more),
+    ``width`` of them."""
     shape = (x.shape[0], x.shape[1], width)
     return _row_call(
-        functools.partial(_conv_fwd_kernel, walk=walk), "short_conv_silu_fwd",
+        functools.partial(_conv_fwd_kernel, walk=walk, biased=biased),
+        "short_conv_silu_fwd",
         walk, shape,
         [("rows", x, start), ("before", x, start), ("lane", taps, start)],
         [("rows", shape, x.dtype)])[0]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _conv_backward(x, taps, dy, walk, start):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _conv_backward(x, taps, dy, walk, start, biased=False):
     """(dx, d taps [kernel, width] float32) of the lanes ``dy`` is the
-    cotangent of."""
+    cotangent of; ``biased``: the bias and its gradient are the last row."""
     k, shape = taps.shape[0], dy.shape
     dx, dw = _row_call(
-        functools.partial(_conv_bwd_kernel, walk=walk), "short_conv_silu_bwd",
+        functools.partial(_conv_bwd_kernel, walk=walk, biased=biased),
+        "short_conv_silu_bwd",
         walk, shape,
         [("rows", x, start), ("before", x, start), ("after", x, start),
          ("rows", dy, 0), ("after", dy, 0), ("lane", taps, start)],
@@ -423,27 +431,39 @@ def _pieces(widths):
     return list(zip(np.cumsum((0,) + widths[:-1]).tolist(), widths))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv_vmem(x, weight, walk, widths):
+def _taps(weight, bias):
+    """[kernel, channels] float32 rows of taps; the bias, if any, one more."""
     taps = weight.astype(F32).T
-    return tuple(_conv_forward(x, taps, walk, start, width)
+    if bias is None:
+        return taps
+    return jnp.concatenate([taps, bias.astype(F32)[None]], axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_vmem(x, weight, bias, walk, widths):
+    taps = _taps(weight, bias)
+    return tuple(_conv_forward(x, taps, walk, start, width, bias is not None)
                  for start, width in _pieces(widths))
 
 
-def _conv_vmem_fwd(x, weight, walk, widths):
-    return _conv_vmem(x, weight, walk, widths), (x, weight)
+def _conv_vmem_fwd(x, weight, bias, walk, widths):
+    return _conv_vmem(x, weight, bias, walk, widths), (x, weight, bias)
 
 
 def _conv_vmem_bwd(walk, widths, res, dys):
-    x, weight = res
-    taps = weight.astype(F32).T
-    dxs, dws = zip(*(_conv_backward(x, taps, dy, walk, start)
+    x, weight, bias = res
+    taps = _taps(weight, bias)
+    dxs, dws = zip(*(_conv_backward(x, taps, dy, walk, start,
+                                    bias is not None)
                      for (start, _), dy in zip(_pieces(widths), dys)))
     # x's further lanes (what the caller keeps beside the conv's channels)
     # have no part in it
     dx = jnp.pad(jnp.concatenate(dxs, axis=-1),
                  ((0, 0), (0, 0), (0, x.shape[2] - weight.shape[0])))
-    return dx, jnp.concatenate(dws, axis=-1).T.astype(weight.dtype)
+    dw = jnp.concatenate(dws, axis=-1)
+    if bias is None:
+        return dx, dw.T.astype(weight.dtype), None
+    return dx, dw[:-1].T.astype(weight.dtype), dw[-1].astype(bias.dtype)
 
 
 _conv_vmem.defvjp(_conv_vmem_fwd, _conv_vmem_bwd)
@@ -486,13 +506,14 @@ def _conv_xla_bwd(res, dy):
 _conv_xla.defvjp(_conv_xla_fwd, _conv_xla_bwd)
 
 
-def short_conv_silu(x, weight, splits=None):
+def short_conv_silu(x, weight, splits=None, bias=None):
     """silu(causal depthwise conv) over the first ``channels`` lanes of x
-    [batch, seq, channels or more] with ``weight`` [channels, kernel], no
-    bias: y_t = silu(sum_i w[:, i] x_(t-K+1+i)), zeros before the sequence's
-    start; y [batch, seq, channels] in x's type, sums in float32. With
-    ``splits`` (widths that add up to ``channels``) y comes as that many
-    arrays [batch, seq, width], side by side.
+    [batch, seq, channels or more] with ``weight`` [channels, kernel] and,
+    where given, ``bias`` [channels]: y_t = silu(sum_i w[:, i] x_(t-K+1+i) +
+    b), zeros before the sequence's start; y [batch, seq, channels] in x's
+    type, sums in float32. With ``splits`` (widths that add up to
+    ``channels``) y comes as that many arrays [batch, seq, width], side by
+    side.
 
     Where the channels (and the splits) are whole blocks of 128 lanes and
     the sequence whole rows of 8, two kernels do it, ``short_conv_silu_fwd``
@@ -513,10 +534,14 @@ def short_conv_silu(x, weight, splits=None):
                           *(start for start, _ in pieces))
     _pass_event("short_conv_silu", walk, why, batch, seq, channels)
     if walk is None:
-        y = _conv_xla(x[..., :channels], weight)
+        if bias is None:
+            y = _conv_xla(x[..., :channels], weight)
+        else:  # differentiated by JAX: a pad and a reduction per tap
+            y = jax.nn.silu(_causal_taps(x[..., :channels], weight)
+                            + bias.astype(F32)).astype(x.dtype)
         ys = tuple(y[..., start:start + width] for start, width in pieces)
     else:
-        ys = _conv_vmem(x, weight, walk, widths)
+        ys = _conv_vmem(x, weight, bias, walk, widths)
     return ys if splits else ys[0]
 
 
@@ -586,11 +611,24 @@ def _norm_xla(o, z, weight, epsilon):
         o.dtype).reshape(o.shape)
 
 
-def gated_rms_norm(o, z, weight, *, epsilon=1e-6):
+def _gate_then_norm_xla(o, z, weight, epsilon):
+    gated = o.astype(F32) * jax.nn.silu(z.astype(F32))
+    var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+    return (gated * jax.lax.rsqrt(var + epsilon)
+            * weight.astype(F32)).astype(o.dtype)
+
+
+def gated_rms_norm(o, z, weight, *, epsilon=1e-6, gate_first=False):
     """o / rms(o) * weight * silu(z), head by head: o [batch, seq, heads *
     d] as the rule leaves it, a head being ``d = len(weight)`` lanes; z the
     LAST heads * d lanes of [batch, seq, heads * d or more]. Statistics and
     the gate in float32; the gain is not zero-centred.
+
+    ``gate_first`` is the other order: g = o * silu(z), then g / rms(g) *
+    weight with ONE statistic over all of o's lanes and a gain of that
+    width (a row's sum crosses the 128-lane blocks the kernels below work
+    in: it runs as the ``jax.numpy`` expression, ``mixer_pass.why``
+    ``statistic_over_all_lanes``).
 
     Where d is one block of 128 lanes and the sequence whole rows of 8,
     two kernels do it (``gated_rms_norm_fwd``, ``_bwd``: do, dz and the
@@ -599,6 +637,12 @@ def gated_rms_norm(o, z, weight, *, epsilon=1e-6):
     Each trace leaves one ``mixer_pass`` event."""
     batch, seq, lanes = o.shape
     d, gate = weight.shape[0], z.shape[2] - lanes
+    if gate_first:
+        if d != lanes:
+            raise ValueError(f"gate-first norm: a gain of {d} for {lanes}")
+        _pass_event("gated_rms_norm", None, "statistic_over_all_lanes",
+                    batch, seq, lanes)
+        return _gate_then_norm_xla(o, z[..., gate:], weight, epsilon)
     if d != 128:
         walk, why = None, "head_not_128_lanes"
     else:

@@ -24,6 +24,12 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
                    traced for a shape: its Pallas kernels ("vmem") or the
                    jax.numpy expression ("xla", with why), rows, lanes and
                    the rows of a grid step (trace time, once per trace)
+  ssd_chunks       the state-space scan was traced for a shape: seq, chunk,
+                   the heads held and published, groups, its Pallas kernels
+                   ("vmem") or the jax.numpy chunks ("xla", with why; counted
+                   as ssd_scan_fallbacks) (trace time, once per trace)
+  mixer_share      a mixer that holds a share of its heads was traced: the
+                   kind (site), the heads held and published
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that

@@ -17,6 +17,7 @@ import importlib
 import os
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -28,6 +29,7 @@ import paddle_tpu as paddle
 fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 fu = importlib.import_module("paddle_tpu.ops.pallas.fused_update")
 la = importlib.import_module("paddle_tpu.ops.linear_attention")
+ssm = importlib.import_module("paddle_tpu.ops.state_space")
 
 
 @pytest.fixture(scope="module")
@@ -61,6 +63,7 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(fu, "_interpret", lambda: False)
     monkeypatch.setattr(la, "_interpret", lambda: False)
+    monkeypatch.setattr(ssm, "_interpret", lambda: False)
 
 
 def _sds(shape, dtype, sharding):
@@ -307,3 +310,83 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, compiled_kernels,
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 14.5 * 2**30, mem
     assert len(step._params) == 62  # expert weights are stacked leaves
+
+
+# ---------------------------------------------------------------------------
+# granite4h-train-s8192 (batch 1 x 8,192; benchmark/configs/granite-4.0-h-*)
+# ---------------------------------------------------------------------------
+def test_ssd_scan_compiles_for_v5e(one_chip, compiled_kernels):
+    """16 heads of 64 on one B / C group of 128 at s = 8,192, chunk 256,
+    forward and backward: the two kernels are in the compiled text, and no
+    table of all chunks leaves VMEM (no float32 [.., 256, 256] array)."""
+    x = _sds((1, 8192, 16, 64), jnp.bfloat16, one_chip)
+    gate = _sds((1, 8192, 16), jnp.float32, one_chip)
+    head = _sds((16,), jnp.bfloat16, one_chip)
+    bc = _sds((1, 8192, 1, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(*args):
+        def loss(*a):
+            return ssm.ssd_scan(*a).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=tuple(range(6)))(*args)
+
+    compiled = jax.jit(fwd_bwd).lower(x, gate, head, bc, bc, head).compile()
+    text = compiled.as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert not re.findall(r"f32\[[0-9,]*256,256\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def test_granite_hybrid_train_step_compiles_for_v5e(one_chip,
+                                                    compiled_kernels,
+                                                    monkeypatch):
+    """The whole compile_train_step program of the cell, built from the
+    cell's own configuration file: published widths, one period of ten
+    layers, 16 of 128 state-space heads, 4 of 32 query heads on 1 KV head, 9
+    of 72 experts, an eighth of the vocabulary, AMP O2 bf16, AdamW, batch 1 x
+    8,192, nothing recomputed. It fits the chip and holds the scan's and the
+    conv's kernels, the flash kernels and the grouped products. (PERF.md
+    section 4 has the memory it reads, and what it reads with the mixers
+    recomputed.)"""
+    import json
+
+    import paddle_tpu.nn.initializer as I
+    from benchmark.lib import program_granite_hybrid as prog
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models import GPTPretrainingCriterion
+
+    # shapes are all that matter here: skip drawing a billion normals
+    monkeypatch.setattr(I.Normal, "_generate",
+                        lambda self, shape, dtype: jnp.zeros(shape, dtype))
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "granite-4.0-h-small-tp8ep8.json")) as f:
+        sizes = json.load(f)
+    assert sizes["recompute_mixer"] is False
+    _, model = prog.build_model(sizes)
+    model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    crit = GPTPretrainingCriterion()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, lb: crit(lg.astype("float32"), lb), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((1, 8192), jnp.int32, one_chip)
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), _random.next_key(),
+            jnp.asarray(1e-4, jnp.float32), ids, ids)
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    compiled = step._build().lower(*specs).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq", "ssd_scan_fwd", "ssd_scan_bwd",
+                 "ragged-dot", "short_conv_silu_fwd", "short_conv_silu_bwd"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 14.0 * 2**30, mem
+    assert sum(int(np.prod(p.shape)) for p in step._params) == 1_221_088_944
+    assert len(step._params) == 157
