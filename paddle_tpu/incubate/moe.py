@@ -11,7 +11,11 @@ chip uses: it is told which experts of ``num_experts`` it HOLDS (this chip's
 share of an expert-parallel deployment), routes over all of them, gathers the
 slots that fall on held experts sorted by expert, multiplies them as grouped
 matrix products (``jax.lax.ragged_dot``, a Mosaic kernel on the TPU) over
-stacked ``[held, ...]`` leaves, scatters back and combines. No slot is
+stacked ``[held, ...]`` leaves with the slot weight applied on the narrow
+activation between them, and scatter-adds the down product's float32 rows
+into the tokens: between ops the rows lie in the tokens' dtype, and nothing
+of the buffer's length and the model's width is written but the two
+products' own results (forward; backward likewise). No slot is
 dropped whatever the imbalance, and every shape is static: the sorted slots
 go through a row buffer of fixed size in as many passes as the routed load
 needs (one, unless routing is badly skewed). On one chip it runs without its
@@ -362,27 +366,53 @@ _permute.defvjp(lambda x, order, inverse: (x[order], (order, inverse)),
                 lambda res, g: (g[res[1]], None, None))
 
 
-def _swiglu_groups(xin, w_gate_up, w_down, sizes):
-    """silu(x W_g) * (x W_u), then W_d, each row under its own expert's
-    weights: rows sorted by expert, ``sizes`` rows for each."""
-    h = jax.lax.ragged_dot(xin, w_gate_up, sizes,
-                           preferred_element_type=jnp.float32)
-    gate, up = jnp.split(h, 2, axis=-1)
-    act = (jax.nn.silu(gate) * up).astype(xin.dtype)
-    return jax.lax.ragged_dot(act, w_down, sizes,
+def _grouped(rows, stack, sizes):
+    """rows [R, a] x stack [held, a, b] -> float32 [R, b], each row under its
+    own expert's matrix: rows sorted by expert, ``sizes`` rows for each. A
+    cotangent goes back through ``swapaxes(stack, 1, 2)``, a copy of the
+    stack: dimension numbers that contract the stack's last axis instead
+    lower to a dense product over all held experts under a mask, not to the
+    grouped kernel."""
+    return jax.lax.ragged_dot(rows, stack, sizes,
                               preferred_element_type=jnp.float32)
 
 
-def _pass_rows(c, rows, tok, wgt, offsets):
-    """Pass c of the row buffer: its slots' tokens and weights, which of its
-    rows hold a routed slot, and each held expert's rows inside it."""
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_outer(left, right, sizes, dtype):
+    """A stack's gradient as one grouped product: left [R, a] x right [R, b]
+    -> [held, a, b] in ``dtype``, each expert's rows contracted away in
+    float32."""
+    return jax.lax.ragged_dot_general(left, right, sizes, _ROWS_CONTRACTED,
+                                      preferred_element_type=dtype)
+
+
+def _swiglu_groups(xin, w_gate_up, sizes):
+    """The float32 halves (gate, up) of x W_gu, each row under its own
+    expert's weights."""
+    return jnp.split(_grouped(xin, w_gate_up, sizes), 2, axis=-1)
+
+
+def _swiglu(gate, up):
+    return jax.nn.silu(gate) * up
+
+
+def _pass_rows(c, rows, tok, wgt, offsets, tokens):
+    """Pass c of the row buffer: its slots' tokens, the same with the rows
+    that hold no routed slot sent past the last token (where a scatter drops
+    them), its slots' weights, which rows hold a routed slot, and each held
+    expert's rows inside it."""
     lo = c * np.int32(rows)
-    at = lo + jnp.arange(rows, dtype=jnp.int32)
+    valid = lo + jnp.arange(rows, dtype=jnp.int32) < offsets[-1]
     sizes = (jnp.clip(offsets[1:], lo, lo + rows)
              - jnp.clip(offsets[:-1], lo, lo + rows))
-    return (jax.lax.dynamic_slice(tok, (lo,), (rows,)),
-            jax.lax.dynamic_slice(wgt, (lo,), (rows,)),
-            (at < offsets[-1])[:, None], sizes, lo)
+    t = jax.lax.dynamic_slice(tok, (lo,), (rows,))
+    return (t, jnp.where(valid, t, np.int32(tokens)),
+            jax.lax.dynamic_slice(wgt, (lo,), (rows,))[:, None],
+            valid[:, None], sizes, lo)
 
 
 def _n_passes(offsets, rows):
@@ -398,11 +428,23 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     ``sort_held_slots``. The sorted slots pass through a buffer of ``rows``
     rows, ceil(routed / rows) times: the count is data, so the loop is a
     while loop and the backward (which walks the same passes, recomputing
-    each pass's activations) is written out, not derived."""
+    each pass's activations) is written out, not derived.
+
+    What a pass writes to HBM between its gather and its scatter-add: the
+    gathered rows (x's dtype), the float32 [rows, 2 d] gate-up product, the
+    weighted activation (x's dtype, [rows, d]) and the down product's float32
+    [rows, h] result, which the scatter-add reads as it is. The slot weight
+    and the mask sit on the d-wide activation, never on a [rows, h] array.
+    The mask is a select there AND an index: the grouped product skips the
+    tiles past the groups and leaves what was in memory, so those rows of
+    its result are not zero whatever it was given; they leave by the index
+    (``mode="drop"``), not by a multiplication."""
     def one_pass(c, y):
-        t, w, valid, sizes, _ = _pass_rows(c, rows, tok, wgt, offsets)
-        out = _swiglu_groups(x[t], w_gate_up, w_down, sizes)
-        return y.at[t].add(jnp.where(valid, out * w[:, None], 0.0))
+        t, kept, w, valid, sizes, _ = _pass_rows(
+            c, rows, tok, wgt, offsets, x.shape[0])
+        gate, up = _swiglu_groups(x[t], w_gate_up, sizes)
+        act = jnp.where(valid, _swiglu(gate, up) * w, 0.0).astype(x.dtype)
+        return y.at[kept].add(_grouped(act, w_down, sizes), mode="drop")
 
     y = jax.lax.fori_loop(np.int32(0), _n_passes(offsets, rows), one_pass,
                           jnp.zeros(x.shape, jnp.float32))
@@ -415,21 +457,43 @@ def _held_fwd(x, wgt, w_gate_up, w_down, tok, offsets, rows):
 
 
 def _held_bwd(rows, res, dy):
+    """A pass of the backward: the gate-up product again, then four grouped
+    products, every operand in the rows' dtype and every sum float32 (seven
+    a pass with the forward's two, where a derived backward ran eight). With
+    g = dy[t] W_d^T, the unweighted [rows, d] cotangent, the slot weight's
+    gradient is sum(swiglu * g) and needs no second down product, and the
+    activation's is g * w; the weight goes on the activation's side of
+    W_d's gradient too, so dy's rows enter both products as gathered. The
+    [rows, 2 d] cotangent of the gate-up product is masked and rounded once,
+    where the SwiGLU's derivative is applied, for the two products that read
+    it. Each stack's gradient is written by its product in the stack's dtype
+    on the first pass, which always runs; a later pass adds to it in
+    float32."""
     x, wgt, w_gate_up, w_down, tok, offsets = res
+
+    def summed(c, total, part):
+        return jax.lax.cond(
+            c == 0, lambda: part,
+            lambda: (total.astype(jnp.float32) + part).astype(total.dtype))
 
     def one_pass(c, carry):
         dx, dwgt, dgu, dd = carry
-        t, w, valid, sizes, lo = _pass_rows(c, rows, tok, wgt, offsets)
-        out, vjp = jax.vjp(
-            lambda xin, a, b: _swiglu_groups(xin, a, b, sizes),
-            x[t], w_gate_up, w_down)
-        dyt = dy[t].astype(jnp.float32)
-        dxin, dgu_c, dd_c = vjp(jnp.where(valid, dyt * w[:, None], 0.0))
-        dw = jnp.where(valid[:, 0], (out * dyt).sum(-1), 0.0)
-        return (dx.at[t].add(jnp.where(valid, dxin.astype(jnp.float32), 0.0)),
+        t, kept, w, valid, sizes, lo = _pass_rows(
+            c, rows, tok, wgt, offsets, x.shape[0])
+        xin, dyt = x[t], dy[t]
+        gate, up = _swiglu_groups(xin, w_gate_up, sizes)
+        s, pull = jax.vjp(_swiglu, gate, up)
+        g = _grouped(dyt, jnp.swapaxes(w_down, 1, 2), sizes)
+        dh = jnp.where(valid, jnp.concatenate(pull(g * w), axis=-1),
+                       0.0).astype(x.dtype)
+        act = jnp.where(valid, s * w, 0.0).astype(x.dtype)
+        dxin = _grouped(dh, jnp.swapaxes(w_gate_up, 1, 2), sizes)
+        dgu_c = _grouped_outer(xin, dh, sizes, dgu.dtype)
+        dd_c = _grouped_outer(act, dyt, sizes, dd.dtype)
+        dw = jnp.where(valid[:, 0], (s * g).sum(-1), 0.0)
+        return (dx.at[kept].add(dxin, mode="drop"),
                 jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
-                (dgu.astype(jnp.float32) + dgu_c).astype(dgu.dtype),
-                (dd.astype(jnp.float32) + dd_c).astype(dd.dtype))
+                summed(c, dgu, dgu_c), summed(c, dd, dd_c))
 
     dx, dwgt, dgu, dd = jax.lax.fori_loop(
         np.int32(0), _n_passes(offsets, rows), one_pass,

@@ -287,12 +287,12 @@ def mixer_pass_inputs(seq, dtype, seed=21):
                 ct=draw(2, seq, 256))
 
 
-def assert_close(got, want, dtype, what):
+def assert_close(got, want, dtype, what, roundings=1):
     """To the rounding of ONE result in ``dtype`` (the kernels sum in
     float32 and round once), relative to the largest entry."""
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert got.shape == want.shape, what
-    tol = 2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5
+    tol = roundings * (2.0 ** -7 if dtype == jnp.bfloat16 else 1e-5)
     assert np.abs(got - want).max() <= tol * np.abs(want).max(), what
 
 
@@ -499,6 +499,86 @@ def test_routing_extremes_drop_nothing(case):
     for name, a, c in zip(names, got, ref_grad):
         np.testing.assert_allclose(
             a, c, atol=2e-5 * float(jnp.abs(c).max()) + 1e-6, err_msg=name)
+
+
+@pytest.fixture
+def garbage_past_the_groups(monkeypatch):
+    """The grouped product as the chip runs it: Mosaic's kernel skips the
+    tiles past the groups, so those rows of its result hold whatever was in
+    memory. Here they hold NaN, which no multiplication by zero removes."""
+    plain = moe._grouped
+
+    def grouped(rows, stack, sizes):
+        out = plain(rows, stack, sizes)
+        past = jnp.arange(out.shape[0]) >= sizes.sum()
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(moe, "_grouped", grouped)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("passes", [1, 3])
+def test_output_and_every_gradient_in_one_pass_and_in_three(
+        garbage_past_the_groups, dtype, passes):
+    """The layer's output and the gradient of x, of the router (which gets
+    it through the slot weights), of both stacks and of the shared expert
+    against the reference on the same numbers, within one rounding of the
+    dtype: the slot weight's gradient from the d-wide cotangent, the stacks'
+    gradients written by the first pass and added to by the later two."""
+    held, top_k, tokens, first = 4, 4, 64, 4
+    w = {k: a.astype(dtype) for k, a in expert_weights().items()}
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.standard_normal((tokens, 32)), dtype)
+    ct = jnp.asarray(rng.standard_normal((tokens, 32)), jnp.float32)
+    sizes = dict(num_experts_per_tok=top_k, num_experts=held)
+    routed = int(program_experts(x, w, first, top_k, 256)[1])
+    rows = -(-routed // (8 * passes)) * 8
+    names = ["x"] + list(w)
+
+    def run(fn):
+        def loss(*a):
+            return (fn(a[0], dict(zip(list(w), a[1:]))).astype(jnp.float32)
+                    * ct).sum()
+        return jax.grad(loss, range(len(names)))
+
+    y, _, ran = program_experts(x, w, first, top_k, rows)
+    assert int(ran) == passes * rows and y.dtype == dtype
+    got = run(lambda x, w: program_experts(x, w, first, top_k, rows)[0])(
+        x, *w.values())
+    wide = [a.astype(jnp.float32) for a in (x, *w.values())]
+    want_y = ref.experts(wide[0], dict(zip(list(w), wide[1:])), sizes,
+                         held=(first, held))
+    want = run(lambda x, w: ref.experts(x, w, sizes, held=(first, held)))(
+        *wide)
+    assert_close(y, want_y, dtype, "y")
+    for name, a, c in zip(names, got, want):
+        assert a.dtype == dtype, name
+        # the shared expert's bf16 chain (and x through it) rounds at every op
+        routed_alone = name in ("router", "egu_w", "ed_w")
+        assert_close(a, c, dtype, name, roundings=1 if routed_alone else 2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_no_slot_held_gives_exact_zeros(garbage_past_the_groups, dtype):
+    """With no slot on a held expert every row of the buffer lies past the
+    groups: the routed part and all four of its gradients are exactly zero,
+    whatever the products left in those rows."""
+    w = {k: a.astype(dtype) for k, a in expert_weights().items()}
+    rng = np.random.default_rng(13)
+    x = jnp.asarray(rng.standard_normal((64, 32)), dtype)
+    tok = jnp.asarray(rng.integers(0, 64, 96), jnp.int32)
+    wgt = jnp.asarray(rng.random(96), jnp.float32)
+    offsets = jnp.zeros(5, jnp.int32)
+
+    def routed(x, wgt, gate_up, down):
+        return moe.held_experts_apply(x, wgt, gate_up, down, tok, offsets, 32)
+
+    y, vjp = jax.vjp(routed, x, wgt, w["egu_w"], w["ed_w"])
+    for name, a in zip(("y", "x", "wgt", "egu_w", "ed_w"),
+                       (y,) + vjp(jnp.ones_like(y))):
+        assert not np.asarray(a, np.float32).any(), name
 
 
 def test_counters_ride_the_compiled_step_and_one_event_a_compile(both):

@@ -313,6 +313,63 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, compiled_kernels,
 
 
 # ---------------------------------------------------------------------------
+# the dropless expert layer at both sparse cells' shapes (PERF.md section 4)
+# ---------------------------------------------------------------------------
+# tokens a step, model width, expert width, router width, experts held, the
+# shared expert's width, whether it is gated
+EXPERT_LAYERS = {
+    "granite4h": (8192, 4096, 768, 72, 9, 1536, False),
+    "qwen3next": (16384, 2048, 512, 512, 64, 512, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(EXPERT_LAYERS))
+def test_expert_layer_pass_moves_its_rows_in_bf16(one_chip, cell):
+    """One expert layer, forward and gradient, bf16, ten slots a token: a
+    pass holds 7 grouped products (2 forward; the gate-up product again and
+    4 more backward: the down product is not made again, so one fewer than
+    the 8 a derived backward held), every operand of the buffer's length is
+    bf16, the two weight-gradient products return bf16 stacks, no dense
+    product under a [held, rows] mask stands in for a grouped one, and
+    nothing of the buffer's length and the model's width is written in
+    float32 but the two products' own results."""
+    from paddle_tpu.incubate import moe
+
+    tokens, h, d, wide, held, d_shared, shared_gate = EXPERT_LAYERS[cell]
+    rows = moe.row_buffer_rows(tokens, 10, wide, held)
+    x_and_leaves = [(tokens, h), (h, wide), (held, h, 2 * d), (held, d, h),
+                    (h, 2 * d_shared), (d_shared, h)] + [(h, 1)] * shared_gate
+
+    def fwd_bwd(ct, *a):
+        y, vjp = jax.vjp(lambda *a: moe.dropless_experts(
+            *a, *[None] * (not shared_gate), first=0, top_k=10,
+            renormalize=True, rows=rows)[0], *a)
+        return (y,) + vjp(ct)
+
+    text = _compiled_text(fwd_bwd, *[
+        _sds(shape, jnp.bfloat16, one_chip)
+        for shape in [(tokens, h)] + x_and_leaves])
+    calls = [line for line in text.split("\n")
+             if re.match(r"\s*%ragged-dot-none\S* = .* custom-call\(", line)]
+    assert len(calls) == 7, len(calls)
+    for call in calls:  # the operands' shapes stand in the layout constraints
+        operands = call.split("operand_layout_constraints={")[1].split(
+            "}, frontend_attributes")[0]
+        assert f"[{rows}," in operands or f"[{held}," in operands, call
+        assert not re.search(r"f32\[%d," % rows, operands), operands
+    results = [re.match(r"\s*%\S+ = (\w+\[[\d,]*\])", c).group(1)
+               for c in calls]
+    assert sorted(r for r in results if "f32" not in r) == sorted(
+        [f"bf16[{held},{h},{2 * d}]", f"bf16[{held},{d},{h}]"]), results
+    assert results.count(f"f32[{rows},{h}]") == 2, results
+    assert f"pred[{held},{rows}]" not in text
+    # no weight, mask or rounding is applied on a float32 array of the
+    # buffer's length and the model's width, fused or not
+    assert not re.findall(
+        r"= f32\[%d,%d\]\S* (?:select|multiply|convert)\(" % (rows, h), text)
+
+
+# ---------------------------------------------------------------------------
 # granite4h-train-s8192 (batch 1 x 8,192; benchmark/configs/granite-4.0-h-*)
 # ---------------------------------------------------------------------------
 def test_ssd_scan_compiles_for_v5e(one_chip, compiled_kernels):
