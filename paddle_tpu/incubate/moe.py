@@ -529,8 +529,9 @@ def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
     """The whole expert layer on [T, h] tokens: (y, routed_slots,
     expert_rows). Routes over all of the router's experts; adds, for each
     token, its held experts' weighted outputs and the shared expert, gated
-    by sigmoid(x ``shared_gate``) or, with ``shared_gate`` None, as it is.
-    What the absent experts would have added is left out."""
+    by sigmoid(x ``shared_gate``) or, with ``shared_gate`` None, as it is;
+    with ``shared_gate_up`` None there is no shared expert and nothing is
+    added. What the absent experts would have added is left out."""
     from ..profiler import trace
 
     tokens, count = x.shape[0], w_gate_up.shape[0]
@@ -547,6 +548,8 @@ def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
         routed = offsets[-1]
     with jax.named_scope("experts"):
         y = held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows)
+    if shared_gate_up is None:
+        return y, routed, _n_passes(offsets, rows) * np.int32(rows)
     with jax.named_scope("shared_expert"):
         gate, up = jnp.split(jnp.matmul(x, shared_gate_up), 2, axis=-1)
         shared = jnp.matmul(jax.nn.silu(gate) * up, shared_down)
@@ -566,7 +569,9 @@ class DroplessExperts(Layer):
     is held), ``w_gate_up`` [count, h, 2 d] and ``w_down`` [count, d, h]
     (stacked leaves, not 3 x count arrays), ``shared_gate_up`` [h, 2 d_s],
     ``shared_down`` [d_s, h], ``shared_gate`` [h, 1] (none with
-    ``shared_gate=False``: the shared expert is added ungated). Two int32 buffers ride
+    ``shared_gate=False``: the shared expert is added ungated; with
+    ``d_shared=0`` the model has no shared expert: none of the three is
+    built and nothing is added). Two int32 buffers ride
     a compiled step like a running statistic and hold, after each forward,
     ``routed_slots`` (slots that fell on held experts) and ``expert_rows``
     (rows of the row buffer the grouped products were given: passes x the
@@ -583,7 +588,7 @@ class DroplessExperts(Layer):
         self.first, self.count = int(first), int(count)
         self.num_experts, self.top_k = num_experts, top_k
         self.renormalize = renormalize
-        d_shared = d_shared or d_expert
+        d_shared = d_expert if d_shared is None else d_shared
 
         def make(*shape):
             return self.create_parameter(shape=list(shape), attr=weight_attr)
@@ -591,9 +596,10 @@ class DroplessExperts(Layer):
         self.router = make(d_model, num_experts)
         self.w_gate_up = make(self.count, d_model, 2 * d_expert)
         self.w_down = make(self.count, d_expert, d_model)
-        self.shared_gate_up = make(d_model, 2 * d_shared)
-        self.shared_down = make(d_shared, d_model)
-        self.shared_gate = make(d_model, 1) if shared_gate else None
+        self.shared_gate_up = make(d_model, 2 * d_shared) if d_shared else None
+        self.shared_down = make(d_shared, d_model) if d_shared else None
+        self.shared_gate = (make(d_model, 1) if shared_gate and d_shared
+                            else None)
         self.register_buffer("routed_slots", Tensor(np.int32(0)))
         self.register_buffer("expert_rows", Tensor(np.int32(0)))
 

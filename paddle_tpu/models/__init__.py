@@ -26,3 +26,9 @@ from .granite_hybrid import (  # noqa: F401
     GraniteHybridForCausalLM,
     GraniteHybridModel,
 )
+from .sdar_moe import (  # noqa: F401
+    BlockDiffusionCriterion,
+    SDARMoEConfig,
+    SDARMoEForBlockDiffusion,
+    SDARMoEModel,
+)

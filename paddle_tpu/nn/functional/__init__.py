@@ -680,22 +680,29 @@ def rms_norm(x, weight, epsilon=1e-6, zero_centered=False, name=None):
                  zero_centered=zero_centered, op_name="rms_norm")
 
 
-def rotary_embedding(x, rotary_dim=None, theta=10000.0, name=None):
+def rotary_embedding(x, rotary_dim=None, theta=10000.0, positions=None,
+                     name=None):
     """Rotary positions on the first ``rotary_dim`` dims of each head of
-    ``x`` [batch, seq, heads, head_dim] (all of them by default)."""
-    return apply(_nn.rotary_embedding, x,
+    ``x`` [batch, seq, heads, head_dim] (all of them by default);
+    ``positions`` [seq] in the place of 0 .. seq - 1."""
+    return apply(_nn.rotary_embedding, x, positions,
                  rotary_dim=int(rotary_dim or x.shape[-1]), theta=float(theta),
                  op_name="rotary_embedding")
 
 
 def scaled_dot_product_attention(
     query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
-    training=True, name=None, scale=None,
+    training=True, name=None, scale=None, block_mask=None,
 ):
     # ``scale`` multiplies q k^T in place of head_dim ** -0.5 (a model with
     # an attention multiplier of its own); without it the ops are called as
-    # they always were
-    scaled = {} if scale is None else {"scale": float(scale)}
+    # they always were. ``block_mask`` = (half, block) is the two-stream mask
+    # of block-diffusion training in the place of ``is_causal``
+    # (ops/pallas/flash_attention.py); the kernels walk it, and where they
+    # cannot the dense path builds it as an array
+    options = {} if scale is None else {"scale": float(scale)}
+    if block_mask is not None:
+        options["block_mask"] = tuple(map(int, block_mask))
     dropout_key = (
         _random.next_key() if (dropout_p > 0.0 and training) else None
     )
@@ -719,12 +726,12 @@ def scaled_dot_product_attention(
             else "attn_mask" if attn_mask is not None
             else "attention_dropout" if dropout_key is not None
             else _nn.flash_attention_refusal(query.shape, key.shape,
-                                             value.shape)
+                                             value.shape, block_mask)
         )
         if refusal is None:
             return apply(
                 _nn.flash_scaled_dot_product_attention, query, key, value,
-                is_causal=is_causal, op_name="flash_sdpa", **scaled,
+                is_causal=is_causal, op_name="flash_sdpa", **options,
             )
         from ...core import dispatch as _dispatch
 
@@ -733,7 +740,7 @@ def scaled_dot_product_attention(
     return apply(
         _nn.scaled_dot_product_attention, query, key, value, attn_mask,
         dropout_key, is_causal=is_causal, dropout_p=dropout_p, op_name="sdpa",
-        **scaled,
+        **options,
     )
 
 

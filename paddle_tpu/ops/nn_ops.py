@@ -445,16 +445,21 @@ def rms_norm(x, weight, *, epsilon=1e-6, zero_centered=False):
     return (xf * jax.lax.rsqrt(var + epsilon) * gain).astype(x.dtype)
 
 
-def rotary_embedding(x, *, rotary_dim, theta=10000.0):
+def rotary_embedding(x, positions=None, *, rotary_dim, theta=10000.0):
     """Rotary positions on the first ``rotary_dim`` dims of each head of
     ``x`` [batch, seq, heads, head_dim], the rest passed through: dim i is
     paired with dim i + rotary_dim / 2 (the half-split convention), position
-    p (from 0) turns pair i by p * theta ** (-2 i / rotary_dim). Angles in
-    float32."""
+    p turns pair i by p * theta ** (-2 i / rotary_dim). Position s of the
+    sequence is s (from 0), or ``positions[s]`` where they are given ([seq],
+    the same for every row of the batch). Angles in float32."""
     s, half = x.shape[1], rotary_dim // 2
     inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32)
                                 * (2.0 / rotary_dim)))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq
+    if positions is None:
+        positions = jnp.arange(s, dtype=jnp.float32)
+    else:
+        positions = positions.astype(jnp.float32)
+    ang = positions[:, None] * inv_freq
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
     x1 = x[..., :half].astype(jnp.float32)
@@ -739,13 +744,26 @@ def dropout(x, key, *, p=0.5, mode="upscale_in_train", mask_shape=None):
 # XLA fuses this well already; a Pallas flash kernel lives in
 # paddle_tpu/ops/pallas/flash_attention.py for long sequences.
 # ---------------------------------------------------------------------------
+def block_diffusion_mask(half, block):
+    """[2 half, 2 half] bool, True where row may attend column: the
+    two-stream mask of block-diffusion training over a stream of ``half``
+    clean positions and then ``half`` noised ones, in blocks of ``block``
+    (``pallas.flash_attention``'s ``block_mask``, as a dense array)."""
+    pos = jnp.arange(2 * half)
+    noised, blk = pos >= half, (pos % half) // block
+    (nq, nk), (bq, bk) = ((a[:, None], a[None, :]) for a in (noised, blk))
+    return ((nq & nk & (bk == bq)) | (nq & ~nk & (bk < bq))
+            | (~nq & ~nk & (bk <= bq)))
+
+
 def scaled_dot_product_attention(
     q, k, v, mask=None, dropout_key=None, *, scale=None, is_causal=False,
-    dropout_p=0.0,
+    dropout_p=0.0, block_mask=None,
 ):
     """q,k,v: [batch, seq, heads, head_dim] (paddle fused_attention layout).
     Attention dropout applies to the probabilities when dropout_key is given
-    (the functional wrapper threads a key only in training).
+    (the functional wrapper threads a key only in training). ``block_mask``
+    = (half, block): ``block_diffusion_mask`` in the place of ``is_causal``.
 
     The flash hot path lives in flash_scaled_dot_product_attention below —
     selection happens in the functional wrapper (nn/functional) so the
@@ -761,7 +779,10 @@ def scaled_dot_product_attention(
         kf = jnp.repeat(kf, group, axis=1)
         vf = jnp.repeat(vf, group, axis=1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * s
-    if is_causal:
+    if block_mask is not None:
+        logits = jnp.where(block_diffusion_mask(*block_mask), logits,
+                           jnp.finfo(logits.dtype).min)
+    elif is_causal:
         ql, kl = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((ql, kl), dtype=bool), k=kl - ql)
         logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
@@ -871,7 +892,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, k_new, v_new, *,
     return out.astype(q.dtype), k_pool, v_pool
 
 
-def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False):
+def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False,
+                                       block_mask=None):
     """Pallas flash kernel path (ops/pallas/flash_attention.py — the
     fused_attention_op.cu replacement): O(S·D) memory instead of the O(S²)
     probability matrix, which is what makes long-seq training fit in HBM.
@@ -881,14 +903,19 @@ def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False):
 
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / (d**0.5)
+    if block_mask is not None:
+        with jax.named_scope("block_diffusion_attention"):
+            return _flash(q, k, v, scale=s, block_mask=block_mask)
     return _flash(q, k, v, scale=s, causal=is_causal)
 
 
-def flash_attention_refusal(q_shape, k_shape, v_shape):
+def flash_attention_refusal(q_shape, k_shape, v_shape, block_mask=None):
     """Why the flash kernel cannot take these [batch, seq, heads, head_dim]
     shapes, as a short reason, or None where it can. k and v may have fewer
-    heads than q (grouped-query heads: a divisor of q's)."""
+    heads than q (grouped-query heads: a divisor of q's). ``block_mask``:
+    the two-stream block mask (half, block) asked for."""
     from .pallas.flash_attention import supports as _supports
+    from .pallas.flash_attention import supports_block_mask
 
     q_shape, k_shape, v_shape = map(tuple, (q_shape, k_shape, v_shape))
     if len(q_shape) != 4 or len(k_shape) != 4:
@@ -900,7 +927,10 @@ def flash_attention_refusal(q_shape, k_shape, v_shape):
         return "q_kv_lengths_differ"
     if k_shape[2] == 0 or q_shape[2] % k_shape[2]:
         return "kv_heads_do_not_divide_q_heads"
-    if not _supports(q_shape[1], q_shape[3]):
+    if block_mask is not None:
+        if not supports_block_mask(q_shape[1], q_shape[3], block_mask):
+            return "block_mask_not_tiled"
+    elif not _supports(q_shape[1], q_shape[3]):
         return "seq_or_head_dim_not_tiled"
     return None
 

@@ -28,6 +28,25 @@ sub-tile sizes follow the shape; the sweep they were chosen from is PERF.md
 §5, "flash attention sub-tile sweep". Accumulation is always f32 regardless
 of input dtype (bf16 in → bf16 out, f32 math). Off the TPU (tests/dev on
 CPU) the kernel runs in interpret mode.
+
+The same three kernels walk the two-stream block mask of block-diffusion
+training (``block_mask`` = (half, blk): a stream of ``half`` clean positions
+and then ``half`` noised ones, in blocks of ``blk``). It is the causal walk
+with the diagonal rounded to the mask's blocks, made by BOTH halves' q blocks
+over the CLEAN half's key blocks: up to the end of a clean row's own block
+(``_key_walk`` with ``blk``), up to the start of a noised row's
+(``before``). The grid's q axis is the stream's (2 half / bq blocks), its k
+axis the clean half's, so the quadrant clean-on-noised is no grid step and
+is never fetched. What is left, a noised row on the noised keys of its own
+block, lies on the block diagonal of the fourth quadrant: the noised key
+block at the place of the clean block the diagonal crosses rides along as two
+more operands (the same k and v arrays under a second index map; dkv gives
+it two more outputs), and its sub-tiles on the diagonal (``own``) join that
+step's strips, inside the same softmax. Only sub-tiles a rounded diagonal
+crosses, and the own sub-tiles, build a mask (``_causal_mask``: a compare on
+positions or-ed, and-ed or xor-ed with blk - 1, so blk is a power of two).
+With ``block_mask`` None every index map, walk and mask is what it was:
+``blk`` 1 is the plain diagonal.
 """
 from __future__ import annotations
 
@@ -101,30 +120,74 @@ def _starts_le(x, base, step, n):
     return _clip(_div(x - base + step, step), n)
 
 
-def _key_walk(row0, rows, col_base, step, n, causal):
+def _plus(x, n):
+    # x + n with nothing traced where n is 0: blk 1 is the causal walk as it
+    # always was, index maps included
+    return x + n if n else x
+
+
+def _key_walk(row0, rows, col_base, step, n, causal, blk=1, before=False):
     """For q rows row0 .. row0+rows-1 and n key tiles of `step` columns from
     col_base: (n_full, n_run). Tiles [0, n_full) lie wholly on or under the
     diagonal (last column <= first row: no mask), [n_full, n_run) are crossed
-    by it (first column <= last row: masked), the rest are skipped."""
+    by it (first column <= last row: masked), the rest are skipped. With
+    positions in blocks of ``blk`` (row0 and rows whole blocks) the diagonal
+    is rounded to them: a row sees every key up to the end of its own block
+    or, with ``before``, only the blocks before its own."""
     if not causal:
         return n, n
-    return (_ends_le(row0, col_base, step, n),
+    if before:
+        return (_ends_le(row0 - 1, col_base, step, n),
+                _starts_le(row0 + rows - blk - 1, col_base, step, n))
+    return (_ends_le(_plus(row0, blk - 1), col_base, step, n),
             _starts_le(row0 + rows - 1, col_base, step, n))
 
 
-def _query_walk(col0, cols, row_base, step, n):
+def _query_walk(col0, cols, row_base, step, n, blk=1, before=False):
     """The transposed (causal) walk, for key columns col0 .. col0+cols-1 and n
     q tiles of `step` rows from row_base: (r_first, r_full). Tiles [0, r_first)
     are skipped (last row < first column), [r_first, r_full) are crossed by
-    the diagonal, [r_full, n) lie wholly on or under it."""
+    the diagonal, [r_full, n) lie wholly on or under it. ``blk`` and
+    ``before`` round the diagonal as in ``_key_walk``."""
+    if before:
+        return (_ends_le(col0 + blk - 1, row_base, step, n),
+                _starts_le(col0 + cols - 1, row_base, step, n))
     return (_ends_le(col0 - 1, row_base, step, n),
-            _starts_le(col0 + cols - 2, row_base, step, n))
+            _starts_le(col0 + cols - (1 + blk), row_base, step, n))
 
 
-def causal_tile_counts(seq_len, block_q, block_k, sub_q, sub_k, causal):
+def _overlapped(lo, hi, step):
+    """How many tiles of `step` the positions [lo, hi) touch."""
+    return (hi - 1) // step - lo // step + 1
+
+
+def causal_tile_counts(seq_len, block_q, block_k, sub_q, sub_k, causal,
+                       block_mask=None):
     """(run, masked, total) sub-tiles of one head's seq_len x seq_len score
     square as the kernels walk it: computed, computed with a mask, and all.
-    `run / total` is how far the causal skip engages (1.0 = not at all)."""
+    `run / total` is how far the causal skip engages (1.0 = not at all).
+    ``block_mask`` = (half, blk) counts the two-stream block mask's walk
+    (``flash_attention``): each half's rows over the clean keys, and the
+    noised rows' own blocks."""
+    if block_mask is not None:
+        half, blk = block_mask
+        n_sk = block_k // sub_k
+        run = masked = 0
+        for noised in (False, True):
+            for r0 in range(0, half, sub_q):  # a q sub-block, in its half
+                j0 = r0 // block_q * block_q
+                _, k_steps = _key_walk(j0, block_q, 0, block_k,
+                                       half // block_k, True)
+                for kk in range(k_steps):
+                    n_full, n_run = _key_walk(r0, sub_q, kk * block_k, sub_k,
+                                              n_sk, True, blk, noised)
+                    run += n_run
+                    masked += n_run - n_full
+                if noised:
+                    own = _overlapped(r0, r0 + sub_q, sub_k)
+                    run += own
+                    masked += own  # always built: a few tiles of the walk
+        return run, masked, (seq_len // sub_q) * (seq_len // sub_k)
     n_q, n_k = seq_len // block_q, seq_len // block_k
     n_sq, n_sk = block_q // sub_q, block_k // sub_k
     run = masked = 0
@@ -177,41 +240,83 @@ def _at_block_offset(n_q, n_k, q_axis, k_axis, bq, bk, causal, walk,
         pl.when(off >= bk - 1)(functools.partial(walk, None))
 
 
-def _q_strips(off, bq, bk, sq, sk):
+def _at_stream_block(n_qh, n_k, q_axis, k_axis, bq, bk, walk, q_wraps=False):
+    """``_at_block_offset`` under the two-stream block mask: the q axis walks
+    the clean half's n_qh blocks and then the noised half's, each against the
+    n_k blocks of CLEAN keys, and a block's offset is taken inside its half.
+    Calls walk(off, noised): a block the rounded diagonal crosses has one
+    copy of the walk for clean rows and one for noised rows (which also meet
+    their own blocks' noised keys there); a block wholly under it is the
+    same walk for both. Offsets are multiples of bq and bk of bq, so the
+    causal thresholds hold for the rounded diagonals too."""
+    offs = {j * bq - kk * bk for j in range(n_qh) for kk in range(n_k)}
+    j = pl.program_id(q_axis)
+    if q_wraps:
+        j = jax.lax.rem(j, np.int32(2 * n_qh))
+    noised = j >= n_qh
+    off = jnp.where(noised, j - n_qh, j) * bq - pl.program_id(k_axis) * bk
+    for d in sorted(d for d in offs if -bq < d < bk - 1):
+        pl.when((off == d) & ~noised)(functools.partial(walk, d, False))
+        pl.when((off == d) & noised)(functools.partial(walk, d, True))
+    if max(offs) >= bk - 1:
+        pl.when(off >= bk - 1)(functools.partial(walk, None, False))
+
+
+def _q_strips(off, bq, bk, sq, sk, blk=1, noised=False):
     """The forward's and dq's walk of a resident [bq, bk] block: one strip of
     scores for each q sub-block, over every key it attends to. Yields (rows,
-    keys, first masked column). A block wholly under the diagonal (off None)
-    is one strip."""
+    keys, first masked column, own). A block wholly under the diagonal (off
+    None) is one strip. ``own``: for noised rows of the block mask, the
+    columns of the NOISED key block at this block's place that hold the
+    rows' own blocks (else None); their scores join the strip's."""
     if off is None:
-        yield slice(0, bq), bk, bk
+        yield slice(0, bq), bk, bk, None
         return
     for i in range(bq // sq):
-        n_full, n_run = _key_walk(off + i * sq, sq, 0, sk, bk // sk, True)
-        if n_run:  # else this block has no key at or before these rows
-            yield slice(i * sq, (i + 1) * sq), n_run * sk, n_full * sk
+        r0 = off + i * sq
+        n_full, n_run = _key_walk(r0, sq, 0, sk, bk // sk, True, blk, noised)
+        own = slice(r0, r0 + sq) if noised and 0 <= r0 < bk else None
+        if n_run or own:  # else this block has no key these rows attend to
+            yield slice(i * sq, (i + 1) * sq), n_run * sk, n_full * sk, own
 
 
-def _k_strips(off, bq, bk, sq, sk):
+def _k_strips(off, bq, bk, sq, sk, blk=1, noised=False):
     """dkv's walk, transposed: one strip for each key sub-block, over every q
-    row that attends to it. Yields (columns, first row, first unmasked row)."""
+    row that attends to it. Yields (columns, rows, masked, own): the first
+    ``masked`` rows of the strip are of sub-tiles the diagonal crosses.
+    ``own`` strips (noised rows of the block mask) are of the NOISED key
+    block at this block's place against the rows of the same blocks, and
+    always masked."""
     if off is None:
-        yield slice(0, bk), 0, 0
+        yield slice(0, bk), slice(0, bq), 0, False
         return
     for c in range(bk // sk):
-        r_first, r_full = _query_walk(c * sk, sk, off, sq, bq // sq)
+        cols = slice(c * sk, (c + 1) * sk)
+        r_first, r_full = _query_walk(c * sk, sk, off, sq, bq // sq, blk,
+                                      noised)
         if r_first < bq // sq:  # else every row here is above these keys
-            yield slice(c * sk, (c + 1) * sk), r_first * sq, r_full * sq
+            yield cols, slice(r_first * sq, bq), (r_full - r_first) * sq, False
+        lo, hi = max(c * sk - off, 0), min((c + 1) * sk - off, bq)
+        if noised and lo < hi:
+            yield cols, slice(lo, hi), hi - lo, True
 
 
-def _kv_index(causal, bq, bk, n_k, group=1):
+def _kv_index(causal, bq, bk, n_k, group=1, n_qh=None, own=False):
     """Index map of a k/v block under grid (head, q block j, k block kk). A
     step above the diagonal is skipped in the kernel: give it the index of
     the last step that runs, so that Pallas sees no change and copies
     nothing. Under grouped-query heads (``group`` query heads on one KV
     head) query head i reads KV head i // group: k and v are never copied
-    out to the query heads."""
+    out to the query heads. Under the block mask (``n_qh`` q blocks a half)
+    a q block's place is the one inside its half and the n_k key blocks are
+    the clean half's; ``own`` names the noised key block that holds the q
+    block's own blocks instead, whatever the step."""
     def index(i, j, kk):
-        if causal and n_k > 1:
+        if n_qh is not None:
+            j = jax.lax.rem(j, np.int32(n_qh))
+        if own:
+            kk = n_k + _div(j * bq, bk)
+        elif causal and n_k > 1:
             _, k_steps = _key_walk(j * bq, bq, 0, bk, n_k, causal)
             kk = jnp.minimum(kk, k_steps - 1)
         if group > 1:
@@ -220,24 +325,36 @@ def _kv_index(causal, bq, bk, n_k, group=1):
     return index
 
 
-def _causal_mask(s, row0, col0, keys_first=False):
+def _causal_mask(s, row0, col0, keys_first=False, blk=1, before=False,
+                 own=False):
     """Mask a score tile whose corner is (row0, col0) to the causal region
     (shared by all 3 kernels); `keys_first` for a tile with the keys down
-    the sublanes."""
+    the sublanes. With positions in blocks of ``blk`` (a power of two): to
+    the keys up to the end of the row's block, with ``before`` to the blocks
+    before it, with ``own`` to the row's own block."""
     q_dim, k_dim = (1, 0) if keys_first else (0, 1)
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
-    return jnp.where(cols <= rows, s, NEG_INF)
+    if own:
+        seen = (rows ^ cols) < blk
+    elif before:
+        seen = cols < (rows & np.int32(-blk))
+    elif blk > 1:
+        seen = cols <= (rows | np.int32(blk - 1))
+    else:
+        seen = cols <= rows
+    return jnp.where(seen, s, NEG_INF)
 
 
-def _mask_lanes(s, lo, hi, row0, col0, keys_first=False):
+def _mask_lanes(s, lo, hi, row0, col0, keys_first=False, blk=1, before=False):
     """Mask lanes [lo, hi) of a score strip, the sub-tiles the diagonal
     crosses; the lanes beside them lie wholly under it and pass untouched."""
     if lo == hi:
         return s
     parts = [s[:, :lo],
              _causal_mask(s[:, lo:hi], row0 + (lo if keys_first else 0),
-                          col0 + (0 if keys_first else lo), keys_first),
+                          col0 + (0 if keys_first else lo), keys_first, blk,
+                          before),
              s[:, hi:]]
     parts = [x for x in parts if x.shape[1]]
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
@@ -265,6 +382,48 @@ def _times(ref):
     return lambda x, rows: _mm(turned[:, rows], x, _NT).T
 
 
+def _own_refs(refs, mask):
+    """((kn_ref, vn_ref), the other refs) of a kernel's refs after q, k, v:
+    under the block mask the noised key block at the clean block's place
+    rides along."""
+    return (refs[:2], refs[2:]) if mask else ((None, None), refs)
+
+
+def _key_parts(ref, own_ref, keys, own):
+    """[(ref, columns)] a q strip meets: the block's first ``keys`` keys and,
+    for noised rows of the block mask, their own blocks' noised keys."""
+    parts = [(ref, slice(0, keys))] if keys else []
+    return parts if own is None else parts + [(own_ref, own)]
+
+
+def _lanes(x, lo, hi):
+    return x if (lo, hi) == (0, x.shape[1]) else x[:, lo:hi]
+
+
+def _across(x, parts, times):
+    """sum of times(x's lanes of a part, the part): a strip's probabilities
+    (or their cotangents) against each part's values (or keys)."""
+    total, lo = None, 0
+    for ref, cols in parts:
+        n = cols.stop - cols.start
+        y = times(_lanes(x, lo, lo + n), ref, cols)
+        total, lo = y if total is None else total + y, lo + n
+    return total
+
+
+def _strip_mask(parts, keys, masked, own, row0, blk, noised):
+    """A q strip's scores, part by part (``_key_parts``), as one masked
+    array: the clean keys' lanes from ``masked`` on under the (rounded)
+    diagonal, the own blocks' lanes to the row's own block."""
+    out = []
+    if keys:
+        out.append(_mask_lanes(parts[0], masked, keys, row0, 0, blk=blk,
+                               before=noised))
+    if own is not None:
+        out.append(_causal_mask(parts[-1], row0, own.start, blk=blk, own=True))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
 def _ahead(strips, products):
     """(strip, products(strip)) for each strip, with the next strip's products
     issued before this strip's are handed out: the MXU then works on them
@@ -290,8 +449,11 @@ def _finish(m, l, acc, o_ref, lse_ref, rows):
     lse_ref[0, :, rows] = jnp.broadcast_to(lse, (lse.shape[0], 128)).T[:1]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                scale, causal, bq, bk, sq, sk, n_q, n_k):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
+                scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None):
+    # mask = (q blocks a half, blk) or None
+    (kn_ref, vn_ref), (o_ref, lse_ref, *scratch) = _own_refs(refs, mask)
+    blk = mask[1] if mask else 1
     scale = np.float32(scale)
     if scratch:  # (m, l, acc) carried from one k step to the next
         m_scr, l_scr, acc_scr = scratch
@@ -303,22 +465,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def scores(strip):
-        rows, keys, _ = strip
-        return _mm(q_ref[0, rows, :], k_ref[0, :keys, :], _NT) * scale
+        rows, keys, _, own = strip
+        return [_mm(q_ref[0, rows, :], ref[0, cols, :], _NT) * scale
+                for ref, cols in _key_parts(k_ref, kn_ref, keys, own)]
 
-    def walk(off):
-        for (rows, keys, masked), s in _ahead(_q_strips(off, bq, bk, sq, sk),
-                                              scores):
+    def times_v(p, ref, cols):
+        v = ref[0, cols, :]
+        return _mm(p.astype(v.dtype), v, _NN)
+
+    def walk(off, noised=False):
+        for (rows, keys, masked, own), s in _ahead(
+                _q_strips(off, bq, bk, sq, sk, blk, noised), scores):
             n = rows.stop - rows.start
-            s = _mask_lanes(s, masked, keys, (off or 0) + rows.start, 0)
+            s = _strip_mask(s, keys, masked, own, (off or 0) + rows.start,
+                            blk, noised)
             m = jnp.max(s, axis=-1, keepdims=True)
             if scratch:
                 m_prev = m_scr[rows, :1]
                 m = jnp.maximum(m_prev, m)
             p = jnp.exp(s - m)
             l = jnp.sum(p, axis=-1, keepdims=True)
-            v = v_ref[0, :keys, :]
-            acc = _mm(p.astype(v.dtype), v, _NN)
+            acc = _across(p, _key_parts(v_ref, vn_ref, keys, own), times_v)
             if scratch:
                 alpha = jnp.exp(m_prev - m)
                 m_scr[rows, :] = jnp.broadcast_to(m, (n, m_scr.shape[1]))
@@ -328,7 +495,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             else:
                 _finish(m, l, acc, o_ref, lse_ref, rows)
 
-    _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
+    if mask:
+        _at_stream_block(mask[0], n_k, 1, 2, bq, bk, walk)
+    else:
+        _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
 
     if scratch:
         @pl.when(pl.program_id(2) == n_k - 1)
@@ -337,10 +507,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                     slice(None))
 
 
-def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1):
+def _grid_of(s, bq, bk, mask):
+    """(q blocks, key blocks, the kernels' ``mask``): under the block mask
+    (half, blk) the q blocks are the whole stream's and the key blocks the
+    clean half's."""
+    if mask is None:
+        return s // bq, s // bk, None
+    half, blk = mask
+    return s // bq, half // bk, (half // bq, blk)
+
+
+def _kv_specs(causal, bq, bk, d, n_k, group, mask):
+    """The in_specs of k and v under grid (head, q block, k block), and of
+    the noised blocks that ride along under the block mask."""
+    n_qh = mask[0] if mask else None
+    kv_index = _kv_index(causal, bq, bk, n_k, group, n_qh)
+    specs = [pl.BlockSpec((1, bk, d), kv_index)] * 2
+    if mask:
+        own = _kv_index(causal, bq, bk, n_k, group, n_qh, own=True)
+        specs += [pl.BlockSpec((1, bk, d), own)] * 2
+    return specs
+
+
+def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1, mask=None):
     bh, s, d = q.shape
-    n_q, n_k = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk, n_k, group)
+    n_q, n_k, mask = _grid_of(s, bq, bk, mask)
+    kv = _kv_specs(causal, bq, bk, d, n_k, group, mask)
     # one k step: the running (m, l, acc) never leave the step's registers
     scratch = [] if n_k == 1 else [
         pltpu.VMEM((bq, 128), jnp.float32),
@@ -349,14 +541,10 @@ def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1):
     ]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask),
         name="flash_attention_fwd",
         grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
-            pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
-        ],
+        in_specs=[pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)), *kv],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, _0, j)),
@@ -368,62 +556,80 @@ def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(q, k, v)
+    )(q, k, v, *((k, v) if mask else ()))
     return out, lse
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *scratch,
-                    scale, causal, bq, bk, sq, sk, n_q, n_k, group=1):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs,
+                    scale, causal, bq, bk, sq, sk, n_q, n_k, group=1,
+                    mask=None):
+    # under the block mask the noised key block has gradients of its own
+    # (dkn, dvn): its keys are met by the noised rows of the same blocks alone
+    (kn_ref, vn_ref), (do_ref, lse_ref, delta_ref, *refs) = _own_refs(refs,
+                                                                      mask)
+    n_out = 4 if mask else 2
+    outs, scratch = refs[:n_out], refs[n_out:]
+    blk = mask[1] if mask else 1
     scale = np.float32(scale)
     if scratch:  # (dk, dv) carried from one q step to the next, and from one
         # query head of the group to the next: they come out summed over it
-        dk_scr, dv_scr = scratch
-
         @pl.when(pl.program_id(2) == 0)
         def _():
-            dk_scr[:] = jnp.zeros_like(dk_scr)
-            dv_scr[:] = jnp.zeros_like(dv_scr)
+            for scr in scratch:
+                scr[:] = jnp.zeros_like(scr)
 
     def products(strip):
         # scores with the keys down the sublanes, [keys, rows]: lse and delta
         # are rows as they lie in memory, and nothing is transposed
-        cols, r0, _ = strip
-        return (_mm(k_ref[0, cols, :], q_ref[0, r0:, :], _NT) * scale,
-                _mm(v_ref[0, cols, :], do_ref[0, r0:, :], _NT))
+        cols, rows, _, own = strip
+        k, v = (kn_ref, vn_ref) if own else (k_ref, v_ref)
+        return (_mm(k[0, cols, :], q_ref[0, rows, :], _NT) * scale,
+                _mm(v[0, cols, :], do_ref[0, rows, :], _NT))
 
-    def walk(off):
+    def walk(off, noised=False):
         times_do, times_q = _times(do_ref), _times(q_ref)
-        for (cols, r0, r_full), (st, dpt) in _ahead(
-                _k_strips(off, bq, bk, sq, sk), products):
-            rows = slice(r0, bq)
-            st = _mask_lanes(st, 0, r_full - r0, (off or 0) + r0, cols.start,
-                             keys_first=True)
+        for (cols, rows, masked, own), (st, dpt) in _ahead(
+                _k_strips(off, bq, bk, sq, sk, blk, noised), products):
+            if own:
+                st = _causal_mask(st, off + rows.start, cols.start,
+                                  keys_first=True, blk=blk, own=True)
+            else:
+                st = _mask_lanes(st, 0, masked, (off or 0) + rows.start,
+                                 cols.start, keys_first=True, blk=blk,
+                                 before=noised)
             pt = jnp.exp(st - lse_ref[0, :, rows])
             dst = pt * (dpt - delta_ref[0, :, rows])
             dv = times_do(pt.astype(do_ref.dtype), rows)
             dk = times_q((dst * scale).astype(q_ref.dtype), rows)
+            to = 2 if own else 0
             if scratch:
-                dk_scr[cols, :] += dk
-                dv_scr[cols, :] += dv
+                scratch[to][cols, :] += dk
+                scratch[to + 1][cols, :] += dv
             else:
-                dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
-                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+                outs[to][0, cols, :] = dk.astype(outs[to].dtype)
+                outs[to + 1][0, cols, :] = dv.astype(outs[to + 1].dtype)
 
-    _at_block_offset(n_q, n_k, 2, 1, bq, bk, causal, walk, q_wraps=group > 1)
+    if mask:
+        _at_stream_block(mask[0], n_k, 2, 1, bq, bk, walk, q_wraps=group > 1)
+    else:
+        _at_block_offset(n_q, n_k, 2, 1, bq, bk, causal, walk,
+                         q_wraps=group > 1)
 
     if scratch:
         @pl.when(pl.program_id(2) == group * n_q - 1)
         def _():
-            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+            for out, scr in zip(outs, scratch):
+                out[0] = scr[:].astype(out.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *scratch, scale, causal, bq, bk, sq, sk, n_q, n_k):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs,
+                   scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None):
+    (kn_ref, vn_ref), (do_ref, lse_ref, delta_ref, dq_ref, *scratch) = \
+        _own_refs(refs, mask)
+    blk = mask[1] if mask else 1
     scale = np.float32(scale)
     if scratch:  # dq carried from one k step to the next
         dq_scr, = scratch
@@ -433,24 +639,35 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def products(strip):
-        rows, keys, _ = strip
-        return (_mm(q_ref[0, rows, :], k_ref[0, :keys, :], _NT) * scale,
-                _mm(do_ref[0, rows, :], v_ref[0, :keys, :], _NT))
+        rows, keys, _, own = strip
+        s = [_mm(q_ref[0, rows, :], ref[0, cols, :], _NT) * scale
+             for ref, cols in _key_parts(k_ref, kn_ref, keys, own)]
+        dp = [_mm(do_ref[0, rows, :], ref[0, cols, :], _NT)
+              for ref, cols in _key_parts(v_ref, vn_ref, keys, own)]
+        return s, dp[0] if len(dp) == 1 else jnp.concatenate(dp, axis=1)
 
-    def walk(off):
+    def walk(off, noised=False):
         times_k = _times(k_ref)
-        for (rows, keys, masked), (s, dp) in _ahead(
-                _q_strips(off, bq, bk, sq, sk), products):
-            s = _mask_lanes(s, masked, keys, (off or 0) + rows.start, 0)
+        times_kn = _times(kn_ref) if noised else None
+        for (rows, keys, masked, own), (s, dp) in _ahead(
+                _q_strips(off, bq, bk, sq, sk, blk, noised), products):
+            s = _strip_mask(s, keys, masked, own, (off or 0) + rows.start,
+                            blk, noised)
             p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
             ds = p * (dp - delta_ref[0, 0, rows][:, None])
-            dq = times_k((ds * scale).astype(k_ref.dtype), slice(0, keys))
+            dq = _across((ds * scale).astype(k_ref.dtype),
+                         _key_parts(k_ref, kn_ref, keys, own),
+                         lambda x, ref, cols: (
+                             times_k if ref is k_ref else times_kn)(x, cols))
             if scratch:
                 dq_scr[rows, :] += dq
             else:
                 dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
 
-    _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
+    if mask:
+        _at_stream_block(mask[0], n_k, 1, 2, bq, bk, walk)
+    else:
+        _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
 
     if scratch:
         @pl.when(pl.program_id(2) == n_k - 1)
@@ -458,13 +675,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
+def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
+         mask=None):
     bh, s, d = k.shape  # the grid's heads are the KV heads
-    n_q, n_k = s // bq, s // bk
+    n_q, n_k, mask = _grid_of(s, bq, bk, mask)
 
     def q_index(kk, j):
         # a q block whose every row is above this k block's first column is
         # skipped: name the first block that runs instead, so nothing is copied
+        if mask:  # in each half of the stream alike
+            n_qh = np.int32(mask[0])
+            j_first, _ = _query_walk(kk * bk, bk, 0, bq, mask[0])
+            noised = jax.lax.div(j, n_qh)
+            return jnp.maximum(j - noised * n_qh, j_first) + noised * n_qh
         if causal and n_q > 1:
             j_first, _ = _query_walk(kk * bk, bk, 0, bq, n_q)
             j = jnp.maximum(j, j_first)
@@ -488,9 +711,12 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
     # one q step: dk and dv never leave the step's registers
     scratch = [] if group * n_q == 1 else [pltpu.VMEM((bk, d), jnp.float32),
                                            pltpu.VMEM((bk, d), jnp.float32)]
-    return pl.pallas_call(
+    own = [pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, n_k + kk, _0))] * 2
+    out_rows = n_k * bk  # under the block mask: a half's keys an output
+    got = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, group=group),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, group=group,
+                          mask=mask),
         name="flash_attention_bwd_dkv",
         grid=(bh, n_k, group * n_q),
         in_specs=[
@@ -498,6 +724,7 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
                          lambda i, kk, j: (q_head(i, j), q_block(kk, j), _0)),
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
+            *(own if mask else ()),
             pl.BlockSpec((1, bq, d),
                          lambda i, kk, j: (q_head(i, j), q_block(kk, j), _0)),
             pl.BlockSpec((1, 1, bq),
@@ -508,31 +735,34 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
             pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, kk, _0)),
-        ],
+        ] * (2 if mask else 1),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
-        ],
-        scratch_shapes=scratch,
+            jax.ShapeDtypeStruct((bh, out_rows, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, out_rows, d), v.dtype),
+        ] * (2 if mask else 1),
+        scratch_shapes=scratch * (2 if mask else 1),
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, *((k, v) if mask else ()), do, lse, delta)
+    if mask:  # (dk, dv, dkn, dvn): the halves side by side again
+        return (jnp.concatenate(got[0::2], axis=1),
+                jnp.concatenate(got[1::2], axis=1))
+    return got
 
 
-def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
+def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
+        mask=None):
     bh, s, d = q.shape
-    n_q, n_k = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk, n_k, group)
+    n_q, n_k, mask = _grid_of(s, bq, bk, mask)
     scratch = [] if n_k == 1 else [pltpu.VMEM((bq, d), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask),
         name="flash_attention_bwd_dq",
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
-            pl.BlockSpec((1, bk, d), kv_index),
-            pl.BlockSpec((1, bk, d), kv_index),
+            *_kv_specs(causal, bq, bk, d, n_k, group, mask),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, _0, j)),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, _0, j)),
@@ -542,14 +772,14 @@ def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1):
         scratch_shapes=scratch,
         compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, *((k, v) if mask else ()), do, lse, delta)
 
 
-def _bwd(scale, causal, bq, bk, sq, sk, group, res, do):
+def _bwd(scale, causal, bq, bk, sq, sk, group, mask, res, do):
     q, k, v, out, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None, :]
     sizes = dict(scale=scale, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-                 group=group)
+                 group=group, mask=mask)
     dk, dv = _dkv(q, k, v, do, lse, delta, **sizes)
     dq = _dq(q, k, v, do, lse, delta, **sizes)
     return dq, dk, dv
@@ -558,14 +788,14 @@ def _bwd(scale, causal, bq, bk, sq, sk, group, res, do):
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, scale, causal, bq, bk, sq, sk, group):
-    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, scale, causal, bq, bk, sq, sk, group, mask):
+    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk, group):
-    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group)
+def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask):
+    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask)
     return out, (q, k, v, out, lse)
 
 
@@ -590,9 +820,48 @@ def supports(seq_len: int, head_dim: int, block_q: int = None, block_k: int = 10
     )
 
 
-def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None, block_k=1024):
+def _mask_blocks(seq_len, block_mask, block_q=None, block_k=1024):
+    """(bq, bk, sq, sk) the two-stream block mask (half, blk) is walked
+    with, or the short reason why the kernels cannot take it: the halves in
+    whole blocks, a key block in whole q blocks, the mask's blocks a power
+    of two that divides the sub-tiles."""
+    half, blk = block_mask
+    if seq_len != 2 * half:
+        return "stream_is_not_two_halves"
+    bq = min(block_q or _default_block_q(half), half)
+    bk = min(block_k, half)
+    if half % bq or half % bk or bk % bq:
+        return "half_not_tiled"
+    sq, sk = _default_sub_tiles(bq, bk)
+    if blk < 1 or blk & (blk - 1) or sq % blk or sk % blk:
+        return "block_length"
+    return bq, bk, sq, sk
+
+
+def supports_block_mask(seq_len: int, head_dim: int, block_mask) -> bool:
+    """``supports`` for the two-stream block mask (half, blk)."""
+    return (not isinstance(_mask_blocks(seq_len, block_mask), str)
+            and block_mask[0] >= 8 and head_dim % 8 == 0)
+
+
+def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
+                    block_k=1024, block_mask=None):
     """Streaming attention over [batch, seq, heads, head_dim] inputs
     (paddle fused_attention layout, matching scaled_dot_product_attention).
+
+    ``block_mask`` = (half, blk), in the place of ``causal``: the two-stream
+    mask of block-diffusion training. The sequence is a stream of two halves
+    of ``half`` positions, the clean tokens and then the noised ones, each
+    cut into blocks of ``blk``. A clean position attends the clean positions
+    up to the end of its block; a noised position attends the clean blocks
+    before its own and the noised positions of its own block; no clean
+    position attends a noised one. The kernels walk the stream's q blocks
+    over the clean half's key blocks as the causal walk does, the diagonal
+    rounded to the mask's blocks (up to the block's end for clean rows, to
+    its start for noised ones); the noised key block that holds a q block's
+    own blocks rides along with the step the diagonal crosses and its
+    sub-tiles on the block diagonal join that step's strips. The quadrant
+    clean-on-noised is never fetched; no position-squared array exists.
 
     Grouped-query heads: k and v may have fewer heads than q, a divisor of
     q's; query head i attends KV head i // group through the kernels' index
@@ -612,29 +881,42 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None, block_k=1
             f"flash_attention: {h} query heads on k {tuple(k.shape)} / v "
             f"{tuple(v.shape)}: the KV heads must divide the query heads")
     group = h // h_kv
-    if block_q is None:
-        block_q = _default_block_q(s)
-    bq = min(block_q, s)
-    bk = min(block_k, s)
-    if s % bq != 0 or s % bk != 0:
-        raise ValueError(
-            f"flash_attention: seq_len {s} is not divisible by block sizes "
-            f"({bq}, {bk}) — tail rows would be left unwritten; pad the "
-            "sequence or use the dense path"
-        )
+    if block_mask is not None:
+        block_mask = tuple(map(int, block_mask))
+        blocks = _mask_blocks(s, block_mask, block_q, block_k)
+        if isinstance(blocks, str):
+            raise ValueError(
+                f"flash_attention: block mask {block_mask} on a stream of "
+                f"{s}: {blocks}; use the dense path")
+        (bq, bk, sq, sk), causal = blocks, True
+    else:
+        if block_q is None:
+            block_q = _default_block_q(s)
+        bq = min(block_q, s)
+        bk = min(block_k, s)
+        if s % bq != 0 or s % bk != 0:
+            raise ValueError(
+                f"flash_attention: seq_len {s} is not divisible by block "
+                f"sizes ({bq}, {bk}) — tail rows would be left unwritten; "
+                "pad the sequence or use the dense path"
+            )
+        sq, sk = _default_sub_tiles(bq, bk)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    sq, sk = _default_sub_tiles(bq, bk)
 
     from ...profiler import trace
-    run, masked, total = causal_tile_counts(s, bq, bk, sq, sk, bool(causal))
+    run, masked, total = causal_tile_counts(s, bq, bk, sq, sk, bool(causal),
+                                            block_mask)
+    kind = ({"mask": "block_diffusion", "half": block_mask[0],
+             "block": block_mask[1]} if block_mask
+            else {"mask": "causal" if causal else "full"})
     trace.emit("flash_tiles", site="flash_attention", seq=s, block_q=bq,
                block_k=bk, sub_q=sq, sub_k=sk, run=run, masked=masked,
-               total=total)
+               total=total, **kind)
 
     def to_bh(x):
         return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], s, d)
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), np.float32(scale), bool(causal),
-                 bq, bk, sq, sk, group)
+                 bq, bk, sq, sk, group, block_mask)
     return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)

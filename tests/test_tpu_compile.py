@@ -447,3 +447,96 @@ def test_granite_hybrid_train_step_compiles_for_v5e(one_chip,
     assert total < 14.0 * 2**30, mem
     assert sum(int(np.prod(p.shape)) for p in step._params) == 1_221_088_944
     assert len(step._params) == 157
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion sparse decoder at the shapes of the cell
+# sdar-train-s8192 (1 x 8,192 clean tokens = 16,384 stream positions;
+# benchmark/configs/sdar-*)
+# ---------------------------------------------------------------------------
+def test_block_mask_flash_compiles_for_v5e(one_chip, compiled_kernels):
+    """32 query heads on 4 KV heads of 128 over a stream of 2 x 8,192 under
+    the two-stream block mask, block length 4, forward and backward: the
+    three kernels, k and v never copied out to the group or to a half, no
+    array of positions squared."""
+    q = _sds((1, 16384, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, block_mask=(8192, 4)).astype(
+                jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(fwd_bwd).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert name in text
+    assert "16384,16384]" not in text
+    assert "bf16[1,16384,32,128]{3,2,1,0} broadcast" not in text
+    # q, o, do, dq, lse and delta, and the halves of dk and dv joined
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+
+
+def test_sdar_moe_train_step_compiles_for_v5e(one_chip, compiled_kernels,
+                                              monkeypatch):
+    """The whole compile_train_step program of the cell, built from the
+    cell's own configuration and traffic files: published widths, six
+    layers, 16 of 128 experts and no shared expert, an eighth of the
+    vocabulary, AMP O2 bf16, AdamW, 1 x 8,192 clean tokens as a stream of
+    16,384 under the block mask. It fits the chip (under 15.75 GB) and holds
+    the flash kernels under the attention's scope, the noise's scope and the
+    grouped products: no dense attention, no [positions, positions] array.
+    (PERF.md section 4 has the memory it reads.)"""
+    import json
+
+    import paddle_tpu.nn.initializer as I
+    from benchmark.lib import program_sdar_moe as prog
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models import BlockDiffusionCriterion
+
+    # shapes are all that matter here: skip drawing a billion normals
+    monkeypatch.setattr(I.Normal, "_generate",
+                        lambda self, shape, dtype: jnp.zeros(shape, dtype))
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(here, "configs",
+                           "sdar-30b-a3b-chat-ep8.json")) as f:
+        sizes = json.load(f)
+    with open(os.path.join(here, "workloads", "sdar-train-s8192.json")) as f:
+        mix = json.load(f)["traffic"]
+    assert (sizes["num_hidden_layers"], mix["batch"], mix["seq"]) == (
+        6, 1, 8192)
+    _, model = prog.build_model(sizes, mix["block_length"])
+    model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    crit = BlockDiffusionCriterion()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, tg: crit(lg.astype("float32"), tg), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((1, 8192), jnp.int32, one_chip)
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), _random.next_key(),
+            jnp.asarray(1e-4, jnp.float32), ids, ids,
+            _sds((1, 8192, 2), jnp.float32, one_chip))
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    compiled = step._build().lower(*specs).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq", "block_diffusion_attention",
+                 "diffusion_noise", "ragged-dot"):
+        assert name in text, name
+    assert "16384,16384]" not in text
+    assert "shared_expert" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print("sdar step bytes", total, mem)
+    assert total < 15.75e9, mem
+    assert sum(int(np.prod(p.shape)) for p in step._params) == 645_623_296
+    assert len(step._params) == 69
