@@ -12,10 +12,11 @@ share of an expert-parallel deployment), routes over all of them, gathers the
 slots that fall on held experts sorted by expert, multiplies them as grouped
 matrix products (``jax.lax.ragged_dot``, a Mosaic kernel on the TPU) over
 stacked ``[held, ...]`` leaves with the slot weight applied on the narrow
-activation between them, and scatter-adds the down product's float32 rows
-into the tokens: between ops the rows lie in the tokens' dtype, and nothing
-of the buffer's length and the model's width is written but the two
-products' own results (forward; backward likewise). No slot is
+activation between them, and adds the down product's float32 rows into the
+tokens with a kernel of its own (``ops/pallas/moe_combine.py``): between ops
+the rows lie in the tokens' dtype, and nothing of the buffer's length and
+the model's width is written but the two products' own results (forward;
+backward likewise). No slot is
 dropped whatever the imbalance, and every shape is static: the sorted slots
 go through a row buffer of fixed size in as many passes as the routed load
 needs (one, unless routing is badly skewed). On one chip it runs without its
@@ -402,9 +403,9 @@ def _swiglu(gate, up):
 
 def _pass_rows(c, rows, tok, wgt, offsets, tokens):
     """Pass c of the row buffer: its slots' tokens, the same with the rows
-    that hold no routed slot sent past the last token (where a scatter drops
-    them), its slots' weights, which rows hold a routed slot, and each held
-    expert's rows inside it."""
+    that hold no routed slot sent past the last token (where the combine
+    drops them), its slots' weights, which rows hold a routed slot, and each
+    held expert's rows inside it."""
     lo = c * np.int32(rows)
     valid = lo + jnp.arange(rows, dtype=jnp.int32) < offsets[-1]
     sizes = (jnp.clip(offsets[1:], lo, lo + rows)
@@ -420,6 +421,37 @@ def _n_passes(offsets, rows):
                        np.int32(1))
 
 
+def _combine_plan(site, rows, tokens, lanes):
+    """The token block the combine kernel takes a pass's float32 [rows,
+    lanes] result into ``tokens`` tokens with, or None where the shape keeps
+    XLA's scatter-add; one ``moe_combine`` event a trace and direction says
+    which, and why."""
+    from ..ops.pallas import moe_combine as mc
+    from ..profiler import trace
+
+    block, why = mc.plan(tokens, lanes)
+    trace.emit("moe_combine", site=site, path="xla" if why else "kernel",
+               rows=rows, tokens=tokens, lanes=lanes, token_block=block or 0,
+               **({"why": why} if why else {}))
+    return block
+
+
+def _combine(part, kept, total, tokens, block):
+    """``total`` (zeros on the first pass, where it is None) plus each
+    float32 row of ``part`` added into the token ``kept`` names; a row that
+    ``kept`` sends to ``tokens`` is never read. A token's rows are added in
+    ascending row index on both paths, so the kernel's sums are the
+    scatter-add's bit for bit."""
+    if block is None:
+        if total is None:
+            total = jnp.zeros((tokens, part.shape[1]), jnp.float32)
+        return total.at[kept].add(part, mode="drop")
+    from ..ops.pallas import moe_combine as mc
+
+    return mc.moe_combine(part, *mc.token_order(kept, tokens, block), total,
+                          block=block)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     """sum over a token's slots on held experts of weight * expert(x): x
@@ -430,24 +462,29 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     while loop and the backward (which walks the same passes, recomputing
     each pass's activations) is written out, not derived.
 
-    What a pass writes to HBM between its gather and its scatter-add: the
+    What a pass writes to HBM between its gather and its combine: the
     gathered rows (x's dtype), the float32 [rows, 2 d] gate-up product, the
     weighted activation (x's dtype, [rows, d]) and the down product's float32
-    [rows, h] result, which the scatter-add reads as it is. The slot weight
-    and the mask sit on the d-wide activation, never on a [rows, h] array.
-    The mask is a select there AND an index: the grouped product skips the
-    tiles past the groups and leaves what was in memory, so those rows of
-    its result are not zero whatever it was given; they leave by the index
-    (``mode="drop"``), not by a multiplication."""
+    [rows, h] result, which the combine reads as it is. The slot weight and
+    the mask sit on the d-wide activation, never on a [rows, h] array. The
+    mask is a select there AND an index: the grouped product skips the tiles
+    past the groups and leaves what was in memory, so those rows of its
+    result are not zero whatever it was given; the combine never reads them
+    (``_combine``), and nothing multiplies them. The first pass, which always
+    runs, is made before the loop: its combine writes every token, so no
+    zeros are written first."""
+    tokens = x.shape[0]
+    block = _combine_plan("forward", rows, tokens, x.shape[1])
+
     def one_pass(c, y):
         t, kept, w, valid, sizes, _ = _pass_rows(
-            c, rows, tok, wgt, offsets, x.shape[0])
+            c, rows, tok, wgt, offsets, tokens)
         gate, up = _swiglu_groups(x[t], w_gate_up, sizes)
         act = jnp.where(valid, _swiglu(gate, up) * w, 0.0).astype(x.dtype)
-        return y.at[kept].add(_grouped(act, w_down, sizes), mode="drop")
+        return _combine(_grouped(act, w_down, sizes), kept, y, tokens, block)
 
-    y = jax.lax.fori_loop(np.int32(0), _n_passes(offsets, rows), one_pass,
-                          jnp.zeros(x.shape, jnp.float32))
+    y = jax.lax.fori_loop(np.int32(1), _n_passes(offsets, rows), one_pass,
+                          one_pass(np.int32(0), None))
     return y.astype(x.dtype)
 
 
@@ -468,8 +505,16 @@ def _held_bwd(rows, res, dy):
     where the SwiGLU's derivative is applied, for the two products that read
     it. Each stack's gradient is written by its product in the stack's dtype
     on the first pass, which always runs; a later pass adds to it in
-    float32."""
+    float32. Unlike the forward's, the first pass stays inside the loop
+    (from zeros): made before it, the compiled step keeps more live (PERF.md
+    section 6, PR 35)."""
     x, wgt, w_gate_up, w_down, tok, offsets = res
+    # measured, not derived: behind the barrier the compiled steps of the
+    # three sparse cells keep 28-671 MB less than their parents' did;
+    # without it the SDAR cell's keeps 106 MB more (AOT compile, PR 35)
+    x = jax.lax.optimization_barrier(x)
+    tokens = x.shape[0]
+    block = _combine_plan("backward", rows, tokens, x.shape[1])
 
     def summed(c, total, part):
         return jax.lax.cond(
@@ -479,7 +524,7 @@ def _held_bwd(rows, res, dy):
     def one_pass(c, carry):
         dx, dwgt, dgu, dd = carry
         t, kept, w, valid, sizes, lo = _pass_rows(
-            c, rows, tok, wgt, offsets, x.shape[0])
+            c, rows, tok, wgt, offsets, tokens)
         xin, dyt = x[t], dy[t]
         gate, up = _swiglu_groups(xin, w_gate_up, sizes)
         s, pull = jax.vjp(_swiglu, gate, up)
@@ -491,7 +536,7 @@ def _held_bwd(rows, res, dy):
         dgu_c = _grouped_outer(xin, dh, sizes, dgu.dtype)
         dd_c = _grouped_outer(act, dyt, sizes, dd.dtype)
         dw = jnp.where(valid[:, 0], (s * g).sum(-1), 0.0)
-        return (dx.at[kept].add(dxin, mode="drop"),
+        return (_combine(dxin, kept, dx, tokens, block),
                 jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
                 summed(c, dgu, dgu_c), summed(c, dd, dd_c))
 

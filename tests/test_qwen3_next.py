@@ -516,21 +516,24 @@ def garbage_past_the_groups(monkeypatch):
     monkeypatch.setattr(moe, "_grouped", grouped)
 
 
+@pytest.mark.parametrize("h", [32, 128], ids=["xla_combine", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("passes", [1, 3])
 def test_output_and_every_gradient_in_one_pass_and_in_three(
-        garbage_past_the_groups, dtype, passes):
+        garbage_past_the_groups, dtype, passes, h):
     """The layer's output and the gradient of x, of the router (which gets
     it through the slot weights), of both stacks and of the shared expert
     against the reference on the same numbers, within one rounding of the
     dtype: the slot weight's gradient from the d-wide cotangent, the stacks'
-    gradients written by the first pass and added to by the later two."""
+    gradients written by the first pass and added to by the later two. At a
+    width of 128 lanes the combine is the kernel (interpreted), at 32 XLA's
+    scatter-add."""
     held, top_k, tokens, first = 4, 4, 64, 4
-    w = {k: a.astype(dtype) for k, a in expert_weights().items()}
+    w = {k: a.astype(dtype) for k, a in expert_weights(h=h).items()}
     rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.standard_normal((tokens, 32)), dtype)
-    ct = jnp.asarray(rng.standard_normal((tokens, 32)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((tokens, h)), dtype)
+    ct = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
     sizes = dict(num_experts_per_tok=top_k, num_experts=held)
     routed = int(program_experts(x, w, first, top_k, 256)[1])
     rows = -(-routed // (8 * passes)) * 8
@@ -559,15 +562,16 @@ def test_output_and_every_gradient_in_one_pass_and_in_three(
         assert_close(a, c, dtype, name, roundings=1 if routed_alone else 2)
 
 
+@pytest.mark.parametrize("h", [32, 128], ids=["xla_combine", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_no_slot_held_gives_exact_zeros(garbage_past_the_groups, dtype):
+def test_no_slot_held_gives_exact_zeros(garbage_past_the_groups, dtype, h):
     """With no slot on a held expert every row of the buffer lies past the
     groups: the routed part and all four of its gradients are exactly zero,
     whatever the products left in those rows."""
-    w = {k: a.astype(dtype) for k, a in expert_weights().items()}
+    w = {k: a.astype(dtype) for k, a in expert_weights(h=h).items()}
     rng = np.random.default_rng(13)
-    x = jnp.asarray(rng.standard_normal((64, 32)), dtype)
+    x = jnp.asarray(rng.standard_normal((64, h)), dtype)
     tok = jnp.asarray(rng.integers(0, 64, 96), jnp.int32)
     wgt = jnp.asarray(rng.random(96), jnp.float32)
     offsets = jnp.zeros(5, jnp.int32)

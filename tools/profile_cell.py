@@ -7,10 +7,10 @@ Builds the cell's program as ``benchmark/run.py`` does (its driver's
 ``--steps`` more under ``paddle.profiler.Profiler`` and prints
 ``summary(layer_depth=--depth)``: device seconds by section, by layer path
 (``layers.*/mixer/short_conv`` at depth 3) and by named kernel, then the
-``mixer_pass``, ``gdn_chunks``, ``ssd_chunks`` and ``mixer_share`` events the
-trace left. Chip only for times
-(through the builder's chip tool); ``--rehearse`` drives the same flow at the
-cell's rehearsal size on the CPU. Scope names are part of the compile cache's
+``mixer_pass``, ``gdn_chunks``, ``ssd_chunks``, ``mixer_share`` and
+``moe_combine`` events the trace left. Chip only for times (through the
+builder's chip tool); ``--rehearse`` drives the same flow at the cell's
+rehearsal size on the CPU. Scope names are part of the compile cache's
 key here, so a renamed scope shows at once.
 """
 import argparse
@@ -58,7 +58,8 @@ def main():
     with paddle.profiler.Profiler() as prof:
         steps(3, a.steps)
     prof.summary(layer_depth=a.depth)
-    for kind in ("mixer_pass", "gdn_chunks", "ssd_chunks", "mixer_share"):
+    for kind in ("mixer_pass", "gdn_chunks", "ssd_chunks", "mixer_share",
+                 "moe_combine"):
         for event in paddle.profiler.trace.events(kind=kind):
             print(kind, event.site, event.attrs)
 
