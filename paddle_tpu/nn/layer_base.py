@@ -16,6 +16,7 @@ import numpy as np
 from ..core.dispatch import no_grad
 from ..core.dtype import get_default_dtype
 from ..core.tensor import Tensor
+from ..profiler import trace as _trace
 
 
 class HookRemoveHelper:
@@ -111,8 +112,11 @@ class Layer:
             init = g
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
-        value = init._generate(tuple(int(s) for s in shape), dtype)
-        return Parameter(value, trainable=trainable, name=name)
+        shape = tuple(int(s) for s in shape)
+        # a span a leaf: the programs its initializer builds are sited here
+        with _trace.span("create_parameter", shape=shape, dtype=str(dtype)):
+            return Parameter(init._generate(shape, dtype),
+                             trainable=trainable, name=name)
 
     def add_parameter(self, name, parameter):
         self._parameters[name] = parameter

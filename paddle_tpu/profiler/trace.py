@@ -9,11 +9,16 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
                    (op/segment/backward/optimizer/captured/compiled; the
                    last is one ``compile_train_step`` call)
   span             one closed host span (``span`` / ``RecordEvent``): name,
-                   start on the profiler's clock, duration, id, parent span
-  compile /        a program built (seconds: the backend compile, or the
-  cache_hit        fetch when a cache_hit came just before it) and a
-                   persistent-cache hit, from ``jax.monitoring``, sited at
-                   the span open around them
+                   start on the profiler's clock, duration, id, parent span;
+                   ``compile_train_step`` and its children each step,
+                   ``create_parameter`` round each leaf a layer makes
+  compile          one program built, from ``jax.monitoring``, sited at the
+                   span open around it: its outermost trace, lowering and
+                   compile-or-fetch seconds, whether the persistent cache
+                   held it, the fetch's seconds, jax's name for it and when
+                   its trace began (jax reports one trace event per nested
+                   jit as well: they are folded into the program's record,
+                   never kept one by one)
   flash_tiles      the flash attention kernels were built for a shape: how
                    many sub-tiles of a head's score square the causal walk
                    runs, masks and skips (trace time, once per compile)
@@ -306,28 +311,61 @@ class span:
 
 
 # ---------------------------------------------------------------------------
-# Which step compiled: jax.jit compiles inside the first launch, so without
-# these a recompile is only a long span. Counted in dispatch_counters() too.
-# jax times compile-or-fetch as one event: a `compile` that a `cache_hit`
-# precedes was a fetch from the persistent cache, and its seconds the fetch's.
+# Which step compiled, and what building it cost: jax.jit traces, lowers and
+# compiles (or fetches from the persistent cache) inside the first launch, so
+# without these a first step is only a long span. jax reports each phase as
+# its own event, and a trace event for EVERY jit nested in the one traced
+# (1,850 of them for the 10 programs a tiny GPT's first step builds), so the
+# phases wait per thread (the lazy tier compiles on a background thread)
+# until the backend compile's event closes the program: one `compile` event
+# a program. Nested traces finish inside the outer one, so the program's
+# trace is the LONGEST since the last program built, never their sum.
 # ---------------------------------------------------------------------------
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_FETCH = "/jax/compilation_cache/cache_retrieval_time_sec"
+_HIT = "/jax/compilation_cache/cache_hits"
+_BUILT = "/jax/core/compile/backend_compile_duration"
+_pending = threading.local()  # this thread's phases since its last program
+
+
 def _on_compile_event(name, *args, **kw):
-    if name == "/jax/core/compile/backend_compile_duration":
-        kind, attrs = "compile", {"seconds": args[0]}
-        counts = (("backend_compiles", 1), ("backend_compile_s", args[0]))
-    elif name == "/jax/compilation_cache/cache_hits":
-        kind, attrs, counts = "cache_hit", {}, (("compile_cache_hits", 1),)
-    else:
+    if name == _TRACE:
+        if args[0] > getattr(_pending, "trace_s", 0.0):
+            _pending.trace_s = args[0]
+        return
+    if name == _LOWER:
+        _pending.lower_s = args[0]
+        return
+    if name == _FETCH:
+        _pending.fetch_s = args[0]
+        return
+    if name != _HIT and name != _BUILT:
         return
     from ..core import dispatch
 
-    for key, n in counts:  # a background compile thread may be the caller
-        dispatch._counter_add(key, n)
+    if name == _HIT:
+        _pending.cache_hit = True
+        dispatch._counter_add("compile_cache_hits", 1)
+        return
+    seconds = args[0]
+    phases = _pending.__dict__
+    trace_s = phases.pop("trace_s", 0.0)
+    lower_s = phases.pop("lower_s", 0.0)
+    attrs = {"seconds": seconds, "trace_s": trace_s, "lower_s": lower_s,
+             "fetch_s": phases.pop("fetch_s", 0.0),
+             "cache_hit": phases.pop("cache_hit", False),
+             "fun": kw.get("fun_name", ""),
+             "start_ns": time.time_ns() - round(
+                 (trace_s + lower_s + seconds) * 1e9)}
+    # a background compile thread may be the caller
+    dispatch._counter_add("backend_compiles", 1)
+    dispatch._counter_add("backend_compile_s", seconds)
     stack = _open_spans()
     if stack:
-        emit(kind, site=stack[-1].name, span=stack[-1].id, **attrs)
+        emit("compile", site=stack[-1].name, span=stack[-1].id, **attrs)
     else:
-        emit(kind, **attrs)
+        emit("compile", **attrs)
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_compile_event)

@@ -4,6 +4,7 @@ in the flight recorder's ring, the `compiled` launch counter, the compile
 listener, and Profiler.summary()'s device view. CPU, tiny GPT."""
 import glob
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -46,17 +47,28 @@ def traced_run(tmp_path_factory):
     planes, the ring's events, and the step."""
     model, step, x, y = tiny_trainer()
     d = str(tmp_path_factory.mktemp("trace"))
+    traces = []  # (end on time.time_ns(), seconds) of every jax trace event
+
+    def on_trace(name, secs, **kw):
+        if name == "/jax/core/compile/jaxpr_trace_duration":
+            traces.append((time.time_ns(), secs))
+
     trace.clear()
     dispatch.reset_dispatch_counters()
+    jax.monitoring.register_event_duration_secs_listener(on_trace)
     jax.profiler.start_trace(d)
-    for _ in range(3):
-        loss = step(x, y)
-    loss._value.block_until_ready()
-    jax.profiler.stop_trace()
+    try:
+        for _ in range(3):
+            loss = step(x, y)
+        loss._value.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        jax.monitoring.unregister_event_duration_listener(on_trace)
     (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*",
                                      "*.xplane.pb"))
     return {"path": path, "events": trace.events(), "step": step,
-            "counters": dict(dispatch.dispatch_counters()), "model": model}
+            "counters": dict(dispatch.dispatch_counters()), "model": model,
+            "traces": traces}
 
 
 def test_layers_are_scoped_by_their_registered_names():
@@ -208,6 +220,109 @@ def test_compile_event_names_the_step_that_compiled(traced_run):
     assert not [e for e in compiles if e.attrs.get("span") in later]
     assert traced_run["counters"]["backend_compiles"] == len(compiles)
     assert traced_run["counters"]["backend_compile_s"] > 0
+
+
+def first_launch(events):
+    root = next(e for e in events
+                if e.kind == "span" and e.site == ROOT and e.step == 0)
+    return next(e.attrs for e in events if e.kind == "span"
+                and e.site == LAUNCH and e.attrs["parent"] == root.attrs["id"])
+
+
+def test_step_program_carries_its_trace_lowering_and_compile(traced_run):
+    """One `compile` event for the step's program, sited in root 0's
+    `/launch`, its three phases laid inside that span on its clock."""
+    events = traced_run["events"]
+    launch = first_launch(events)
+    (built,) = [e.attrs for e in events if e.kind == "compile"
+                and e.attrs["span"] == launch["id"]]
+    assert "step_fn" in built["fun"]
+    assert built["trace_s"] > 0 and built["lower_s"] > 0
+    assert built["seconds"] > 0
+    assert built["cache_hit"] is False and built["fetch_s"] == 0
+    end = launch["start_ns"] + launch["dur_ns"]
+    phases = (built["trace_s"] + built["lower_s"] + built["seconds"]) * 1e9
+    assert launch["start_ns"] <= built["start_ns"]
+    assert built["start_ns"] + phases <= end + 1e6  # two clocks: 1 ms
+    # the outermost trace: every jit nested in the step finished inside it
+    nested = [s for t, s in traced_run["traces"]
+              if launch["start_ns"] <= t <= end]
+    assert len(nested) > 1
+    assert built["trace_s"] >= max(nested)
+    assert built["trace_s"] <= launch["dur_ns"] / 1e9
+
+
+@pytest.mark.parametrize("field", ["trace_s", "lower_s", "fetch_s",
+                                   "cache_hit", "fun", "start_ns"])
+def test_every_compile_event_carries_the_phases(traced_run, field):
+    compiles = [e for e in traced_run["events"] if e.kind == "compile"]
+    assert len(compiles) == traced_run["counters"]["backend_compiles"]
+    assert all(field in e.attrs for e in compiles)
+    # steps 1 and 2 build nothing
+    roots = {e.attrs["id"]: e.step for e in traced_run["events"]
+             if e.kind == "span" and e.site == ROOT}
+    parent = {e.attrs["id"]: e.attrs["parent"] for e in traced_run["events"]
+              if e.kind == "span"}
+    for e in compiles:
+        span = e.attrs.get("span")
+        while span is not None and span not in roots:
+            span = parent.get(span)
+        assert span is None or roots[span] == 0
+
+
+def test_create_parameter_is_a_span_per_leaf():
+    trace.clear()
+    cfg = GPTConfig(vocab_size=256, hidden_size=32, num_layers=2, num_heads=2,
+                    max_seq_len=64, dropout=0.0, attn_dropout=0.0)
+    model = GPTForPretraining(cfg)
+    events = trace.events()
+    spans = [e.attrs for e in events
+             if e.kind == "span" and e.site == "create_parameter"]
+    params = model.parameters()
+    assert len(spans) == len(params)
+    assert [tuple(s["shape"]) for s in spans] == \
+        [tuple(p.shape) for p in params]
+    assert all(s["dtype"] == "float32" and s["parent"] is None for s in spans)
+    # the programs the initializers build are sited under the leaf's span
+    ids = {s["id"] for s in spans}
+    built = [e for e in events if e.kind == "compile"]
+    assert all(e.site == "create_parameter" and e.attrs["span"] in ids
+               for e in built)
+
+
+def test_a_persistent_cache_fetch_is_a_field_of_its_compile(tmp_path):
+    """The retired `cache_hit` ring kind: a fetch is the `compile` event's
+    `cache_hit`, with the retrieval's seconds; the counter still counts."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    for k, v in zip(keys, (str(tmp_path), True, 0, 0)):
+        jax.config.update(k, v)
+    cc.reset_cache()
+    try:
+        def build():
+            def probe(x):
+                return jnp.sin(x) * 3.0 + 1.0
+            return jax.jit(probe)
+
+        x = jnp.ones((7, 5), jnp.float32)
+        hits = dispatch.dispatch_counters()["compile_cache_hits"]
+        trace.clear()
+        for _ in range(2):  # a new jit each time: no in-memory cache
+            build()(x).block_until_ready()
+        miss, hit = [e.attrs for e in trace.events(kind="compile")]
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert (miss["cache_hit"], hit["cache_hit"]) == (False, True)
+    assert miss["fetch_s"] == 0 and hit["fetch_s"] > 0
+    assert "probe" in hit["fun"]
+    assert dispatch.dispatch_counters()["compile_cache_hits"] == hits + 1
+    assert not [e for e in trace.events() if e.kind == "cache_hit"]
 
 
 def test_compiled_launch_is_counted(traced_run):
