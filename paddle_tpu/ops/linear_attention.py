@@ -19,9 +19,13 @@ Everything a chunk needs is made in VMEM from the chunk's own rows of q, k,
 v, g, beta, by three Pallas kernels that read the heads where the layer left
 them ([batch, seq, heads * d]: a head is a block of lanes; key head
 ``value head // rep`` by the index map) and walk a grid of (batch x key
-heads, blocks of chunks, the value heads a key head serves). The rows of q
-and k are taken to unit length there too (and q scaled by d_k^-0.5): done
-by XLA, each norm went through HBM as a float32 array of the rows' size.
+heads, blocks of chunks). A grid step holds a key head and the ``rep`` value
+heads it serves: their state chains are independent, so each head's
+products are written beside the others' for the scheduler to overlap, and
+what depends on the key head alone (the unit rows of q and k, Q K^T, K K^T)
+is made once a chunk. The rows of q and k are taken to unit length there
+too (and q scaled by d_k^-0.5): done by XLA, each norm went through HBM as
+a float32 array of the rows' size.
 
 ``gated_delta_rule_fwd_inverse``  T = (I + A)^-1 of every chunk, by doubling,
     the chunks of a grid step level by level together (the products of one
@@ -35,8 +39,8 @@ by XLA, each norm went through HBM as a float32 array of the rows' size.
     chunk's operands again from T, carries the cotangents through them (dT
     from dw and du0, dA = -T^T dT T^T, the decay table's to dg by the
     reversed running sum of row sums minus column sums) and writes dq, dk
-    (summed over the value heads of a key head, then through the norm), dv,
-    dg, dbeta.
+    (summed over the value heads of a key head inside the grid step, head 0
+    first, then through the norm), dv, dg, dbeta.
 
 Round the rule, two passes over rows, each as a forward and a backward
 kernel on (rows, 128 x n) lane blocks of [batch, seq, lanes], read where the
@@ -87,6 +91,10 @@ from ..incubate.recompute import KEEP_NAME
 F32 = jnp.float32
 L2_EPSILON = 1e-6  # under the root of a q or k row's norm
 STEP_ROWS = 512  # rows of a sequence one grid step of the kernels holds
+# what a grid step makes of its value heads, at most: 8 heads of 128 at 512
+# rows and chunk 64 fit the kernels' 16 MiB of scoped VMEM, 16 do not (AOT
+# compiles for a v5e)
+STEP_BYTES = 4 << 20
 _0 = np.int32(0)  # index-map literal; Python ints trace to i64 under x64
 
 
@@ -768,12 +776,18 @@ class _Operands(NamedTuple):
     kd: jax.Array    # [C, dk] exp(last - gam) K
 
 
-def _chunk_operands(q, k, v, t, gt):
+def _chunk_keys(q, k):
+    """What the value heads of a key head share in a chunk: its rows of q
+    (scaled by d_k^-0.5) and k at unit length, and Q K^T in float32."""
+    q, k = _unit_rows(q, q.shape[-1] ** -0.5), _unit_rows(k)
+    return q, k, _mm(q, k, _NT)
+
+
+def _chunk_operands(q, k, qk, v, t, gt):
     cdt = v.dtype
     e, el = jnp.exp(gt.gam), jnp.exp(gt.last - gt.gam)
     kf = k.astype(F32)
     kb = (kf * (gt.beta * e)).astype(cdt)
-    qk = _mm(q, k, _NT)
     return _Operands(
         e, el, kb, (v.astype(F32) * gt.beta).astype(cdt),
         _mm(t, kb).astype(cdt), qk, (qk * gt.decay).astype(cdt),
@@ -786,149 +800,207 @@ def _chunk_operands(q, k, v, t, gt):
 # A kernel holds a grid step's chunks unrolled, so that the scheduler can lay
 # one chunk's preparation under another's pass; what a chunk does is a jitted
 # function of values, traced once and not once a chunk (a step's trace is
-# part of every run's set-up).
+# part of every run's set-up). A grid step holds a key head and the value
+# heads it serves: what depends on the key head alone is made once a chunk,
+# and the heads' products are written stage by stage side by side, so that
+# each head's chain of products that carry its state has the others' work to
+# overlap with (the scheduler follows source order).
 @jax.jit
 def _chunk_inverses(k, g_rows, beta_rows):
-    """T [m C, C] in k's type of the m chunks of a grid step, from their
-    rows of k [m C, dk] and their gates [m, C]."""
-    m, c = g_rows.shape
-    gates = [_chunk_gates(g_rows[i:i + 1], beta_rows[i:i + 1])
-             for i in range(m)]
+    """T [m C, C] in k's type of the m chunks of a grid step, one for each
+    value head, from the rows of k [m C, dk] and each head's gates [m, C]."""
+    m, c = g_rows[0].shape
     rows = [_unit_rows(k[i * c:(i + 1) * c]) for i in range(m)]
-    many = [_strict(gt, gt.beta * gt.decay * _mm(ki, ki, _NT))
-            for gt, ki in zip(gates, rows)]
-    return jnp.concatenate(
-        _unit_lower_inverses(many, gates[0].eye, k.dtype),
-        axis=0).astype(k.dtype)
+    kks = [_mm(ki, ki, _NT) for ki in rows]
+    gates = [_chunk_gates(g[i:i + 1], b[i:i + 1])
+             for g, b in zip(g_rows, beta_rows) for i in range(m)]
+    many = [_strict(gt, gt.beta * gt.decay * kk)
+            for gt, kk in zip(gates, kks * len(g_rows))]
+    xs = _unit_lower_inverses(many, gates[0].eye, k.dtype)
+    return [jnp.concatenate(xs[h:h + m], axis=0).astype(k.dtype)
+            for h in range(0, len(xs), m)]
 
 
 @jax.jit
-def _chunk_forward(q, k, v, t, g_row, beta_row, s):
+def _chunk_forward(q, k, vs, ts, g_rows, beta_rows, ss):
     """(o, U, the state as the products see it, the next chunk's state) of
-    one chunk that starts from the float32 state ``s``."""
-    cdt = v.dtype
-    gt = _chunk_gates(g_row, beta_row)
-    op = _chunk_operands(_unit_rows(q, q.shape[-1] ** -0.5), _unit_rows(k),
-                         v, t, gt)
-    sb = s.astype(cdt)
-    u0 = _mm(t, op.vb).astype(cdt)
-    ub = (u0.astype(F32) - _mm(op.w, sb)).astype(cdt)
-    o = _mm(op.qg, sb) + _mm(op.attn, ub)
-    return (o.astype(cdt), ub, sb,
-            s * jnp.exp(gt.last) + _mm(op.kd, ub, _TN))
+    one chunk, a list of each over the value heads, each head starting from
+    its float32 state in ``ss``."""
+    cdt = vs[0].dtype
+    q, k, qk = _chunk_keys(q, k)
+    gts = [_chunk_gates(g, b) for g, b in zip(g_rows, beta_rows)]
+    ops = [_chunk_operands(q, k, qk, v, t, gt)
+           for v, t, gt in zip(vs, ts, gts)]
+    sbs = [s.astype(cdt) for s in ss]
+    u0s = [_mm(t, op.vb).astype(cdt) for t, op in zip(ts, ops)]
+    ubs = [(u0.astype(F32) - _mm(op.w, sb)).astype(cdt)
+           for u0, op, sb in zip(u0s, ops, sbs)]
+    # the next states first: the chain to the next chunk goes through them
+    nexts = [s * jnp.exp(gt.last) + _mm(op.kd, ub, _TN)
+             for s, gt, op, ub in zip(ss, gts, ops, ubs)]
+    os = [(_mm(op.qg, sb) + _mm(op.attn, ub)).astype(cdt)
+          for op, sb, ub in zip(ops, sbs, ubs)]
+    return os, ubs, sbs, nexts
 
 
-@jax.jit
-def _chunk_backward(q, k, v, t, g_row, beta_row, s0, ub, dob, ds):
-    """One chunk's cotangents from d loss / d o (``dob``) and d loss / d the
-    state the NEXT chunk starts from (``ds``, float32): (dq, dk of the
-    normalised rows, float32; dv; dg and dbeta as [1, C] rows; d loss / d
-    the state this chunk starts from)."""
-    def rowsum(x):
-        return jnp.sum(x, axis=1, keepdims=True)
+def _rowsum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
 
-    cdt = v.dtype
-    q, k = _unit_rows(q, q.shape[-1] ** -0.5), _unit_rows(k)
-    gt = _chunk_gates(g_row, beta_row)
-    op = _chunk_operands(q, k, v, t, gt)
-    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
+
+class _ThroughState(NamedTuple):
+    """A head's cotangents through the four products that carry the state."""
+    dub: jax.Array    # of U, the products' type
+    dwb: jax.Array    # of w, the products' type
+    dattn: jax.Array  # of the masked scores
+    dqg: jax.Array    # of exp(gam) Q
+    dkd: jax.Array    # of exp(last - gam) K
+    ddec: jax.Array   # [1, 1] of exp(last) through the state, times it
+    ds: jax.Array     # of the state the chunk starts from
+
+
+# A head's backward of a chunk in three stages, which the kernel issues for
+# all its heads in turn: through the state (the chain from chunk to chunk),
+# through T (the chain of dA's four products), then to the rows and gates.
+def _back_through_state(op, gt, s0, ub, dob, ds):
+    cdt = ub.dtype
     dsb, dec = ds.astype(cdt), jnp.exp(gt.last)
-
-    # through the four products that carry the state
     dub = (_mm(op.attn, dob, _TN) + _mm(op.kd, dsb)).astype(cdt)
-    dattn = _mm(dob, ub, _NT)
-    dqg = _mm(dob, s0, _NT)
-    dkd = _mm(ub, dsb, _NT)
-    dwb = (-_mm(dub, s0, _NT)).astype(cdt)
-    ddec = jnp.sum(rowsum(ds * s0.astype(F32)), axis=0, keepdims=True)
-    ds = _mm(op.qg, dob, _TN) + ds * dec - _mm(op.w, dub, _TN)
+    ddec = jnp.sum(_rowsum(ds * s0.astype(F32)), axis=0, keepdims=True)
+    return _ThroughState(
+        dub, (-_mm(dub, s0, _NT)).astype(cdt), _mm(dob, ub, _NT),
+        _mm(dob, s0, _NT), _mm(ub, dsb, _NT), ddec * dec,
+        _mm(op.qg, dob, _TN) + ds * dec - _mm(op.w, dub, _TN))
 
-    # through w = T kb and u0 = T vb, then T = (I + A)^-1:
-    # dA = -T^T dT T^T (T is exact in its own type: one half)
-    dt = _mm(dwb, op.kb, _NT) + _mm(dub, op.vb, _NT)
-    dkb, dvb = _mm(t, dwb, _TN), _mm(t, dub, _TN)
+
+def _back_through_inverse(t, op, gt, st):
+    """(d kb, d vb, dA under the diagonal) through w = T kb, u0 = T vb and
+    T = (I + A)^-1: dA = -T^T dT T^T (T is exact in its own type: one
+    half)."""
+    cdt = t.dtype
+    dt = _mm(st.dwb, op.kb, _NT) + _mm(st.dub, op.vb, _NT)
+    dkb, dvb = _mm(t, st.dwb, _TN), _mm(t, st.dub, _TN)
     da = _mm_halves(_halves(_mm_halves((t,), _halves(dt, cdt), _TN), cdt),
                     (t,), _NT)
-    dm = _strict(gt, -da)  # of beta decay K K^T, under the diagonal
+    return dkb, dvb, _strict(gt, -da)
 
-    # to the scores, the rows and the gates
-    kk = _mm(k, k, _NT)
+
+def _back_to_inputs(q, k, kk, v, op, gt, st, dkb, dvb, dm):
+    """A head's (dq, dk of the normalised rows, float32; dv; dg and dbeta as
+    [1, C] rows); ``dm`` is d loss / d beta decay K K^T."""
+    cdt = v.dtype
+    qf, kf, vf = q.astype(F32), k.astype(F32), v.astype(F32)
     dmd = dm * gt.decay
     dkk = (dmd * gt.beta).astype(cdt)
-    dqk = (dattn * gt.decay).astype(cdt)
-    ddecay = (dm * gt.beta * kk + dattn * op.qk) * gt.decay
-    dq = dqg * op.e + _mm(dqk, k)
-    dk = (dkd * op.el + dkb * (gt.beta * op.e) + _mm(dqk, q, _TN)
+    dqk = (st.dattn * gt.decay).astype(cdt)
+    ddecay = (dm * gt.beta * kk + st.dattn * op.qk) * gt.decay
+    dq = st.dqg * op.e + _mm(dqk, k)
+    dk = (st.dkd * op.el + dkb * (gt.beta * op.e) + _mm(dqk, q, _TN)
           + _mm(dkk, k) + _mm(dkk, k, _TN))
-    dkb_k = rowsum(dkb * kf)
-    dbeta = rowsum(dmd * kk) + dkb_k * op.e + rowsum(dvb * vf)
+    dkb_k = _rowsum(dkb * kf)
+    dbeta = _rowsum(dmd * kk) + dkb_k * op.e + _rowsum(dvb * vf)
     # gamma: from exp(gam), exp(last - gam), exp(last) and the table (row
     # sums minus column sums); g from gamma by the reversed running sum
-    dkd_k = rowsum(dkd * kf) * op.el
-    dlast = jnp.sum(dkd_k, axis=0, keepdims=True) + ddec * dec
-    dgam = ((rowsum(dqg * qf) + dkb_k * gt.beta) * op.e - dkd_k
-            + rowsum(ddecay)
+    dkd_k = _rowsum(st.dkd * kf) * op.el
+    dlast = jnp.sum(dkd_k, axis=0, keepdims=True) + st.ddec
+    dgam = ((_rowsum(st.dqg * qf) + dkb_k * gt.beta) * op.e - dkd_k
+            + _rowsum(ddecay)
             - _to_col(jnp.sum(ddecay, axis=0, keepdims=True), gt.eye))
     dg = jnp.sum(jnp.where(gt.under, dgam, 0.0), axis=0, keepdims=True)
     return (dq, dk, (dvb * gt.beta).astype(cdt), dg + dlast,
-            _to_row(dbeta, gt.eye), ds)
+            _to_row(dbeta, gt.eye))
 
 
-def _inverse_kernel(k_ref, g_ref, beta_ref, t_ref, *, chunk, per_step):
-    t_ref[0] = _chunk_inverses(k_ref[0], g_ref[0], beta_ref[0])
+@jax.jit
+def _chunk_backward(q, k, vs, ts, g_rows, beta_rows, s0s, ubs, dobs, dss):
+    """One chunk's cotangents, each value head's from d loss / d its o
+    (``dobs``) and d loss / d the state its NEXT chunk starts from (``dss``,
+    float32): dq and dk of the normalised rows, float32, summed over the
+    heads, head 0 first; and lists over the heads of dv, dg and dbeta as
+    [1, C] rows, and d loss / d the state this chunk starts from."""
+    qn, kn, qk = _chunk_keys(q, k)
+    kk = _mm(kn, kn, _NT)
+    gts = [_chunk_gates(g, b) for g, b in zip(g_rows, beta_rows)]
+    ops = [_chunk_operands(qn, kn, qk, v, t, gt)
+           for v, t, gt in zip(vs, ts, gts)]
+    sts = [_back_through_state(*a)
+           for a in zip(ops, gts, s0s, ubs, dobs, dss)]
+    dts = [_back_through_inverse(*a) for a in zip(ts, ops, gts, sts)]
+    heads = [_back_to_inputs(qn, kn, kk, v, op, gt, st, *dt)
+             for v, op, gt, st, dt in zip(vs, ops, gts, sts, dts)]
+    dqs, dks, dvs, dgs, dbetas = zip(*heads)
+    return (sum(dqs[1:], dqs[0]), sum(dks[1:], dks[0]), dvs, dgs, dbetas,
+            [st.ds for st in sts])
+
+
+def _value_rows(geo, r, rows):
+    """Where value head ``r`` of a grid step keeps ``rows`` in a block of
+    the "value" kind (see ``_call``)."""
+    if geo.in_lanes:
+        return 0, rows, slice(r * geo.dv, (r + 1) * geo.dv)
+    return r, rows, slice(None)
+
+
+def _inverse_kernel(k_ref, g_ref, beta_ref, t_ref, *, geo):
+    heads = range(geo.heads)
+    ts = _chunk_inverses(k_ref[0], [g_ref[r] for r in heads],
+                         [beta_ref[r] for r in heads])
+    for r in heads:
+        t_ref[r] = ts[r]
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref,
-                o_ref, u_ref, h_ref, s_scr, *, chunk, per_step):
-    head = pl.program_id(2)  # of the value heads this key head serves
-
+                o_ref, u_ref, h_ref, s_scr, *, geo):
     @pl.when(pl.program_id(1) == 0)
     def _():
-        s_scr[head] = jnp.zeros(s_scr.shape[1:], F32)
+        s_scr[...] = jnp.zeros(s_scr.shape, F32)
 
-    s = s_scr[head]
-    for c in range(per_step):
-        rows = slice(c * chunk, (c + 1) * chunk)
-        # h: the state this chunk starts from, for the backward
-        o_ref[0, rows, :], u_ref[0, rows, :], h_ref[0, c], s = _chunk_forward(
-            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
-            t_ref[0, rows, :], g_ref[0, c:c + 1, :], beta_ref[0, c:c + 1, :],
-            s)
-    s_scr[head] = s
+    heads = range(geo.heads)
+    ss = [s_scr[r] for r in heads]
+    for c in range(geo.per_step):
+        rows = slice(c * geo.chunk, (c + 1) * geo.chunk)
+        at = [_value_rows(geo, r, rows) for r in heads]
+        # h: the states this chunk starts from, for the backward
+        os, us, hs, ss = _chunk_forward(
+            q_ref[0, rows, :], k_ref[0, rows, :], [v_ref[a] for a in at],
+            [t_ref[r, rows, :] for r in heads],
+            [g_ref[r, c:c + 1, :] for r in heads],
+            [beta_ref[r, c:c + 1, :] for r in heads], ss)
+        for r in heads:
+            o_ref[at[r]], u_ref[at[r]], h_ref[r, c] = os[r], us[r], hs[r]
+    for r in heads:
+        s_scr[r] = ss[r]
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, u_ref, h_ref,
                 do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                ds_scr, dq_scr, dk_scr, *, chunk, per_step):
-    head, heads = pl.program_id(2), pl.num_programs(2)
-
+                ds_scr, *, geo):
     @pl.when(pl.program_id(1) == 0)
     def _():
-        ds_scr[head] = jnp.zeros(ds_scr.shape[1:], F32)
+        ds_scr[...] = jnp.zeros(ds_scr.shape, F32)
 
-    @pl.when(head == 0)  # dq, dk: summed over the heads the key head serves
-    def _():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-
-    ds = ds_scr[head]
-    for c in reversed(range(per_step)):
-        rows = slice(c * chunk, (c + 1) * chunk)
-        (dq, dk, dv_ref[0, rows, :], dg_ref[0, c:c + 1, :],
-         dbeta_ref[0, c:c + 1, :], ds) = _chunk_backward(
-            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
-            t_ref[0, rows, :], g_ref[0, c:c + 1, :], beta_ref[0, c:c + 1, :],
-            h_ref[0, c], u_ref[0, rows, :], do_ref[0, rows, :], ds)
-        dq_scr[rows, :] += dq
-        dk_scr[rows, :] += dk
-    ds_scr[head] = ds
-
-    @pl.when(head == heads - 1)
-    def _():
-        q_scale = q_ref.shape[-1] ** -0.5
-        dq_ref[0] = _unit_rows_bwd(q_ref[0], dq_scr[:], q_scale).astype(
-            dq_ref.dtype)
-        dk_ref[0] = _unit_rows_bwd(k_ref[0], dk_scr[:]).astype(dk_ref.dtype)
+    heads = range(geo.heads)
+    dss, dqs, dks = [ds_scr[r] for r in heads], [], []
+    for c in reversed(range(geo.per_step)):
+        rows = slice(c * geo.chunk, (c + 1) * geo.chunk)
+        at = [_value_rows(geo, r, rows) for r in heads]
+        dq, dk, dvs, dgs, dbetas, dss = _chunk_backward(
+            q_ref[0, rows, :], k_ref[0, rows, :], [v_ref[a] for a in at],
+            [t_ref[r, rows, :] for r in heads],
+            [g_ref[r, c:c + 1, :] for r in heads],
+            [beta_ref[r, c:c + 1, :] for r in heads],
+            [h_ref[r, c] for r in heads], [u_ref[a] for a in at],
+            [do_ref[a] for a in at], dss)
+        dqs.insert(0, dq)
+        dks.insert(0, dk)
+        for r in heads:
+            dv_ref[at[r]] = dvs[r]
+            dg_ref[r, c:c + 1, :], dbeta_ref[r, c:c + 1, :] = dgs[r], dbetas[r]
+    for r in heads:
+        ds_scr[r] = dss[r]
+    dq_ref[0] = _unit_rows_bwd(q_ref[0], jnp.concatenate(dqs),
+                               q_ref.shape[-1] ** -0.5).astype(dq_ref.dtype)
+    dk_ref[0] = _unit_rows_bwd(k_ref[0], jnp.concatenate(dks)).astype(
+        dk_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -936,12 +1008,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, t_ref, u_ref, h_ref,
 # ---------------------------------------------------------------------------
 class _Geometry(NamedTuple):
     chunk: int
-    per_step: int     # chunks one grid step holds
-    rep: int          # value heads a key head serves
-    key_lanes: int    # heads side by side in the lanes of q, k: all, or 1
-    value_lanes: int  # and of v, o
+    per_step: int    # chunks one grid step holds
+    rep: int         # value heads a key head serves
+    heads: int       # of them, one grid step holds: all, where they fit
+    key_lanes: int   # key heads side by side in the lanes of q, k: all, or 1
+    in_lanes: bool   # heads read where they lie in the lanes, or moved
     dk: int
     dv: int
+
+    @property
+    def groups(self):  # grid steps a key head's value heads take
+        return self.rep // self.heads
 
 
 def _per_step(n_chunks, chunk):
@@ -951,53 +1028,76 @@ def _per_step(n_chunks, chunk):
     return want if n_chunks % want == 0 and want % 8 == 0 else n_chunks
 
 
+def _heads_per_step(rep, rows, chunk, dv):
+    """Value heads one grid step holds: all ``rep`` of a key head, or the
+    most that divide rep and keep what a step makes of each (its [rows, C]
+    tables and [rows, dv] rows in float32, lanes padded to 128) within
+    STEP_BYTES."""
+    def lanes(n):
+        return -(-n // 128) * 128
+
+    head = 4 * rows * (lanes(chunk) + lanes(dv))
+    return max(h for h in range(1, rep + 1)
+               if rep % h == 0 and (h == 1 or h * head <= STEP_BYTES))
+
+
 def _call(kernel, name, geo, ins, outs, scratch=(), reverse=False):
-    """``kernel`` over the grid (key heads of all batches, blocks of chunks,
-    value heads of the key head). ``ins`` are (kind, array) pairs, ``outs``
-    (kind, shape, dtype); a kind is the block a grid step gets: "key" and
-    "value" rows of one head out of [B, seq, lanes * d] (head n of all
-    batches is lane block n % lanes of row n // lanes), "t" rows of [value
-    heads, seq, chunk], "gate" of [value heads, chunks, chunk], "h" of
-    [value heads, chunks, dk, dv]."""
+    """``kernel`` over the grid (key heads of all batches x their groups of
+    ``geo.heads`` value heads, blocks of chunks); key head i of all batches
+    serves value heads i * rep .. i * rep + rep - 1, and with one group (all
+    shapes the VMEM holds) a grid step holds all of them. ``ins`` are (kind,
+    array) pairs, ``outs`` (kind, shape, dtype); a kind is the block a grid
+    step gets: "key" rows of one head out of [B, seq, lanes * dk] (head i is
+    lane block i % lanes of row i // lanes), "value" rows of the step's
+    value heads: the one (rows, heads * dv) lane block of [B, seq, value
+    heads * dv] they lie in side by side, or (heads, rows, dv) of [value
+    heads, seq, dv] where heads are moved; "t" (heads, rows, chunk) of
+    [value heads, seq, chunk], "gate" of [value heads, chunks, chunk], "h"
+    of [value heads, chunks, dk, dv]; "partial" a group's float32 rows of
+    [groups, B, seq, lanes * dk], summed by the caller."""
     rows = geo.chunk * geo.per_step
     key_heads = ins[0][1].shape[0] * geo.key_lanes
     n_blocks = dict(ins)["gate"].shape[1] // geo.per_step
-    rep, key_lanes, value_lanes = (np.int32(x) for x in (
-        geo.rep, geo.key_lanes, geo.value_lanes))
+    lanes, groups = np.int32(geo.key_lanes), np.int32(geo.groups)
 
     def block(j):
         return np.int32(n_blocks - 1) - j if reverse else j
 
-    def key(i, j, r):
-        return jax.lax.div(i, key_lanes), block(j), jax.lax.rem(i, key_lanes)
+    def key(i, j):
+        i = jax.lax.div(i, groups)
+        return jax.lax.div(i, lanes), block(j), jax.lax.rem(i, lanes)
 
-    def value(i, j, r):
-        n = i * rep + r
-        return (jax.lax.div(n, value_lanes), block(j),
-                jax.lax.rem(n, value_lanes))
+    def lane_block(i, j):  # the step's value heads, side by side
+        return (jax.lax.div(i, lanes * groups), block(j),
+                jax.lax.rem(i, lanes * groups))
 
-    def head(i, j, r):
-        return i * rep + r, block(j), _0
+    def heads(i, j):
+        return i, block(j), _0
 
+    h = geo.heads
     specs = {
         "key": pl.BlockSpec((1, rows, geo.dk), key),
-        "value": pl.BlockSpec((1, rows, geo.dv), value),
-        "t": pl.BlockSpec((1, rows, geo.chunk), head),
-        "gate": pl.BlockSpec((1, geo.per_step, geo.chunk), head),
-        "h": pl.BlockSpec((1, geo.per_step, geo.dk, geo.dv),
-                          lambda i, j, r: head(i, j, r) + (_0,)),
+        "value": pl.BlockSpec((1, rows, h * geo.dv), lane_block)
+        if geo.in_lanes else pl.BlockSpec((h, rows, geo.dv), heads),
+        "t": pl.BlockSpec((h, rows, geo.chunk), heads),
+        "gate": pl.BlockSpec((h, geo.per_step, geo.chunk), heads),
+        "h": pl.BlockSpec((h, geo.per_step, geo.dk, geo.dv),
+                          lambda i, j: heads(i, j) + (_0,)),
+        "partial": pl.BlockSpec(
+            (None, 1, rows, geo.dk),
+            lambda i, j: (jax.lax.rem(i, groups),) + key(i, j)),
     }
     return pl.pallas_call(
-        functools.partial(kernel, chunk=geo.chunk, per_step=geo.per_step),
+        functools.partial(kernel, geo=geo),
         name=name,
-        grid=(key_heads, n_blocks, geo.rep),
+        grid=(key_heads * geo.groups, n_blocks),
         in_specs=[specs[kind] for kind, _ in ins],
         out_specs=[specs[kind] for kind, _, _ in outs],
         out_shape=[jax.ShapeDtypeStruct(shape, dtype)
                    for _, shape, dtype in outs],
         scratch_shapes=list(scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
     )(*(x for _, x in ins))
 
@@ -1021,22 +1121,24 @@ def _forward(q, k, v, g, beta, t, geo):
          ("t", t)],
         [("value", v.shape, v.dtype), ("value", v.shape, v.dtype),
          ("h", (heads, n, geo.dk, geo.dv), v.dtype)],
-        [pltpu.VMEM((geo.rep, geo.dk, geo.dv), F32)])
+        [pltpu.VMEM((geo.heads, geo.dk, geo.dv), F32)])
 
 
 @functools.partial(jax.jit, static_argnums=(9,))
 def _backward(q, k, v, g, beta, t, u, h, do, geo):
-    rows = geo.chunk * geo.per_step
-    return tuple(_call(
+    # dq, dk: written once a key head, or as each group's part, summed here
+    dqk = [("key", x.shape, x.dtype) if geo.groups == 1
+           else ("partial", (geo.groups,) + x.shape, F32) for x in (q, k)]
+    dq, dk, dv, dg, dbeta = _call(
         _bwd_kernel, "gated_delta_rule_bwd", geo,
         [("key", q), ("key", k), ("value", v), ("gate", g), ("gate", beta),
          ("t", t), ("value", u), ("h", h), ("value", do)],
-        [("key", q.shape, q.dtype), ("key", k.shape, k.dtype),
-         ("value", v.shape, v.dtype), ("gate", g.shape, F32),
-         ("gate", beta.shape, F32)],
-        [pltpu.VMEM((geo.rep, geo.dk, geo.dv), F32),
-         pltpu.VMEM((rows, geo.dk), F32), pltpu.VMEM((rows, geo.dk), F32)],
-        reverse=True))
+        dqk + [("value", v.shape, v.dtype), ("gate", g.shape, F32),
+               ("gate", beta.shape, F32)],
+        [pltpu.VMEM((geo.heads, geo.dk, geo.dv), F32)], reverse=True)
+    if geo.groups > 1:
+        dq, dk = dq.sum(0).astype(q.dtype), dk.sum(0).astype(k.dtype)
+    return dq, dk, dv, dg, dbeta
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -1090,13 +1192,16 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk=64):
             f"or {hv} value heads are not a multiple of {hk} key heads")
     n = seq // chunk
     in_lanes = dk % 128 == 0 and dv % 128 == 0
-    geo = _Geometry(chunk, _per_step(n, chunk), hv // hk,
-                    hk if in_lanes else 1, hv if in_lanes else 1, dk, dv)
+    per_step = _per_step(n, chunk)
+    geo = _Geometry(chunk, per_step, hv // hk,
+                    _heads_per_step(hv // hk, per_step * chunk, chunk, dv),
+                    hk if in_lanes else 1, in_lanes, dk, dv)
 
     from ..profiler import trace
     trace.emit("gdn_chunks", site="gated_delta_rule", seq=seq, chunk=chunk,
                chunks_per_step=geo.per_step, rep=geo.rep,
-               heads_in_lanes=in_lanes, prepared="vmem")
+               heads_per_step=geo.heads, heads_in_lanes=in_lanes,
+               prepared="vmem")
 
     def heads(x):  # [b, seq, h, d] -> [B, seq, lanes * d]
         if in_lanes:

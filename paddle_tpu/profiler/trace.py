@@ -23,8 +23,9 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
                    many sub-tiles of a head's score square the causal walk
                    runs, masks and skips (trace time, once per compile)
   gdn_chunks       the gated delta rule's kernels were built for a shape:
-                   chunk, chunks a grid step, value heads a key head, where
-                   the chunks are prepared (trace time, once per trace)
+                   chunk, chunks a grid step, value heads a key head and a
+                   grid step, where the chunks are prepared (trace time,
+                   once per trace)
   mixer_pass       the short conv or the gated norm round the rule was
                    traced for a shape: its Pallas kernels ("vmem") or the
                    jax.numpy expression ("xla", with why), rows, lanes and

@@ -136,6 +136,9 @@ SMALL_HEADS = (2, 2, 4, 32, 16)  # batch, key heads, value heads, d_k, d_v
 # kernels read as blocks of lanes, two value heads to a key head), over two
 # grid steps of eight chunks: the carried state and the reversed walk cross
 CELL_HEADS = (1, 1, 2, 128, 128)
+# one and four value heads to a key head, heads moved and in lanes
+REP1_HEADS, REP4_HEADS = (2, 2, 2, 32, 16), (2, 1, 4, 32, 16)
+REP1_LANES, REP4_LANES = (1, 2, 2, 128, 128), (1, 1, 4, 128, 128)
 
 
 def delta_rule_inputs(seq, heads, decay, seed):
@@ -173,7 +176,10 @@ def output_and_gradients(rule, args, ct):
                          ids=["near_one", "middling", "near_zero"])
 @pytest.mark.parametrize("seq,chunk,heads", [
     (192, 64, SMALL_HEADS), (64, 16, SMALL_HEADS), (48, 64, SMALL_HEADS),
-    (1024, 64, CELL_HEADS)], ids=["192-64", "64-16", "48-64", "cell"])
+    (1024, 64, CELL_HEADS), (192, 64, REP1_HEADS), (192, 64, REP4_HEADS),
+    (1024, 64, REP1_LANES), (1024, 64, REP4_LANES)],
+    ids=["192-64", "64-16", "48-64", "cell", "rep1", "rep4", "rep1-lanes",
+         "rep4-lanes"])
 def test_chunked_delta_rule_matches_recurrence(seq, chunk, heads, decay):
     args, ct = delta_rule_inputs(seq, heads, decay, seq + chunk)
     got = output_and_gradients(
@@ -183,6 +189,42 @@ def test_chunked_delta_rule_matches_recurrence(seq, chunk, heads, decay):
     for name, a, c in zip("q k v g beta".split(), got[1:], want[1:]):
         np.testing.assert_allclose(
             a, c, atol=5e-5 * float(jnp.abs(c).max()) + 1e-7, err_msg=name)
+
+
+@pytest.mark.parametrize("seq,heads,step_bytes", [
+    (1024, CELL_HEADS, None), (192, REP4_HEADS, None),
+    (192, REP4_HEADS, 2 * 4 * 192 * 256)],
+    ids=["cell", "rep4", "rep4-two-steps-of-two"])
+def test_value_heads_walked_together_give_each_what_it_gets_alone(
+        seq, heads, step_bytes, monkeypatch):
+    """A grid step walks a key head's value heads side by side (or, where
+    STEP_BYTES caps it, as many as fit); each value head gets exactly what
+    a walk of it alone gives: the same call with q and k repeated to one key
+    head a value head. o, dv, dg, dbeta bit for bit; dq and dk the lone
+    walks' summed over a key head's value heads, head 0 first, within
+    float32 rounding (the rows' norm is taken back once over the sum)."""
+    if step_bytes:
+        monkeypatch.setattr(la, "STEP_BYTES", step_bytes)
+    (q, k, v, g, beta), ct = delta_rule_inputs(seq, heads, 1.0, 21)
+    rep = heads[2] // heads[1]
+    together = output_and_gradients(la.gated_delta_rule, (q, k, v, g, beta),
+                                    ct)
+    event = trace.events(kind="gdn_chunks")[-1].attrs
+    assert (event["rep"], event["heads_per_step"]) == (
+        rep, 2 if step_bytes else rep)
+    alone = output_and_gradients(
+        la.gated_delta_rule,
+        (jnp.repeat(q, rep, 2), jnp.repeat(k, rep, 2), v, g, beta), ct)
+    for name, i in (("o", 0), ("v", 3), ("g", 4), ("beta", 5)):
+        np.testing.assert_array_equal(together[i], alone[i], err_msg=name)
+    for name, i in (("q", 1), ("k", 2)):
+        parts = alone[i].reshape(*q.shape[:3], rep, q.shape[3])
+        summed = parts[:, :, :, 0]
+        for r in range(1, rep):
+            summed = summed + parts[:, :, :, r]
+        np.testing.assert_allclose(
+            together[i], summed, rtol=0,
+            atol=1e-6 * float(jnp.abs(summed).max()), err_msg=name)
 
 
 def test_bf16_delta_rule_stays_within_its_rounding_of_the_recurrence():
@@ -235,7 +277,8 @@ def test_each_trace_of_the_rule_leaves_one_gdn_chunks_event():
     (event,) = trace.events(kind="gdn_chunks")[before:]
     assert event.site == "gated_delta_rule"
     assert event.attrs == dict(seq=128, chunk=64, chunks_per_step=2, rep=2,
-                               heads_in_lanes=False, prepared="vmem")
+                               heads_per_step=2, heads_in_lanes=False,
+                               prepared="vmem")
     jax.block_until_ready(fn(*args))
     assert len(trace.events(kind="gdn_chunks")) == before + 1
 

@@ -235,8 +235,9 @@ def test_gated_delta_rule_compiles_for_v5e(one_chip, compiled_kernels):
     is prepared from stays in VMEM: no float32 [.., 64, 64] table of all
     chunks (the parent's ``f32[2,32,128,64,64]`` inverse and decay tables,
     134 MB each) in any shape, and the program's temporaries under 1 GiB
-    (896 MiB here, 2,960 MiB before the preparation moved into the kernels;
-    AOT compiles, PR 29)."""
+    (832 MiB with a key head's two value heads in one grid step; 896 MiB
+    with one a step, 2,960 MiB before the preparation moved into the
+    kernels; AOT compiles)."""
     qk = _sds((2, 8192, 16, 128), jnp.bfloat16, one_chip)
     v = _sds((2, 8192, 32, 128), jnp.bfloat16, one_chip)
     gate = _sds((2, 8192, 32), jnp.float32, one_chip)
