@@ -32,3 +32,8 @@ from .sdar_moe import (  # noqa: F401
     SDARMoEForBlockDiffusion,
     SDARMoEModel,
 )
+from .mellum2 import (  # noqa: F401
+    Mellum2Config,
+    Mellum2ForCausalLM,
+    Mellum2Model,
+)

@@ -692,17 +692,25 @@ def rotary_embedding(x, rotary_dim=None, theta=10000.0, positions=None,
 
 def scaled_dot_product_attention(
     query, key, value, attn_mask=None, dropout_p=0.0, is_causal=False,
-    training=True, name=None, scale=None, block_mask=None,
+    training=True, name=None, scale=None, block_mask=None, window=None,
 ):
     # ``scale`` multiplies q k^T in place of head_dim ** -0.5 (a model with
     # an attention multiplier of its own); without it the ops are called as
     # they always were. ``block_mask`` = (half, block) is the two-stream mask
     # of block-diffusion training in the place of ``is_causal``
     # (ops/pallas/flash_attention.py); the kernels walk it, and where they
-    # cannot the dense path builds it as an array
+    # cannot the dense path builds it as an array. ``window`` = W, with
+    # ``is_causal``: a sliding window, each query attending its last W keys
+    # (its own included); the kernels walk the band, the dense path builds it
     options = {} if scale is None else {"scale": float(scale)}
     if block_mask is not None:
         options["block_mask"] = tuple(map(int, block_mask))
+    if window is not None:
+        if not is_causal or block_mask is not None or int(window) < 1:
+            raise ValueError(
+                f"scaled_dot_product_attention: window={window} is a causal "
+                "band of one or more keys (is_causal=True, no block_mask)")
+        options["window"] = int(window)
     dropout_key = (
         _random.next_key() if (dropout_p > 0.0 and training) else None
     )
@@ -726,7 +734,8 @@ def scaled_dot_product_attention(
             else "attn_mask" if attn_mask is not None
             else "attention_dropout" if dropout_key is not None
             else _nn.flash_attention_refusal(query.shape, key.shape,
-                                             value.shape, block_mask)
+                                             value.shape, block_mask,
+                                             options.get("window"))
         )
         if refusal is None:
             return apply(

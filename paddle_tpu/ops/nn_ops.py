@@ -8,6 +8,7 @@ lax.conv_general_dilated which XLA lays out for TPU.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -445,16 +446,50 @@ def rms_norm(x, weight, *, epsilon=1e-6, zero_centered=False):
     return (xf * jax.lax.rsqrt(var + epsilon) * gain).astype(x.dtype)
 
 
-def rotary_embedding(x, positions=None, *, rotary_dim, theta=10000.0):
+def rope_inv_freq(theta, dim, yarn=None):
+    """[dim / 2] float32: the turn a position gives each rotary pair,
+    theta ** (-2 i / dim), or with ``yarn`` (a mapping with the published
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast`` and
+    ``beta_slow``) the YaRN table of Peng et al., "YaRN: Efficient Context
+    Window Extension of Large Language Models" (2023), as ``transformers``
+    makes it: low = floor(dim ln(L / (beta_fast 2 pi)) / (2 ln theta)) and
+    high = ceil(dim ln(L / (beta_slow 2 pi)) / (2 ln theta)) with L the
+    original length, ramp(i) = clip((i - low) / (high - low), 0, 1), and
+    the pair's turn (1 - ramp) theta ** (-2 i / dim) + ramp theta **
+    (-2 i / dim) / factor. YaRN's attention factor scales the scores and is
+    not in the table."""
+    half = dim // 2
+    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32)
+                                * (2.0 / dim)))
+    if yarn is None:
+        return inv_freq
+    length = yarn["original_max_position_embeddings"]
+
+    def pair(beta):  # the pair that turns beta times over the length
+        return dim * math.log(length / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair(yarn["beta_fast"])), 0)
+    high = min(math.ceil(pair(yarn["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (inv_freq / np.float32(yarn["factor"]) * ramp
+            + inv_freq * (1.0 - ramp)).astype(np.float32)
+
+
+def rotary_embedding(x, positions=None, *, rotary_dim, theta=10000.0,
+                     inv_freq=None):
     """Rotary positions on the first ``rotary_dim`` dims of each head of
     ``x`` [batch, seq, heads, head_dim], the rest passed through: dim i is
     paired with dim i + rotary_dim / 2 (the half-split convention), position
-    p turns pair i by p * theta ** (-2 i / rotary_dim). Position s of the
-    sequence is s (from 0), or ``positions[s]`` where they are given ([seq],
-    the same for every row of the batch). Angles in float32."""
+    p turns pair i by p * theta ** (-2 i / rotary_dim), or by p *
+    ``inv_freq[i]`` where a table is given (``rope_inv_freq``). Position s
+    of the sequence is s (from 0), or ``positions[s]`` where they are given
+    ([seq], the same for every row of the batch). Angles in float32."""
     s, half = x.shape[1], rotary_dim // 2
-    inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32)
-                                * (2.0 / rotary_dim)))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (np.arange(half, dtype=np.float32)
+                                    * (2.0 / rotary_dim)))
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.float32)
     else:
@@ -758,12 +793,14 @@ def block_diffusion_mask(half, block):
 
 def scaled_dot_product_attention(
     q, k, v, mask=None, dropout_key=None, *, scale=None, is_causal=False,
-    dropout_p=0.0, block_mask=None,
+    dropout_p=0.0, block_mask=None, window=None,
 ):
     """q,k,v: [batch, seq, heads, head_dim] (paddle fused_attention layout).
     Attention dropout applies to the probabilities when dropout_key is given
     (the functional wrapper threads a key only in training). ``block_mask``
     = (half, block): ``block_diffusion_mask`` in the place of ``is_causal``.
+    ``window`` = W with ``is_causal``: the band of each query's last W keys,
+    its own included.
 
     The flash hot path lives in flash_scaled_dot_product_attention below —
     selection happens in the functional wrapper (nn/functional) so the
@@ -785,6 +822,9 @@ def scaled_dot_product_attention(
     elif is_causal:
         ql, kl = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((ql, kl), dtype=bool), k=kl - ql)
+        if window is not None:
+            causal = causal & ~jnp.tril(jnp.ones((ql, kl), dtype=bool),
+                                        k=kl - ql - window)
         logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
     if mask is not None:
         logits = logits + mask
@@ -893,7 +933,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, k_new, v_new, *,
 
 
 def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False,
-                                       block_mask=None):
+                                       block_mask=None, window=None):
     """Pallas flash kernel path (ops/pallas/flash_attention.py — the
     fused_attention_op.cu replacement): O(S·D) memory instead of the O(S²)
     probability matrix, which is what makes long-seq training fit in HBM.
@@ -906,14 +946,16 @@ def flash_scaled_dot_product_attention(q, k, v, *, scale=None, is_causal=False,
     if block_mask is not None:
         with jax.named_scope("block_diffusion_attention"):
             return _flash(q, k, v, scale=s, block_mask=block_mask)
-    return _flash(q, k, v, scale=s, causal=is_causal)
+    return _flash(q, k, v, scale=s, causal=is_causal, window=window)
 
 
-def flash_attention_refusal(q_shape, k_shape, v_shape, block_mask=None):
+def flash_attention_refusal(q_shape, k_shape, v_shape, block_mask=None,
+                            window=None):
     """Why the flash kernel cannot take these [batch, seq, heads, head_dim]
     shapes, as a short reason, or None where it can. k and v may have fewer
     heads than q (grouped-query heads: a divisor of q's). ``block_mask``:
-    the two-stream block mask (half, block) asked for."""
+    the two-stream block mask (half, block) asked for; ``window``: a
+    sliding window of that many keys."""
     from .pallas.flash_attention import supports as _supports
     from .pallas.flash_attention import supports_block_mask
 
@@ -930,6 +972,9 @@ def flash_attention_refusal(q_shape, k_shape, v_shape, block_mask=None):
     if block_mask is not None:
         if not supports_block_mask(q_shape[1], q_shape[3], block_mask):
             return "block_mask_not_tiled"
+    elif window is not None:
+        if not _supports(q_shape[1], q_shape[3], window=window):
+            return "window_not_tiled"
     elif not _supports(q_shape[1], q_shape[3]):
         return "seq_or_head_dim_not_tiled"
     return None

@@ -47,6 +47,18 @@ crosses, and the own sub-tiles, build a mask (``_causal_mask``: a compare on
 positions or-ed, and-ed or xor-ed with blk - 1, so blk is a power of two).
 With ``block_mask`` None every index map, walk and mask is what it was:
 ``blk`` 1 is the plain diagonal.
+
+A sliding window (``window`` = W: query i sees key j iff i - W < j <= i)
+bounds the causal walk from below as well. The grid's key axis spans only
+the key blocks a q block's band touches (dkv's q axis only the q blocks
+whose rows reach a key block, down to its last column + W - 1), a step's
+block being the first such block plus the step; a step past the band is
+skipped and fetches nothing. Inside a block a strip starts at the first
+sub-tile its rows' band reaches, and the sub-tiles that the band's lower edge
+crosses build a mask as those the diagonal crosses do (``_causal_mask``
+with a second compare on positions). Those kernels carry the names
+``flash_attention_window_*``. With ``window`` None every index map, walk,
+mask and name is what it was.
 """
 from __future__ import annotations
 
@@ -156,19 +168,94 @@ def _query_walk(col0, cols, row_base, step, n, blk=1, before=False):
             _starts_le(col0 + cols - (1 + blk), row_base, step, n))
 
 
+def _band_walk(row0, rows, col_base, step, n, window):
+    """``_key_walk`` under a sliding window of ``window`` keys, for q rows
+    row0 .. row0+rows-1: (first, lo, hi, end). Tiles [first, end) run, those
+    before ``first`` lie wholly before the band of the first row; [first, lo)
+    are crossed by the band's lower edge and [hi, end) by the diagonal (both
+    masked). Where the two crossings meet every tile is masked (lo = hi =
+    first)."""
+    first = _ends_le(row0 - window, col_base, step, n)
+    lo = _starts_le(row0 + rows - 1 - window, col_base, step, n)
+    hi = _ends_le(row0, col_base, step, n)
+    if lo > hi:
+        lo = hi = first
+    return first, lo, hi, _starts_le(row0 + rows - 1, col_base, step, n)
+
+
+def _band_query_walk(col0, cols, row_base, step, n, window):
+    """``_query_walk`` under a sliding window, for key columns col0 ..
+    col0+cols-1: (r_first, r_full, r_lo, r_end). Tiles [r_first, r_end) of q
+    rows run, the rows stopping at col0+cols-1 + window-1; [r_first, r_full)
+    are crossed by the diagonal and [r_lo, r_end) by the band's lower edge
+    (both masked). Where the two crossings meet every tile is masked."""
+    first = _ends_le(col0 - 1, row_base, step, n)
+    full = _starts_le(col0 + cols - 2, row_base, step, n)
+    lo = _ends_le(col0 + window - 1, row_base, step, n)
+    end = _starts_le(col0 + cols + window - 2, row_base, step, n)
+    if lo < full:
+        full = lo = end
+    return first, full, lo, end
+
+
+def _band_keys(j, bq, bk, n_k, window):
+    """(first, last) key block the band of q block j touches."""
+    return (_ends_le(j * bq - window, 0, bk, n_k),
+            _starts_le(j * bq + bq - 1, 0, bk, n_k) - 1)
+
+
+def _band_queries(kk, bq, bk, n_q, window):
+    """(first, last) q block whose rows' band touches key block kk."""
+    return (_ends_le(kk * bk - 1, 0, bq, n_q),
+            _starts_le(kk * bk + bk + window - 2, 0, bq, n_q) - 1)
+
+
+def _key_steps(n_q, n_k, bq, bk, window):
+    """The grid's key axis: every key block, or under a window as many as
+    the widest band of a q block touches."""
+    if window is None:
+        return n_k
+    return max(b - a + 1 for a, b in (_band_keys(j, bq, bk, n_k, window)
+                                      for j in range(n_q)))
+
+
+def _query_steps(n_q, n_k, bq, bk, window):
+    """dkv's q axis (a query head's share of it): every q block, or under a
+    window as many as reach the widest key block's band."""
+    if window is None:
+        return n_q
+    return max(b - a + 1 for a, b in (_band_queries(kk, bq, bk, n_q, window)
+                                      for kk in range(n_k)))
+
+
 def _overlapped(lo, hi, step):
     """How many tiles of `step` the positions [lo, hi) touch."""
     return (hi - 1) // step - lo // step + 1
 
 
 def causal_tile_counts(seq_len, block_q, block_k, sub_q, sub_k, causal,
-                       block_mask=None):
+                       block_mask=None, window=None):
     """(run, masked, total) sub-tiles of one head's seq_len x seq_len score
     square as the kernels walk it: computed, computed with a mask, and all.
     `run / total` is how far the causal skip engages (1.0 = not at all).
     ``block_mask`` = (half, blk) counts the two-stream block mask's walk
     (``flash_attention``): each half's rows over the clean keys, and the
-    noised rows' own blocks."""
+    noised rows' own blocks. ``window`` counts the sliding window's walk:
+    each q block over the key blocks its band touches."""
+    if window is not None:
+        n_q, n_k = seq_len // block_q, seq_len // block_k
+        run = masked = 0
+        for j in range(n_q):
+            k_first, k_last = _band_keys(j, block_q, block_k, n_k, window)
+            for kk in range(k_first, k_last + 1):
+                for r0 in range(j * block_q, (j + 1) * block_q, sub_q):
+                    first, lo, hi, end = _band_walk(
+                        r0, sub_q, kk * block_k, sub_k, block_k // sub_k,
+                        window)
+                    if end > first:
+                        run += end - first
+                        masked += lo - first + end - hi
+        return run, masked, (seq_len // sub_q) * (seq_len // sub_k)
     if block_mask is not None:
         half, blk = block_mask
         n_sk = block_k // sub_k
@@ -240,6 +327,22 @@ def _at_block_offset(n_q, n_k, q_axis, k_axis, bq, bk, causal, walk,
         pl.when(off >= bk - 1)(functools.partial(walk, None))
 
 
+def _at_band_offset(n_q, n_k, bq, bk, window, off, walk):
+    """``_at_block_offset`` under a sliding window, ``off`` the step's (traced)
+    first row less first column: one predicated copy of the walk for each
+    offset at which the diagonal or the band's lower edge crosses a block,
+    one for all blocks wholly inside the band, none for a step whose block
+    the band does not touch (skipped)."""
+    offs = {d for d in (j * bq - kk * bk for j in range(n_q)
+                        for kk in range(n_k)) if -bq < d < window + bk - 1}
+    inside = {d for d in offs if bk - 1 <= d <= window - bq}
+    for d in sorted(offs - inside):
+        pl.when(off == d)(functools.partial(walk, d))
+    if inside:
+        pl.when((off >= bk - 1) & (off <= window - bq))(
+            functools.partial(walk, None))
+
+
 def _at_stream_block(n_qh, n_k, q_axis, k_axis, bq, bk, walk, q_wraps=False):
     """``_at_block_offset`` under the two-stream block mask: the q axis walks
     the clean half's n_qh blocks and then the noised half's, each against the
@@ -262,50 +365,68 @@ def _at_stream_block(n_qh, n_k, q_axis, k_axis, bq, bk, walk, q_wraps=False):
         pl.when(off >= bk - 1)(functools.partial(walk, None, False))
 
 
-def _q_strips(off, bq, bk, sq, sk, blk=1, noised=False):
+def _q_strips(off, bq, bk, sq, sk, blk=1, noised=False, window=None):
     """The forward's and dq's walk of a resident [bq, bk] block: one strip of
     scores for each q sub-block, over every key it attends to. Yields (rows,
-    keys, first masked column, own). A block wholly under the diagonal (off
-    None) is one strip. ``own``: for noised rows of the block mask, the
-    columns of the NOISED key block at this block's place that hold the
-    rows' own blocks (else None); their scores join the strip's."""
+    keys, (lo, hi), own): the strip's columns, and the columns before ``lo``
+    (the band's lower edge) and from ``hi`` on (the diagonal) masked. A
+    block wholly under the diagonal and inside the band (off None) is one
+    strip. ``own``: for noised rows of the block mask, the columns of the
+    NOISED key block at this block's place that hold the rows' own blocks
+    (else None); their scores join the strip's."""
     if off is None:
-        yield slice(0, bq), bk, bk, None
+        yield slice(0, bq), slice(0, bk), (0, bk), None
         return
     for i in range(bq // sq):
         r0 = off + i * sq
-        n_full, n_run = _key_walk(r0, sq, 0, sk, bk // sk, True, blk, noised)
-        own = slice(r0, r0 + sq) if noised and 0 <= r0 < bk else None
-        if n_run or own:  # else this block has no key these rows attend to
-            yield slice(i * sq, (i + 1) * sq), n_run * sk, n_full * sk, own
+        if window:
+            first, lo, hi, n_run = _band_walk(r0, sq, 0, sk, bk // sk, window)
+            own = None
+        else:
+            n_full, n_run = _key_walk(r0, sq, 0, sk, bk // sk, True, blk,
+                                      noised)
+            first, lo, hi = 0, 0, n_full
+            own = slice(r0, r0 + sq) if noised and 0 <= r0 < bk else None
+        if n_run > first or own:  # else no key here these rows attend to
+            yield (slice(i * sq, (i + 1) * sq), slice(first * sk, n_run * sk),
+                   (lo * sk, hi * sk), own)
 
 
-def _k_strips(off, bq, bk, sq, sk, blk=1, noised=False):
+def _k_strips(off, bq, bk, sq, sk, blk=1, noised=False, window=None):
     """dkv's walk, transposed: one strip for each key sub-block, over every q
-    row that attends to it. Yields (columns, rows, masked, own): the first
-    ``masked`` rows of the strip are of sub-tiles the diagonal crosses.
+    row that attends to it. Yields (columns, rows, (masked, lower), own): the
+    strip's rows before ``masked`` are of sub-tiles the diagonal crosses,
+    those from ``lower`` on of sub-tiles the band's lower edge crosses.
     ``own`` strips (noised rows of the block mask) are of the NOISED key
     block at this block's place against the rows of the same blocks, and
     always masked."""
     if off is None:
-        yield slice(0, bk), slice(0, bq), 0, False
+        yield slice(0, bk), slice(0, bq), (0, bq), False
         return
     for c in range(bk // sk):
         cols = slice(c * sk, (c + 1) * sk)
-        r_first, r_full = _query_walk(c * sk, sk, off, sq, bq // sq, blk,
-                                      noised)
-        if r_first < bq // sq:  # else every row here is above these keys
-            yield cols, slice(r_first * sq, bq), (r_full - r_first) * sq, False
+        if window:
+            r_first, r_full, r_lo, r_end = _band_query_walk(
+                c * sk, sk, off, sq, bq // sq, window)
+        else:
+            r_first, r_full = _query_walk(c * sk, sk, off, sq, bq // sq, blk,
+                                          noised)
+            r_lo = r_end = bq // sq
+        if r_first < r_end:  # else no row here attends to these keys
+            yield (cols, slice(r_first * sq, r_end * sq),
+                   ((r_full - r_first) * sq, (r_lo - r_first) * sq), False)
         lo, hi = max(c * sk - off, 0), min((c + 1) * sk - off, bq)
         if noised and lo < hi:
-            yield cols, slice(lo, hi), hi - lo, True
+            yield cols, slice(lo, hi), (hi - lo, hi - lo), True
 
 
-def _kv_index(causal, bq, bk, n_k, group=1, n_qh=None, own=False):
+def _kv_index(causal, bq, bk, n_k, group=1, n_qh=None, own=False,
+              window=None):
     """Index map of a k/v block under grid (head, q block j, k block kk). A
     step above the diagonal is skipped in the kernel: give it the index of
     the last step that runs, so that Pallas sees no change and copies
-    nothing. Under grouped-query heads (``group`` query heads on one KV
+    nothing. Under a window the k axis counts from the first key block the
+    q block's band touches. Under grouped-query heads (``group`` query heads on one KV
     head) query head i reads KV head i // group: k and v are never copied
     out to the query heads. Under the block mask (``n_qh`` q blocks a half)
     a q block's place is the one inside its half and the n_k key blocks are
@@ -316,6 +437,9 @@ def _kv_index(causal, bq, bk, n_k, group=1, n_qh=None, own=False):
             j = jax.lax.rem(j, np.int32(n_qh))
         if own:
             kk = n_k + _div(j * bq, bk)
+        elif window:
+            first, last = _band_keys(j, bq, bk, n_k, window)
+            kk = jnp.minimum(first + kk, last)
         elif causal and n_k > 1:
             _, k_steps = _key_walk(j * bq, bq, 0, bk, n_k, causal)
             kk = jnp.minimum(kk, k_steps - 1)
@@ -326,12 +450,13 @@ def _kv_index(causal, bq, bk, n_k, group=1, n_qh=None, own=False):
 
 
 def _causal_mask(s, row0, col0, keys_first=False, blk=1, before=False,
-                 own=False):
+                 own=False, window=None):
     """Mask a score tile whose corner is (row0, col0) to the causal region
     (shared by all 3 kernels); `keys_first` for a tile with the keys down
     the sublanes. With positions in blocks of ``blk`` (a power of two): to
     the keys up to the end of the row's block, with ``before`` to the blocks
-    before it, with ``own`` to the row's own block."""
+    before it, with ``own`` to the row's own block. With ``window``: to the
+    band of the row's last ``window`` keys."""
     q_dim, k_dim = (1, 0) if keys_first else (0, 1)
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, k_dim)
@@ -339,6 +464,8 @@ def _causal_mask(s, row0, col0, keys_first=False, blk=1, before=False,
         seen = (rows ^ cols) < blk
     elif before:
         seen = cols < (rows & np.int32(-blk))
+    elif window:
+        seen = (cols <= rows) & (rows - cols < np.int32(window))
     elif blk > 1:
         seen = cols <= (rows | np.int32(blk - 1))
     else:
@@ -346,15 +473,17 @@ def _causal_mask(s, row0, col0, keys_first=False, blk=1, before=False,
     return jnp.where(seen, s, NEG_INF)
 
 
-def _mask_lanes(s, lo, hi, row0, col0, keys_first=False, blk=1, before=False):
-    """Mask lanes [lo, hi) of a score strip, the sub-tiles the diagonal
-    crosses; the lanes beside them lie wholly under it and pass untouched."""
+def _mask_lanes(s, lo, hi, row0, col0, keys_first=False, blk=1, before=False,
+                window=None):
+    """Mask lanes [lo, hi) of a score strip, the sub-tiles the diagonal (or
+    the band's lower edge) crosses; the lanes beside them lie wholly inside
+    and pass untouched."""
     if lo == hi:
         return s
     parts = [s[:, :lo],
              _causal_mask(s[:, lo:hi], row0 + (lo if keys_first else 0),
                           col0 + (0 if keys_first else lo), keys_first, blk,
-                          before),
+                          before, window=window),
              s[:, hi:]]
     parts = [x for x in parts if x.shape[1]]
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
@@ -390,9 +519,9 @@ def _own_refs(refs, mask):
 
 
 def _key_parts(ref, own_ref, keys, own):
-    """[(ref, columns)] a q strip meets: the block's first ``keys`` keys and,
+    """[(ref, columns)] a q strip meets: the block's ``keys`` (a slice) and,
     for noised rows of the block mask, their own blocks' noised keys."""
-    parts = [(ref, slice(0, keys))] if keys else []
+    parts = [(ref, keys)] if keys.stop > keys.start else []
     return parts if own is None else parts + [(own_ref, own)]
 
 
@@ -411,14 +540,17 @@ def _across(x, parts, times):
     return total
 
 
-def _strip_mask(parts, keys, masked, own, row0, blk, noised):
+def _strip_mask(parts, keys, masked, own, row0, blk, noised, window=None):
     """A q strip's scores, part by part (``_key_parts``), as one masked
-    array: the clean keys' lanes from ``masked`` on under the (rounded)
-    diagonal, the own blocks' lanes to the row's own block."""
+    array: the clean keys' lanes from ``masked[1]`` on under the (rounded)
+    diagonal and before ``masked[0]`` inside the band's lower edge, the own
+    blocks' lanes to the row's own block."""
     out = []
-    if keys:
-        out.append(_mask_lanes(parts[0], masked, keys, row0, 0, blk=blk,
-                               before=noised))
+    if keys.stop > keys.start:
+        (lo, hi), c0 = masked, keys.start
+        s = _mask_lanes(parts[0], hi - c0, keys.stop - c0, row0, c0, blk=blk,
+                        before=noised, window=window)
+        out.append(_mask_lanes(s, 0, lo - c0, row0, c0, window=window))
     if own is not None:
         out.append(_causal_mask(parts[-1], row0, own.start, blk=blk, own=True))
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
@@ -450,7 +582,8 @@ def _finish(m, l, acc, o_ref, lse_ref, rows):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
-                scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None):
+                scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None,
+                window=None):
     # mask = (q blocks a half, blk) or None
     (kn_ref, vn_ref), (o_ref, lse_ref, *scratch) = _own_refs(refs, mask)
     blk = mask[1] if mask else 1
@@ -475,10 +608,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
 
     def walk(off, noised=False):
         for (rows, keys, masked, own), s in _ahead(
-                _q_strips(off, bq, bk, sq, sk, blk, noised), scores):
+                _q_strips(off, bq, bk, sq, sk, blk, noised, window), scores):
             n = rows.stop - rows.start
             s = _strip_mask(s, keys, masked, own, (off or 0) + rows.start,
-                            blk, noised)
+                            blk, noised, window)
             m = jnp.max(s, axis=-1, keepdims=True)
             if scratch:
                 m_prev = m_scr[rows, :1]
@@ -497,11 +630,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs,
 
     if mask:
         _at_stream_block(mask[0], n_k, 1, 2, bq, bk, walk)
+    elif window:
+        j = pl.program_id(1)
+        kk = _band_keys(j, bq, bk, n_k, window)[0] + pl.program_id(2)
+        _at_band_offset(n_q, n_k, bq, bk, window, j * bq - kk * bk, walk)
     else:
         _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
 
     if scratch:
-        @pl.when(pl.program_id(2) == n_k - 1)
+        @pl.when(pl.program_id(2)
+                 == _key_steps(n_q, n_k, bq, bk, window) - 1)
         def _():
             _finish(m_scr[:, :1], l_scr[:, :1], acc_scr[:], o_ref, lse_ref,
                     slice(None))
@@ -517,11 +655,11 @@ def _grid_of(s, bq, bk, mask):
     return s // bq, half // bk, (half // bq, blk)
 
 
-def _kv_specs(causal, bq, bk, d, n_k, group, mask):
+def _kv_specs(causal, bq, bk, d, n_k, group, mask, window=None):
     """The in_specs of k and v under grid (head, q block, k block), and of
     the noised blocks that ride along under the block mask."""
     n_qh = mask[0] if mask else None
-    kv_index = _kv_index(causal, bq, bk, n_k, group, n_qh)
+    kv_index = _kv_index(causal, bq, bk, n_k, group, n_qh, window=window)
     specs = [pl.BlockSpec((1, bk, d), kv_index)] * 2
     if mask:
         own = _kv_index(causal, bq, bk, n_k, group, n_qh, own=True)
@@ -529,21 +667,24 @@ def _kv_specs(causal, bq, bk, d, n_k, group, mask):
     return specs
 
 
-def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1, mask=None):
+def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1, mask=None,
+         window=None):
     bh, s, d = q.shape
     n_q, n_k, mask = _grid_of(s, bq, bk, mask)
-    kv = _kv_specs(causal, bq, bk, d, n_k, group, mask)
+    steps = _key_steps(n_q, n_k, bq, bk, window)
+    kv = _kv_specs(causal, bq, bk, d, n_k, group, mask, window)
     # one k step: the running (m, l, acc) never leave the step's registers
-    scratch = [] if n_k == 1 else [
+    scratch = [] if steps == 1 else [
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, d), jnp.float32),
     ]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask),
-        name="flash_attention_fwd",
-        grid=(bh, n_q, n_k),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask,
+                          window=window),
+        name="flash_attention_window_fwd" if window else "flash_attention_fwd",
+        grid=(bh, n_q, steps),
         in_specs=[pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)), *kv],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
@@ -565,7 +706,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group=1, mask=None):
 # ---------------------------------------------------------------------------
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs,
                     scale, causal, bq, bk, sq, sk, n_q, n_k, group=1,
-                    mask=None):
+                    mask=None, window=None):
     # under the block mask the noised key block has gradients of its own
     # (dkn, dvn): its keys are met by the noised rows of the same blocks alone
     (kn_ref, vn_ref), (do_ref, lse_ref, delta_ref, *refs) = _own_refs(refs,
@@ -592,14 +733,18 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs,
     def walk(off, noised=False):
         times_do, times_q = _times(do_ref), _times(q_ref)
         for (cols, rows, masked, own), (st, dpt) in _ahead(
-                _k_strips(off, bq, bk, sq, sk, blk, noised), products):
+                _k_strips(off, bq, bk, sq, sk, blk, noised, window),
+                products):
             if own:
                 st = _causal_mask(st, off + rows.start, cols.start,
                                   keys_first=True, blk=blk, own=True)
             else:
-                st = _mask_lanes(st, 0, masked, (off or 0) + rows.start,
-                                 cols.start, keys_first=True, blk=blk,
-                                 before=noised)
+                row0 = (off or 0) + rows.start
+                st = _mask_lanes(st, 0, masked[0], row0, cols.start,
+                                 keys_first=True, blk=blk, before=noised,
+                                 window=window)
+                st = _mask_lanes(st, masked[1], rows.stop - rows.start, row0,
+                                 cols.start, keys_first=True, window=window)
             pt = jnp.exp(st - lse_ref[0, :, rows])
             dst = pt * (dpt - delta_ref[0, :, rows])
             dv = times_do(pt.astype(do_ref.dtype), rows)
@@ -612,21 +757,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, *refs,
                 outs[to][0, cols, :] = dk.astype(outs[to].dtype)
                 outs[to + 1][0, cols, :] = dv.astype(outs[to + 1].dtype)
 
+    steps = _query_steps(n_q, n_k, bq, bk, window)
     if mask:
         _at_stream_block(mask[0], n_k, 2, 1, bq, bk, walk, q_wraps=group > 1)
+    elif window:
+        kk, t = pl.program_id(1), pl.program_id(2)
+        if group > 1:
+            t = jax.lax.rem(t, np.int32(steps))
+        first, last = _band_queries(kk, bq, bk, n_q, window)
+        j = first + t
+        # a step past the sequence's last q block refetched that block: it
+        # is given an offset the band does not touch
+        off = jnp.where(j <= last, j * bq - kk * bk,
+                        np.int32(window + bk - 1))
+        _at_band_offset(n_q, n_k, bq, bk, window, off, walk)
     else:
         _at_block_offset(n_q, n_k, 2, 1, bq, bk, causal, walk,
                          q_wraps=group > 1)
 
     if scratch:
-        @pl.when(pl.program_id(2) == group * n_q - 1)
+        @pl.when(pl.program_id(2) == group * steps - 1)
         def _():
             for out, scr in zip(outs, scratch):
                 out[0] = scr[:].astype(out.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs,
-                   scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None):
+                   scale, causal, bq, bk, sq, sk, n_q, n_k, mask=None,
+                   window=None):
     (kn_ref, vn_ref), (do_ref, lse_ref, delta_ref, dq_ref, *scratch) = \
         _own_refs(refs, mask)
     blk = mask[1] if mask else 1
@@ -650,9 +808,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs,
         times_k = _times(k_ref)
         times_kn = _times(kn_ref) if noised else None
         for (rows, keys, masked, own), (s, dp) in _ahead(
-                _q_strips(off, bq, bk, sq, sk, blk, noised), products):
+                _q_strips(off, bq, bk, sq, sk, blk, noised, window),
+                products):
             s = _strip_mask(s, keys, masked, own, (off or 0) + rows.start,
-                            blk, noised)
+                            blk, noised, window)
             p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
             ds = p * (dp - delta_ref[0, 0, rows][:, None])
             dq = _across((ds * scale).astype(k_ref.dtype),
@@ -666,19 +825,25 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, *refs,
 
     if mask:
         _at_stream_block(mask[0], n_k, 1, 2, bq, bk, walk)
+    elif window:
+        j = pl.program_id(1)
+        kk = _band_keys(j, bq, bk, n_k, window)[0] + pl.program_id(2)
+        _at_band_offset(n_q, n_k, bq, bk, window, j * bq - kk * bk, walk)
     else:
         _at_block_offset(n_q, n_k, 1, 2, bq, bk, causal, walk)
 
     if scratch:
-        @pl.when(pl.program_id(2) == n_k - 1)
+        @pl.when(pl.program_id(2)
+                 == _key_steps(n_q, n_k, bq, bk, window) - 1)
         def _():
             dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
-         mask=None):
+         mask=None, window=None):
     bh, s, d = k.shape  # the grid's heads are the KV heads
     n_q, n_k, mask = _grid_of(s, bq, bk, mask)
+    steps = _query_steps(n_q, n_k, bq, bk, window)
 
     def q_index(kk, j):
         # a q block whose every row is above this k block's first column is
@@ -688,6 +853,9 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
             j_first, _ = _query_walk(kk * bk, bk, 0, bq, mask[0])
             noised = jax.lax.div(j, n_qh)
             return jnp.maximum(j - noised * n_qh, j_first) + noised * n_qh
+        if window:  # the q blocks from the first whose rows reach kk
+            first, last = _band_queries(kk, bq, bk, n_q, window)
+            return jnp.minimum(first + j, last)
         if causal and n_q > 1:
             j_first, _ = _query_walk(kk * bk, bk, 0, bq, n_q)
             j = jnp.maximum(j, j_first)
@@ -701,24 +869,25 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
             return q_index(kk, t)
     else:
         # the last grid axis walks the group's query heads, each over its q
-        # blocks: KV head i is attended by query heads i * group + t // n_q
+        # blocks: KV head i is attended by query heads i * group + t // steps
         def q_head(i, t):
-            return i * np.int32(group) + jax.lax.div(t, np.int32(n_q))
+            return i * np.int32(group) + jax.lax.div(t, np.int32(steps))
 
         def q_block(kk, t):
-            return q_index(kk, jax.lax.rem(t, np.int32(n_q)))
+            return q_index(kk, jax.lax.rem(t, np.int32(steps)))
 
     # one q step: dk and dv never leave the step's registers
-    scratch = [] if group * n_q == 1 else [pltpu.VMEM((bk, d), jnp.float32),
+    scratch = [] if group * steps == 1 else [pltpu.VMEM((bk, d), jnp.float32),
                                            pltpu.VMEM((bk, d), jnp.float32)]
     own = [pl.BlockSpec((1, bk, d), lambda i, kk, j: (i, n_k + kk, _0))] * 2
     out_rows = n_k * bk  # under the block mask: a half's keys an output
     got = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, group=group,
-                          mask=mask),
-        name="flash_attention_bwd_dkv",
-        grid=(bh, n_k, group * n_q),
+                          mask=mask, window=window),
+        name=("flash_attention_window_bwd_dkv" if window
+              else "flash_attention_bwd_dkv"),
+        grid=(bh, n_k, group * steps),
         in_specs=[
             pl.BlockSpec((1, bq, d),
                          lambda i, kk, j: (q_head(i, j), q_block(kk, j), _0)),
@@ -751,18 +920,21 @@ def _dkv(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
 
 
 def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
-        mask=None):
+        mask=None, window=None):
     bh, s, d = q.shape
     n_q, n_k, mask = _grid_of(s, bq, bk, mask)
-    scratch = [] if n_k == 1 else [pltpu.VMEM((bq, d), jnp.float32)]
+    steps = _key_steps(n_q, n_k, bq, bk, window)
+    scratch = [] if steps == 1 else [pltpu.VMEM((bq, d), jnp.float32)]
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal, bq=bq,
-                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask),
-        name="flash_attention_bwd_dq",
-        grid=(bh, n_q, n_k),
+                          bk=bk, sq=sq, sk=sk, n_q=n_q, n_k=n_k, mask=mask,
+                          window=window),
+        name=("flash_attention_window_bwd_dq" if window
+              else "flash_attention_bwd_dq"),
+        grid=(bh, n_q, steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
-            *_kv_specs(causal, bq, bk, d, n_k, group, mask),
+            *_kv_specs(causal, bq, bk, d, n_k, group, mask, window),
             pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, _0)),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, _0, j)),
             pl.BlockSpec((1, 1, bq), lambda i, j, kk: (i, _0, j)),
@@ -775,11 +947,11 @@ def _dq(q, k, v, do, lse, delta, *, scale, causal, bq, bk, sq, sk, group=1,
     )(q, k, v, *((k, v) if mask else ()), do, lse, delta)
 
 
-def _bwd(scale, causal, bq, bk, sq, sk, group, mask, res, do):
+def _bwd(scale, causal, bq, bk, sq, sk, group, mask, window, res, do):
     q, k, v, out, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, None, :]
     sizes = dict(scale=scale, causal=causal, bq=bq, bk=bk, sq=sq, sk=sk,
-                 group=group, mask=mask)
+                 group=group, mask=mask, window=window)
     dk, dv = _dkv(q, k, v, do, lse, delta, **sizes)
     dq = _dq(q, k, v, do, lse, delta, **sizes)
     return dq, dk, dv
@@ -788,26 +960,33 @@ def _bwd(scale, causal, bq, bk, sq, sk, group, mask, res, do):
 # ---------------------------------------------------------------------------
 # public entry
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, scale, causal, bq, bk, sq, sk, group, mask):
-    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask)
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, scale, causal, bq, bk, sq, sk, group, mask, window):
+    out, _ = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask, window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask):
-    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask)
+def _flash_fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask, window):
+    out, lse = _fwd(q, k, v, scale, causal, bq, bk, sq, sk, group, mask,
+                    window)
     return out, (q, k, v, out, lse)
 
 
 _flash.defvjp(_flash_fwd, _bwd)
 
 
-def supports(seq_len: int, head_dim: int, block_q: int = None, block_k: int = 1024) -> bool:
+def supports(seq_len: int, head_dim: int, block_q: int = None,
+             block_k: int = 1024, window: int = None) -> bool:
     """Shapes the kernel accepts (everything else falls back to the XLA path).
 
     The kernel covers the sequence either with one full-array block
     (seq <= block) or with an exact tiling — a seq that is neither would
-    leave tail rows unwritten, so it must be rejected here."""
+    leave tail rows unwritten, so it must be rejected here. A ``window``
+    (a sliding window of that many keys) is walked at any width of 1 or
+    more."""
+    if window is not None and window < 1:
+        return False
     if block_q is None:
         block_q = _default_block_q(seq_len)
     bq = min(block_q, seq_len)
@@ -845,7 +1024,7 @@ def supports_block_mask(seq_len: int, head_dim: int, block_mask) -> bool:
 
 
 def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
-                    block_k=1024, block_mask=None):
+                    block_k=1024, block_mask=None, window=None):
     """Streaming attention over [batch, seq, heads, head_dim] inputs
     (paddle fused_attention layout, matching scaled_dot_product_attention).
 
@@ -862,6 +1041,13 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
     own blocks rides along with the step the diagonal crosses and its
     sub-tiles on the block diagonal join that step's strips. The quadrant
     clean-on-noised is never fetched; no position-squared array exists.
+
+    ``window`` = W, with ``causal``: a sliding window, query i attends key j
+    iff i - W < j <= i (the ``transformers`` convention: W keys, its own
+    included). The kernels walk only the band: key blocks before it are no
+    grid step and are never fetched, and the sub-tiles its lower edge
+    crosses are masked as the diagonal's are. A window of the whole
+    sequence or more is the causal walk.
 
     Grouped-query heads: k and v may have fewer heads than q, a divisor of
     q's; query head i attends KV head i // group through the kernels' index
@@ -881,6 +1067,14 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
             f"flash_attention: {h} query heads on k {tuple(k.shape)} / v "
             f"{tuple(v.shape)}: the KV heads must divide the query heads")
     group = h // h_kv
+    if window is not None:
+        window = int(window)
+        if block_mask is not None or not causal or window < 1:
+            raise ValueError(
+                f"flash_attention: a window ({window}) is a causal band of "
+                "one or more keys, with no block mask")
+        if window >= s:
+            window = None
     if block_mask is not None:
         block_mask = tuple(map(int, block_mask))
         blocks = _mask_blocks(s, block_mask, block_q, block_k)
@@ -906,9 +1100,10 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
 
     from ...profiler import trace
     run, masked, total = causal_tile_counts(s, bq, bk, sq, sk, bool(causal),
-                                            block_mask)
+                                            block_mask, window)
     kind = ({"mask": "block_diffusion", "half": block_mask[0],
              "block": block_mask[1]} if block_mask
+            else {"mask": "window", "window": window} if window
             else {"mask": "causal" if causal else "full"})
     trace.emit("flash_tiles", site="flash_attention", seq=s, block_q=bq,
                block_k=bk, sub_q=sq, sub_k=sk, run=run, masked=masked,
@@ -918,5 +1113,5 @@ def flash_attention(q, k, v, *, scale=None, causal=True, block_q=None,
         return jnp.swapaxes(x, 1, 2).reshape(b * x.shape[2], s, d)
 
     out = _flash(to_bh(q), to_bh(k), to_bh(v), np.float32(scale), bool(causal),
-                 bq, bk, sq, sk, group, block_mask)
+                 bq, bk, sq, sk, group, block_mask, window)
     return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)

@@ -571,3 +571,98 @@ def test_sdar_moe_train_step_compiles_for_v5e(one_chip, compiled_kernels,
     assert mem.temp_size_in_bytes <= 8_349_854_720, mem
     assert sum(int(np.prod(p.shape)) for p in step._params) == 645_623_296
     assert len(step._params) == 69
+
+
+# ---------------------------------------------------------------------------
+# the sliding-window sparse decoder at the shapes of the cell
+# mellum2-train-s8192 (2 x 8,192 tokens; benchmark/configs/mellum2-*)
+# ---------------------------------------------------------------------------
+def test_window_flash_compiles_for_v5e(one_chip, compiled_kernels):
+    """32 query heads on 4 KV heads of 128 over 2 x 8,192 under a window of
+    1,024 keys, forward and backward: the three windowed kernels by name, k
+    and v never copied out to the group, no array of positions squared."""
+    q = _sds((2, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((2, 8192, 4, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return fa.flash_attention(q, k, v, window=1024).astype(
+                jnp.float32).sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(fwd_bwd).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_window_fwd",
+                 "flash_attention_window_bwd_dkv",
+                 "flash_attention_window_bwd_dq"):
+        assert name in text
+    assert "8192,8192]" not in text
+    assert "bf16[2,8192,32,128]{3,2,1,0} broadcast" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+
+
+def test_mellum2_train_step_compiles_for_v5e(one_chip, compiled_kernels,
+                                             monkeypatch):
+    """The whole compile_train_step program of the cell, built from the
+    cell's own configuration and traffic files: published widths, one period
+    of four layers (three sliding-window, one full with YaRN), 8 of 64
+    experts and no shared expert, an eighth of the vocabulary, AMP O2 bf16,
+    AdamW, 2 x 8,192 tokens, nothing recomputed. It fits the chip and holds
+    both kinds of flash kernels under the two scopes and the grouped
+    products: no dense attention. (PERF.md section 4 has the memory it
+    reads.)"""
+    import json
+
+    import paddle_tpu.nn.initializer as I
+    from benchmark.lib import program_mellum2 as prog
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.models import GPTPretrainingCriterion
+
+    # shapes are all that matter here: skip drawing a billion normals
+    monkeypatch.setattr(I.Normal, "_generate",
+                        lambda self, shape, dtype: jnp.zeros(shape, dtype))
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(here, "configs",
+                           "mellum2-12b-a2.5b-ep8.json")) as f:
+        sizes = json.load(f)
+    with open(os.path.join(here, "workloads",
+                           "mellum2-train-s8192.json")) as f:
+        mix = json.load(f)["traffic"]
+    assert (sizes["num_hidden_layers"], sizes["recompute_mixer"],
+            mix["batch"], mix["seq"]) == (4, False, 2, 8192)
+    _, model = prog.build_model(sizes)
+    model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    crit = GPTPretrainingCriterion()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, lb: crit(lg.astype("float32"), lb), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((2, 8192), jnp.int32, one_chip)
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), _random.next_key(),
+            jnp.asarray(1e-4, jnp.float32), ids, ids)
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    compiled = step._build().lower(*specs).compile()
+    text = compiled.as_text()
+    for name in ("flash_attention_window_fwd",
+                 "flash_attention_window_bwd_dkv",
+                 "flash_attention_window_bwd_dq", "flash_attention_fwd",
+                 "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                 "sliding_attention", "full_attention", "ragged-dot"):
+        assert name in text, name
+    assert "8192,8192]" not in text
+    assert "shared_expert" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # 6.27 GB of 15.75: 2.04 GB of state, 4.23 GB of temporaries
+    assert total < 15.75e9, mem
+    assert mem.argument_size_in_bytes == 2_042_308_096, mem
+    assert mem.temp_size_in_bytes <= 4_231_492_096, mem
+    assert sum(int(np.prod(p.shape)) for p in step._params) == 340_349_184
+    assert len(step._params) == 39
