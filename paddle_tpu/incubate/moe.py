@@ -391,12 +391,6 @@ def _grouped_outer(left, right, sizes, dtype):
                                       preferred_element_type=dtype)
 
 
-def _swiglu_groups(xin, w_gate_up, sizes):
-    """The float32 halves (gate, up) of x W_gu, each row under its own
-    expert's weights."""
-    return jnp.split(_grouped(xin, w_gate_up, sizes), 2, axis=-1)
-
-
 def _swiglu(gate, up):
     return jax.nn.silu(gate) * up
 
@@ -452,6 +446,29 @@ def _combine(part, kept, total, tokens, block):
                           block=block)
 
 
+def _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+    """``held_experts_apply``'s output in float32, and the first pass's
+    float32 [rows, 2 d] gate-up product, which its backward takes in place
+    of making it again."""
+    tokens = x.shape[0]
+    block = _combine_plan("forward", rows, tokens, x.shape[1])
+
+    def one_pass(c, y, gate_up=None):
+        t, kept, w, valid, sizes, _ = _pass_rows(
+            c, rows, tok, wgt, offsets, tokens)
+        if gate_up is None:
+            gate_up = _grouped(x[t], w_gate_up, sizes)
+        act = jnp.where(valid, _swiglu(*jnp.split(gate_up, 2, axis=-1)) * w,
+                        0.0).astype(x.dtype)
+        return (_combine(_grouped(act, w_down, sizes), kept, y, tokens,
+                         block), gate_up)
+
+    y, gate_up = one_pass(np.int32(0), None)
+    y = jax.lax.fori_loop(np.int32(1), _n_passes(offsets, rows),
+                          lambda c, y: one_pass(c, y)[0], y)
+    return y, gate_up
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     """sum over a token's slots on held experts of weight * expert(x): x
@@ -460,7 +477,7 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     ``sort_held_slots``. The sorted slots pass through a buffer of ``rows``
     rows, ceil(routed / rows) times: the count is data, so the loop is a
     while loop and the backward (which walks the same passes, recomputing
-    each pass's activations) is written out, not derived.
+    each later pass's activations) is written out, not derived.
 
     What a pass writes to HBM between its gather and its combine: the
     gathered rows (x's dtype), the float32 [rows, 2 d] gate-up product, the
@@ -472,78 +489,93 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     result are not zero whatever it was given; the combine never reads them
     (``_combine``), and nothing multiplies them. The first pass, which always
     runs, is made before the loop: its combine writes every token, so no
-    zeros are written first."""
-    tokens = x.shape[0]
-    block = _combine_plan("forward", rows, tokens, x.shape[1])
-
-    def one_pass(c, y):
-        t, kept, w, valid, sizes, _ = _pass_rows(
-            c, rows, tok, wgt, offsets, tokens)
-        gate, up = _swiglu_groups(x[t], w_gate_up, sizes)
-        act = jnp.where(valid, _swiglu(gate, up) * w, 0.0).astype(x.dtype)
-        return _combine(_grouped(act, w_down, sizes), kept, y, tokens, block)
-
-    y = jax.lax.fori_loop(np.int32(1), _n_passes(offsets, rows), one_pass,
-                          one_pass(np.int32(0), None))
-    return y.astype(x.dtype)
+    zeros are written first; under differentiation its gate-up product is
+    kept for the backward."""
+    return _held_forward(x, wgt, w_gate_up, w_down, tok, offsets,
+                         rows)[0].astype(x.dtype)
 
 
 def _held_fwd(x, wgt, w_gate_up, w_down, tok, offsets, rows):
-    return (held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows),
-            (x, wgt, w_gate_up, w_down, tok, offsets))
+    # the kept product leaves with y (see the barriers in _held_bwd); y in
+    # float32, so that what reads it may still fuse its rounding. Measured,
+    # not derived: without this barrier the Qwen3-Next expert layer alone
+    # keeps 98 MB more (AOT compile for a v5e, PERF.md section 6)
+    y, gate_up = jax.lax.optimization_barrier(
+        _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows))
+    return y.astype(x.dtype), (x, wgt, w_gate_up, w_down, tok, offsets,
+                               gate_up)
 
 
 def _held_bwd(rows, res, dy):
-    """A pass of the backward: the gate-up product again, then four grouped
-    products, every operand in the rows' dtype and every sum float32 (seven
-    a pass with the forward's two, where a derived backward ran eight). With
-    g = dy[t] W_d^T, the unweighted [rows, d] cotangent, the slot weight's
-    gradient is sum(swiglu * g) and needs no second down product, and the
-    activation's is g * w; the weight goes on the activation's side of
-    W_d's gradient too, so dy's rows enter both products as gathered. The
-    [rows, 2 d] cotangent of the gate-up product is masked and rounded once,
-    where the SwiGLU's derivative is applied, for the two products that read
-    it. Each stack's gradient is written by its product in the stack's dtype
-    on the first pass, which always runs; a later pass adds to it in
-    float32. Unlike the forward's, the first pass stays inside the loop
-    (from zeros): made before it, the compiled step keeps more live (PERF.md
-    section 6, PR 35)."""
-    x, wgt, w_gate_up, w_down, tok, offsets = res
-    # measured, not derived: behind the barrier the compiled steps of the
-    # three sparse cells keep 28-671 MB less than their parents' did;
-    # without it the SDAR cell's keeps 106 MB more (AOT compile, PR 35)
-    x = jax.lax.optimization_barrier(x)
+    """The backward walks the forward's passes. A pass's gate-up product is
+    the one the forward kept on the first pass and is made again on a later
+    one; four grouped products follow, every operand in the rows' dtype and
+    every sum float32 (a one-pass step runs six with the forward's two,
+    where a derived backward ran eight). With g = dy[t] W_d^T, the
+    unweighted [rows, d] cotangent, the slot weight's gradient is
+    sum(swiglu * g) and needs no second down product, and the activation's
+    is g * w; the weight goes on the activation's side of W_d's gradient
+    too, so dy's rows enter both products as gathered. The [rows, 2 d]
+    cotangent of the gate-up product is masked and rounded once, where the
+    SwiGLU's derivative is applied, for the two products that read it.
+
+    The first pass, which always runs, is made before the loop: its combine
+    writes dx with no zeros first, and each stack's gradient is written by
+    its product in the stack's dtype. A later pass adds to the stacks in
+    float32."""
+    x, wgt, w_gate_up, w_down, tok, offsets, gate_up = res
+    # measured, not derived: without a barrier on x the compiled Granite
+    # step keeps 1.7 GB more; without the kept product behind it too, the
+    # Granite expert layer alone keeps 139 MB more (AOT compiles for a v5e,
+    # PERF.md section 6)
+    x, gate_up = jax.lax.optimization_barrier((x, gate_up))
     tokens = x.shape[0]
     block = _combine_plan("backward", rows, tokens, x.shape[1])
+    # one copy of each stack for its cotangent product, whichever pass reads
+    w_gate_up_t, w_down_t = (jnp.swapaxes(w, 1, 2)
+                             for w in (w_gate_up, w_down))
 
-    def summed(c, total, part):
-        return jax.lax.cond(
-            c == 0, lambda: part,
-            lambda: (total.astype(jnp.float32) + part).astype(total.dtype))
-
-    def one_pass(c, carry):
-        dx, dwgt, dgu, dd = carry
+    def one_pass(c, dx, gate_up=None):
         t, kept, w, valid, sizes, lo = _pass_rows(
             c, rows, tok, wgt, offsets, tokens)
         xin, dyt = x[t], dy[t]
-        gate, up = _swiglu_groups(xin, w_gate_up, sizes)
-        s, pull = jax.vjp(_swiglu, gate, up)
-        g = _grouped(dyt, jnp.swapaxes(w_down, 1, 2), sizes)
+        if gate_up is None:
+            gate_up = _grouped(xin, w_gate_up, sizes)
+        s, pull = jax.vjp(_swiglu, *jnp.split(gate_up, 2, axis=-1))
+        g = _grouped(dyt, w_down_t, sizes)
         dh = jnp.where(valid, jnp.concatenate(pull(g * w), axis=-1),
                        0.0).astype(x.dtype)
         act = jnp.where(valid, s * w, 0.0).astype(x.dtype)
-        dxin = _grouped(dh, jnp.swapaxes(w_gate_up, 1, 2), sizes)
-        dgu_c = _grouped_outer(xin, dh, sizes, dgu.dtype)
-        dd_c = _grouped_outer(act, dyt, sizes, dd.dtype)
         dw = jnp.where(valid[:, 0], (s * g).sum(-1), 0.0)
-        return (_combine(dxin, kept, dx, tokens, block),
-                jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
-                summed(c, dgu, dgu_c), summed(c, dd, dd_c))
+        # the order is measured, not derived: all that reads the gate-up
+        # product is made before the products that follow it, and both
+        # stacks' products before dx's, so that neither the product nor
+        # the gathered rows are live beside dx's float32 [rows, h] product
+        # and its combine, where the Granite step's first backward layer
+        # peaked: the Granite and Mellum2 steps keep 262 and 135 MB less
+        # (AOT compiles for a v5e, PERF.md section 6)
+        dh, act, dw = jax.lax.optimization_barrier((dh, act, dw))
+        dgu = _grouped_outer(xin, dh, sizes, w_gate_up.dtype)
+        dd = _grouped_outer(act, dyt, sizes, w_down.dtype)
+        dh, dgu, dd = jax.lax.optimization_barrier((dh, dgu, dd))
+        return (_combine(_grouped(dh, w_gate_up_t, sizes), kept, dx, tokens,
+                         block), dw, lo, dgu, dd)
 
+    def added(total, part):
+        return (total.astype(jnp.float32) + part).astype(total.dtype)
+
+    def later_pass(c, carry):
+        dx, dwgt, dgu, dd = carry
+        dx, dw, lo, dgu_c, dd_c = one_pass(c, dx)
+        return (dx, jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
+                added(dgu, dgu_c), added(dd, dd_c))
+
+    dx, dw, lo, dgu, dd = one_pass(np.int32(0), None, gate_up)
+    dwgt = jax.lax.dynamic_update_slice(jnp.zeros(wgt.shape, jnp.float32),
+                                        dw, (lo,))
     dx, dwgt, dgu, dd = jax.lax.fori_loop(
-        np.int32(0), _n_passes(offsets, rows), one_pass,
-        (jnp.zeros(x.shape, jnp.float32), jnp.zeros(wgt.shape, jnp.float32),
-         jnp.zeros_like(w_gate_up), jnp.zeros_like(w_down)))
+        np.int32(1), _n_passes(offsets, rows), later_pass,
+        (dx, dwgt, dgu, dd))
     return dx.astype(x.dtype), dwgt.astype(wgt.dtype), dgu, dd, None, None
 
 
@@ -582,7 +614,8 @@ def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
     tokens, count = x.shape[0], w_gate_up.shape[0]
     trace.emit("moe_route", site="dropless_experts", held=count,
                num_experts=router.shape[1], top_k=top_k, buffer_rows=rows,
-               tokens=tokens)
+               tokens=tokens,
+               saved_gate_up_bytes=rows * w_gate_up.shape[2] * 4)
     with jax.named_scope("router"):
         logits = jnp.matmul(x, router, preferred_element_type=jnp.float32)
         w, idx = route_top_k(logits, top_k, renormalize)

@@ -3,6 +3,7 @@
 held-expert layer — against the plain reference kept with the benchmark
 (benchmark/lib/reference_qwen3_next.py: token-by-token recurrence, dense
 masked softmax, experts as masks), at small sizes on the CPU in float32."""
+import functools
 import importlib
 
 import jax
@@ -562,16 +563,17 @@ def garbage_past_the_groups(monkeypatch):
 @pytest.mark.parametrize("h", [32, 128], ids=["xla_combine", "kernel"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("passes", [1, 3])
+@pytest.mark.parametrize("passes", [1, 2, 3])
 def test_output_and_every_gradient_in_one_pass_and_in_three(
         garbage_past_the_groups, dtype, passes, h):
     """The layer's output and the gradient of x, of the router (which gets
     it through the slot weights), of both stacks and of the shared expert
     against the reference on the same numbers, within one rounding of the
     dtype: the slot weight's gradient from the d-wide cotangent, the stacks'
-    gradients written by the first pass and added to by the later two. At a
-    width of 128 lanes the combine is the kernel (interpreted), at 32 XLA's
-    scatter-add."""
+    gradients written by the first pass (from the gate-up product the
+    forward kept) and added to by the later ones, which make theirs again.
+    At a width of 128 lanes the combine is the kernel (interpreted), at 32
+    XLA's scatter-add."""
     held, top_k, tokens, first = 4, 4, 64, 4
     w = {k: a.astype(dtype) for k, a in expert_weights(h=h).items()}
     rng = np.random.default_rng(11)
@@ -603,6 +605,112 @@ def test_output_and_every_gradient_in_one_pass_and_in_three(
         # the shared expert's bf16 chain (and x through it) rounds at every op
         routed_alone = name in ("router", "egu_w", "ed_w")
         assert_close(a, c, dtype, name, roundings=1 if routed_alone else 2)
+
+
+HELD_EXPERTS_APPLY = moe.held_experts_apply
+
+
+def _every_pass_in_the_loop_bwd(rows, res, dy):
+    """The layer's written-out backward as it stood before its first pass
+    took the forward's gate-up product, kept here to hold the new one to
+    it: every pass inside the loop from zeros, each making its gate-up
+    product again, and each stack's gradient taken out of a pass-index
+    conditional."""
+    x, wgt, w_gate_up, w_down, tok, offsets = res
+    x = jax.lax.optimization_barrier(x)
+    tokens = x.shape[0]
+    block = moe._combine_plan("backward", rows, tokens, x.shape[1])
+
+    def summed(c, total, part):
+        return jax.lax.cond(
+            c == 0, lambda: part,
+            lambda: (total.astype(jnp.float32) + part).astype(total.dtype))
+
+    def one_pass(c, carry):
+        dx, dwgt, dgu, dd = carry
+        t, kept, w, valid, sizes, lo = moe._pass_rows(
+            c, rows, tok, wgt, offsets, tokens)
+        xin, dyt = x[t], dy[t]
+        gate, up = jnp.split(moe._grouped(xin, w_gate_up, sizes), 2, axis=-1)
+        s, pull = jax.vjp(moe._swiglu, gate, up)
+        g = moe._grouped(dyt, jnp.swapaxes(w_down, 1, 2), sizes)
+        dh = jnp.where(valid, jnp.concatenate(pull(g * w), axis=-1),
+                       0.0).astype(x.dtype)
+        act = jnp.where(valid, s * w, 0.0).astype(x.dtype)
+        dxin = moe._grouped(dh, jnp.swapaxes(w_gate_up, 1, 2), sizes)
+        dgu_c = moe._grouped_outer(xin, dh, sizes, dgu.dtype)
+        dd_c = moe._grouped_outer(act, dyt, sizes, dd.dtype)
+        dw = jnp.where(valid[:, 0], (s * g).sum(-1), 0.0)
+        return (moe._combine(dxin, kept, dx, tokens, block),
+                jax.lax.dynamic_update_slice(dwgt, dw, (lo,)),
+                summed(c, dgu, dgu_c), summed(c, dd, dd_c))
+
+    dx, dwgt, dgu, dd = jax.lax.fori_loop(
+        np.int32(0), moe._n_passes(offsets, rows), one_pass,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros(wgt.shape, jnp.float32),
+         jnp.zeros_like(w_gate_up), jnp.zeros_like(w_down)))
+    return dx.astype(x.dtype), dwgt.astype(wgt.dtype), dgu, dd, None, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def every_pass_in_the_loop(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+    return HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows)
+
+
+every_pass_in_the_loop.defvjp(
+    lambda x, wgt, w_gate_up, w_down, tok, offsets, rows: (
+        HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows),
+        (x, wgt, w_gate_up, w_down, tok, offsets)),
+    _every_pass_in_the_loop_bwd)
+
+
+@pytest.mark.parametrize("h", [32, 128], ids=["xla_combine", "kernel"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("passes", [1, 2, 3])
+def test_saved_first_pass_is_bit_equal_to_every_pass_in_the_loop(
+        garbage_past_the_groups, monkeypatch, dtype, passes, h):
+    """The backward's first pass, made before the loop from the gate-up
+    product the forward kept, gives the layer's output and every gradient
+    (x, the router through the slot weights, both stacks, the shared
+    expert) bit for bit as the backward that made that product again inside
+    the loop: the same kernel on the same operands, NaN past the groups in
+    both and removed by the same selects. Both are compiled, as a step is
+    (run op by op, the first pass's float32 row sums are not the ones the
+    compiled loop made, by an ulp), and without excess precision: the CPU
+    makes a bf16 product in float32 and rounds it after, and may drop that
+    rounding where only a float32 add reads it, which the pass-index
+    conditional hid from it. On the chip the product is a kernel with a
+    bf16 result, so there is no rounding to drop."""
+    held, top_k, tokens, first = 4, 4, 64, 4
+    w = {k: a.astype(dtype) for k, a in expert_weights(h=h, seed=5).items()}
+    rng = np.random.default_rng(17)
+    x = jnp.asarray(rng.standard_normal((tokens, h)), dtype)
+    ct = jnp.asarray(rng.standard_normal((tokens, h)), jnp.float32)
+    routed = int(program_experts(x, w, first, top_k, 256)[1])
+    rows = -(-routed // (8 * passes)) * 8
+    names = ["x"] + list(w)
+
+    def vjp():
+        def loss(*a):
+            y = program_experts(a[0], dict(zip(list(w), a[1:])), first,
+                                top_k, rows)[0]
+            return (y.astype(jnp.float32) * ct).sum(), y
+
+        args = (x, *w.values())
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, range(len(names)), has_aux=True)).lower(*args).compile(
+                compiler_options={"xla_allow_excess_precision": False})(*args)
+        return (y,) + grads
+
+    assert int(program_experts(x, w, first, top_k, rows)[2]) == passes * rows
+    got = vjp()
+    monkeypatch.setattr(moe, "held_experts_apply", every_pass_in_the_loop)
+    want = vjp()
+    for name, a, b in zip(["y"] + names, got, want):
+        assert a.dtype == b.dtype == dtype, name
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), err_msg=name)
 
 
 @pytest.mark.parametrize("h", [32, 128], ids=["xla_combine", "kernel"])
@@ -659,7 +767,8 @@ def test_counters_ride_the_compiled_step_and_one_event_a_compile(both):
     rows = moe.row_buffer_rows(tokens, top_k, SIZES["router_experts"], held)
     assert events[0].attrs == dict(
         held=held, num_experts=SIZES["router_experts"], top_k=top_k,
-        buffer_rows=rows, tokens=tokens)
+        buffer_rows=rows, tokens=tokens,
+        saved_gate_up_bytes=rows * 2 * SIZES["moe_intermediate_size"] * 4)
     load = model.routed_load()
     assert load[0][1] == want
     assert all(ran % rows == 0 and ran >= routed for _, routed, ran in load)
