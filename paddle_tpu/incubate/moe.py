@@ -341,6 +341,36 @@ def route_top_k(logits, top_k, renormalize):
     return w, idx.astype(jnp.int32)
 
 
+def route_sigmoid_top_k(logits, top_k, renormalize, bias, scale):
+    """(weights [T, k] float32, expert ids [T, k]) under sigmoid scores s =
+    sigmoid(logits) in float32: the experts are the ``top_k`` largest of s +
+    ``bias`` (a correction that takes part in the choice only, and takes no
+    gradient), the weights the chosen UNBIASED scores, renormalised to sum
+    to 1 (``renormalize``, with 1e-20 beside the sum) and times ``scale``."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * scale, idx.astype(jnp.int32)
+
+
+def expert_load(idx, num_experts):
+    """int32 [num_experts]: the slots each router output took of ``idx``
+    [T, k], held or not."""
+    outputs = jnp.arange(num_experts, dtype=idx.dtype)
+    return (idx.reshape(-1, 1) == outputs).sum(0, dtype=jnp.int32)
+
+
+def balance_score_bias(bias, load, rate):
+    """The correction bias after one step of the auxiliary-loss-free
+    balancing rule: each expert's bias moves by ``rate``, up where its
+    ``load`` is under the mean over all router outputs, down where over, not
+    at all where even (a sign step, in float32)."""
+    load = load.astype(jnp.float32)
+    return bias + np.float32(rate) * jnp.sign(load.mean() - load)
+
+
 def sort_held_slots(idx, first, count):
     """The T x k routing slots in the order the grouped products want them:
     slots on held experts [first, first + count) first, sorted by expert, the
@@ -395,6 +425,23 @@ def _swiglu(gate, up):
     return jax.nn.silu(gate) * up
 
 
+def _activate(act, h):
+    """An expert's activation of its first product, float32 [rows, 2 d]
+    gate | up for SwiGLU, [rows, d] for relu^2."""
+    if act == "swiglu":
+        return _swiglu(*jnp.split(h, 2, axis=-1))
+    return jnp.square(jax.nn.relu(h))
+
+
+def _activate_vjp(act, h):
+    """(the activation, the map from its cotangent to h's)."""
+    if act == "swiglu":
+        s, pull = jax.vjp(_swiglu, *jnp.split(h, 2, axis=-1))
+        return s, lambda g: jnp.concatenate(pull(g), axis=-1)
+    r = jax.nn.relu(h)
+    return jnp.square(r), lambda g: 2.0 * r * g
+
+
 def _pass_rows(c, rows, tok, wgt, offsets, tokens):
     """Pass c of the row buffer: its slots' tokens, the same with the rows
     that hold no routed slot sent past the last token (where the combine
@@ -446,10 +493,10 @@ def _combine(part, kept, total, tokens, block):
                           block=block)
 
 
-def _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+def _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows, act):
     """``held_experts_apply``'s output in float32, and the first pass's
-    float32 [rows, 2 d] gate-up product, which its backward takes in place
-    of making it again."""
+    float32 first product ([rows, 2 d] gate-up, or [rows, d] up), which its
+    backward takes in place of making it again."""
     tokens = x.shape[0]
     block = _combine_plan("forward", rows, tokens, x.shape[1])
 
@@ -458,9 +505,9 @@ def _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows):
             c, rows, tok, wgt, offsets, tokens)
         if gate_up is None:
             gate_up = _grouped(x[t], w_gate_up, sizes)
-        act = jnp.where(valid, _swiglu(*jnp.split(gate_up, 2, axis=-1)) * w,
-                        0.0).astype(x.dtype)
-        return (_combine(_grouped(act, w_down, sizes), kept, y, tokens,
+        a = jnp.where(valid, _activate(act, gate_up) * w,
+                      0.0).astype(x.dtype)
+        return (_combine(_grouped(a, w_down, sizes), kept, y, tokens,
                          block), gate_up)
 
     y, gate_up = one_pass(np.int32(0), None)
@@ -469,10 +516,14 @@ def _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     return y, gate_up
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                       act="swiglu"):
     """sum over a token's slots on held experts of weight * expert(x): x
-    [T, h]; ``tok`` / ``wgt`` the token and weight of each slot in sorted
+    [T, h]; the expert is W_d^T swiglu(W_gu^T x) over a [held, h, 2 d]
+    ``w_gate_up`` stack (gate | up) with ``act`` "swiglu", or W_d^T
+    relu(W_u^T x)^2 over a [held, h, d] up stack with ``act`` "relu2";
+    ``tok`` / ``wgt`` the token and weight of each slot in sorted
     order, padded to a multiple of ``rows``; ``offsets`` from
     ``sort_held_slots``. The sorted slots pass through a buffer of ``rows``
     rows, ceil(routed / rows) times: the count is data, so the loop is a
@@ -480,8 +531,9 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     each later pass's activations) is written out, not derived.
 
     What a pass writes to HBM between its gather and its combine: the
-    gathered rows (x's dtype), the float32 [rows, 2 d] gate-up product, the
-    weighted activation (x's dtype, [rows, d]) and the down product's float32
+    gathered rows (x's dtype), the float32 first product ([rows, 2 d] or
+    [rows, d]), the weighted activation (x's dtype, [rows, d]; relu^2 made
+    from the float32 product, then rounded once) and the down product's float32
     [rows, h] result, which the combine reads as it is. The slot weight and
     the mask sit on the d-wide activation, never on a [rows, h] array. The
     mask is a select there AND an index: the grouped product skips the tiles
@@ -489,35 +541,36 @@ def held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows):
     result are not zero whatever it was given; the combine never reads them
     (``_combine``), and nothing multiplies them. The first pass, which always
     runs, is made before the loop: its combine writes every token, so no
-    zeros are written first; under differentiation its gate-up product is
+    zeros are written first; under differentiation its first product is
     kept for the backward."""
-    return _held_forward(x, wgt, w_gate_up, w_down, tok, offsets,
-                         rows)[0].astype(x.dtype)
+    return _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                         act)[0].astype(x.dtype)
 
 
-def _held_fwd(x, wgt, w_gate_up, w_down, tok, offsets, rows):
+def _held_fwd(x, wgt, w_gate_up, w_down, tok, offsets, rows, act):
     # the kept product leaves with y (see the barriers in _held_bwd); y in
     # float32, so that what reads it may still fuse its rounding. Measured,
     # not derived: without this barrier the Qwen3-Next expert layer alone
     # keeps 98 MB more (AOT compile for a v5e, PERF.md section 6)
     y, gate_up = jax.lax.optimization_barrier(
-        _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows))
+        _held_forward(x, wgt, w_gate_up, w_down, tok, offsets, rows, act))
     return y.astype(x.dtype), (x, wgt, w_gate_up, w_down, tok, offsets,
                                gate_up)
 
 
-def _held_bwd(rows, res, dy):
-    """The backward walks the forward's passes. A pass's gate-up product is
+def _held_bwd(rows, act, res, dy):
+    """The backward walks the forward's passes. A pass's first product is
     the one the forward kept on the first pass and is made again on a later
     one; four grouped products follow, every operand in the rows' dtype and
     every sum float32 (a one-pass step runs six with the forward's two,
     where a derived backward ran eight). With g = dy[t] W_d^T, the
     unweighted [rows, d] cotangent, the slot weight's gradient is
-    sum(swiglu * g) and needs no second down product, and the activation's
+    sum(act * g) and needs no second down product, and the activation's
     is g * w; the weight goes on the activation's side of W_d's gradient
-    too, so dy's rows enter both products as gathered. The [rows, 2 d]
-    cotangent of the gate-up product is masked and rounded once, where the
-    SwiGLU's derivative is applied, for the two products that read it.
+    too, so dy's rows enter both products as gathered. The cotangent of the
+    first product ([rows, 2 d] for SwiGLU; [rows, d], 2 relu(u) g w, for
+    relu^2) is masked and rounded once, where the activation's derivative
+    is applied, for the two products that read it.
 
     The first pass, which always runs, is made before the loop: its combine
     writes dx with no zeros first, and each stack's gradient is written by
@@ -541,11 +594,10 @@ def _held_bwd(rows, res, dy):
         xin, dyt = x[t], dy[t]
         if gate_up is None:
             gate_up = _grouped(xin, w_gate_up, sizes)
-        s, pull = jax.vjp(_swiglu, *jnp.split(gate_up, 2, axis=-1))
+        s, pull = _activate_vjp(act, gate_up)
         g = _grouped(dyt, w_down_t, sizes)
-        dh = jnp.where(valid, jnp.concatenate(pull(g * w), axis=-1),
-                       0.0).astype(x.dtype)
-        act = jnp.where(valid, s * w, 0.0).astype(x.dtype)
+        dh = jnp.where(valid, pull(g * w), 0.0).astype(x.dtype)
+        a = jnp.where(valid, s * w, 0.0).astype(x.dtype)
         dw = jnp.where(valid[:, 0], (s * g).sum(-1), 0.0)
         # the order is measured, not derived: all that reads the gate-up
         # product is made before the products that follow it, and both
@@ -554,9 +606,9 @@ def _held_bwd(rows, res, dy):
         # and its combine, where the Granite step's first backward layer
         # peaked: the Granite and Mellum2 steps keep 262 and 135 MB less
         # (AOT compiles for a v5e, PERF.md section 6)
-        dh, act, dw = jax.lax.optimization_barrier((dh, act, dw))
+        dh, a, dw = jax.lax.optimization_barrier((dh, a, dw))
         dgu = _grouped_outer(xin, dh, sizes, w_gate_up.dtype)
-        dd = _grouped_outer(act, dyt, sizes, w_down.dtype)
+        dd = _grouped_outer(a, dyt, sizes, w_down.dtype)
         dh, dgu, dd = jax.lax.optimization_barrier((dh, dgu, dd))
         return (_combine(_grouped(dh, w_gate_up_t, sizes), kept, dx, tokens,
                          block), dw, lo, dgu, dd)
@@ -600,84 +652,149 @@ def row_buffer_rows(tokens, top_k, num_experts, held):
     return max(8, min(rows, -(-tokens * top_k // 8) * 8))
 
 
+def _check_router(router, score_bias, routed_scale):
+    if router not in ("softmax", "sigmoid"):
+        raise ValueError(f"router {router!r}")
+    if (score_bias is None) != (router == "softmax"):
+        raise ValueError("a sigmoid router takes a score_bias, a softmax "
+                         f"one none: router {router!r}, score_bias "
+                         f"{'none' if score_bias is None else 'given'}")
+    if router == "softmax" and routed_scale != 1:
+        raise ValueError(f"routed_scale {routed_scale} under a softmax router")
+
+
 def dropless_experts(x, router, w_gate_up, w_down, shared_gate_up,
-                     shared_down, shared_gate, *, first, top_k, renormalize,
-                     rows):
+                     shared_down, shared_gate, score_bias=None, *, first,
+                     top_k, renormalize, rows, act="swiglu",
+                     router_kind="softmax", routed_scale=1.0):
     """The whole expert layer on [T, h] tokens: (y, routed_slots,
-    expert_rows). Routes over all of the router's experts; adds, for each
-    token, its held experts' weighted outputs and the shared expert, gated
-    by sigmoid(x ``shared_gate``) or, with ``shared_gate`` None, as it is;
-    with ``shared_gate_up`` None there is no shared expert and nothing is
-    added. What the absent experts would have added is left out."""
+    expert_rows), and under the sigmoid router the slots each router output
+    took (``expert_load``). Routes over all of the router's experts by
+    ``router_kind``: "softmax", or "sigmoid" with a ``score_bias``
+    (``route_sigmoid_top_k``, the weights times ``routed_scale``); adds, for
+    each token, its held experts' weighted outputs and the shared expert,
+    gated by sigmoid(x ``shared_gate``) or, with ``shared_gate`` None, as it
+    is; with ``shared_gate_up`` None there is no shared expert and nothing
+    is added. ``act`` is every expert's activation, the shared one's too:
+    "swiglu" (``w_gate_up`` and ``shared_gate_up`` gate | up) or "relu2" (up
+    alone). What the absent experts would have added is left out."""
     from ..profiler import trace
 
+    _check_router(router_kind, score_bias, routed_scale)
     tokens, count = x.shape[0], w_gate_up.shape[0]
     trace.emit("moe_route", site="dropless_experts", held=count,
                num_experts=router.shape[1], top_k=top_k, buffer_rows=rows,
                tokens=tokens,
-               saved_gate_up_bytes=rows * w_gate_up.shape[2] * 4)
+               saved_first_product_bytes=rows * w_gate_up.shape[2] * 4,
+               act=act, router=router_kind, routed_scale=routed_scale)
     with jax.named_scope("router"):
         logits = jnp.matmul(x, router, preferred_element_type=jnp.float32)
-        w, idx = route_top_k(logits, top_k, renormalize)
+        if router_kind == "softmax":
+            w, idx = route_top_k(logits, top_k, renormalize)
+        else:
+            w, idx = route_sigmoid_top_k(logits, top_k, renormalize,
+                                         score_bias, routed_scale)
+            load = expert_load(idx, router.shape[1])
         order, inverse, offsets = sort_held_slots(idx, first, count)
         pad = -(-order.shape[0] // rows) * rows - order.shape[0]
         tok = jnp.pad(order // np.int32(top_k), (0, pad))
         wgt = jnp.pad(_permute(w.reshape(-1), order, inverse), (0, pad))
         routed = offsets[-1]
     with jax.named_scope("experts"):
-        y = held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows)
-    if shared_gate_up is None:
-        return y, routed, _n_passes(offsets, rows) * np.int32(rows)
-    with jax.named_scope("shared_expert"):
-        gate, up = jnp.split(jnp.matmul(x, shared_gate_up), 2, axis=-1)
-        shared = jnp.matmul(jax.nn.silu(gate) * up, shared_down)
-        if shared_gate is not None:
-            open_ = jax.nn.sigmoid(jnp.matmul(
-                x, shared_gate, preferred_element_type=jnp.float32))
-            shared = (shared * open_).astype(x.dtype)
-        y = y + shared
-    return y, routed, _n_passes(offsets, rows) * np.int32(rows)
+        y = held_experts_apply(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                               act)
+    if shared_gate_up is not None:
+        with jax.named_scope("shared_expert"):
+            shared = jnp.matmul(
+                _activate(act, jnp.matmul(x, shared_gate_up)), shared_down)
+            if shared_gate is not None:
+                open_ = jax.nn.sigmoid(jnp.matmul(
+                    x, shared_gate, preferred_element_type=jnp.float32))
+                shared = (shared * open_).astype(x.dtype)
+            y = y + shared
+    out = (y, routed, _n_passes(offsets, rows) * np.int32(rows))
+    return out if router_kind == "softmax" else out + (load,)
 
 
 class DroplessExperts(Layer):
-    """Sparse SwiGLU experts with a gated shared expert, dropless, for a
-    chip that holds ``held = (first, count)`` of ``num_experts`` experts.
+    """Sparse experts with a shared expert, dropless, for a chip that holds
+    ``held = (first, count)`` of ``num_experts`` experts. What is computed
+    is named by the model:
+
+    ``act``     "swiglu": an expert is W_d^T (silu(W_g^T x) * W_u^T x);
+                "relu2": W_d^T relu(W_u^T x)^2, no gate. The shared expert
+                takes the same activation.
+    ``router``  "softmax": the ``top_k`` largest of softmax(x W_r),
+                renormalised with ``renormalize``; "sigmoid": s =
+                sigmoid(x W_r), the experts the ``top_k`` largest of s +
+                ``score_bias``, their weights the unbiased s, renormalised
+                with ``renormalize`` and times ``routed_scale``. With a
+                ``bias_update_rate``, each forward in training mode moves
+                ``score_bias`` by the balancing rule
+                (``balance_score_bias``) on the load of ALL router outputs
+                over its tokens, after its own choice: in a compiled step
+                the next step chooses with the moved bias.
 
     Parameters: ``router`` [h, num_experts] (the published width, whatever
-    is held), ``w_gate_up`` [count, h, 2 d] and ``w_down`` [count, d, h]
-    (stacked leaves, not 3 x count arrays), ``shared_gate_up`` [h, 2 d_s],
-    ``shared_down`` [d_s, h], ``shared_gate`` [h, 1] (none with
-    ``shared_gate=False``: the shared expert is added ungated; with
-    ``d_shared=0`` the model has no shared expert: none of the three is
-    built and nothing is added). Two int32 buffers ride
-    a compiled step like a running statistic and hold, after each forward,
-    ``routed_slots`` (slots that fell on held experts) and ``expert_rows``
-    (rows of the row buffer the grouped products were given: passes x the
-    buffer's rows); one ``moe_route`` event is left in the flight recorder
-    per trace."""
+    is held); for SwiGLU ``w_gate_up`` [count, h, 2 d] and
+    ``shared_gate_up`` [h, 2 d_s] (gate | up), for relu^2 ``w_up`` [count,
+    h, d] and ``shared_up`` [h, d_s]; ``w_down`` [count, d, h] (stacked
+    leaves, not one array an expert), ``shared_down`` [d_s, h],
+    ``shared_gate`` [h, 1] (none with ``shared_gate=False``: the shared
+    expert is added ungated; with ``d_shared=0`` the model has no shared
+    expert: none of the three is built and nothing is added). A sigmoid
+    router keeps ``score_bias`` [num_experts], a float32 buffer that no
+    gradient reaches and that ``to(dtype)`` leaves float32 (zero until the
+    model's owner sets it). Two int32
+    buffers ride a compiled step like a running statistic and hold, after
+    each forward, ``routed_slots`` (slots that fell on held experts) and
+    ``expert_rows`` (rows of the row buffer the grouped products were
+    given: passes x the buffer's rows); one ``moe_route`` event is left in
+    the flight recorder per trace."""
 
     def __init__(self, d_model, d_expert, num_experts, top_k, held=None,
                  d_shared=None, renormalize=True, weight_attr=None,
-                 shared_gate=True):
+                 shared_gate=True, act="swiglu", router="softmax",
+                 routed_scale=1.0, bias_update_rate=0.0):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if first < 0 or count < 1 or first + count > num_experts:
             raise ValueError(f"held {held} is no range of {num_experts}")
+        if act not in ("swiglu", "relu2"):
+            raise ValueError(f"act {act!r}")
+        if bias_update_rate and router != "sigmoid":
+            raise ValueError(f"bias_update_rate under router {router!r}")
         self.first, self.count = int(first), int(count)
         self.num_experts, self.top_k = num_experts, top_k
-        self.renormalize = renormalize
+        self.renormalize, self.act, self.router_kind = renormalize, act, router
+        self.routed_scale = float(routed_scale)
+        self.bias_update_rate = float(bias_update_rate)
         d_shared = d_expert if d_shared is None else d_shared
+        wide = 2 if act == "swiglu" else 1
 
         def make(*shape):
             return self.create_parameter(shape=list(shape), attr=weight_attr)
 
         self.router = make(d_model, num_experts)
-        self.w_gate_up = make(self.count, d_model, 2 * d_expert)
+        if act == "swiglu":
+            self.w_gate_up = make(self.count, d_model, 2 * d_expert)
+        else:
+            self.w_up = make(self.count, d_model, d_expert)
         self.w_down = make(self.count, d_expert, d_model)
-        self.shared_gate_up = make(d_model, 2 * d_shared) if d_shared else None
+        shared_in = make(d_model, wide * d_shared) if d_shared else None
+        if act == "swiglu":
+            self.shared_gate_up = shared_in
+        else:
+            self.shared_up = shared_in
         self.shared_down = make(d_shared, d_model) if d_shared else None
         self.shared_gate = (make(d_model, 1) if shared_gate and d_shared
                             else None)
+        if router == "sigmoid":
+            self.register_buffer("score_bias", Tensor(
+                np.zeros(num_experts, np.float32)), keep_dtype=True)
+        else:
+            self.score_bias = None
+        _check_router(router, self.score_bias, routed_scale)
         self.register_buffer("routed_slots", Tensor(np.int32(0)))
         self.register_buffer("expert_rows", Tensor(np.int32(0)))
 
@@ -686,11 +803,19 @@ class DroplessExperts(Layer):
         flat = x.reshape([-1, shape[-1]])
         rows = row_buffer_rows(flat.shape[0], self.top_k, self.num_experts,
                                self.count)
-        y, routed, ran = apply(
-            dropless_experts, flat, self.router, self.w_gate_up, self.w_down,
-            self.shared_gate_up, self.shared_down, self.shared_gate,
+        swiglu = self.act == "swiglu"
+        y, routed, ran, *load = apply(
+            dropless_experts, flat, self.router,
+            self.w_gate_up if swiglu else self.w_up, self.w_down,
+            self.shared_gate_up if swiglu else self.shared_up,
+            self.shared_down, self.shared_gate, self.score_bias,
             first=self.first, top_k=self.top_k, renormalize=self.renormalize,
-            rows=rows, op_name="dropless_experts")
+            rows=rows, act=self.act, router_kind=self.router_kind,
+            routed_scale=self.routed_scale, op_name="dropless_experts")
         self.routed_slots._value = routed._value
         self.expert_rows._value = ran._value
+        if self.bias_update_rate and self.training:
+            self.score_bias._value = balance_score_bias(
+                self.score_bias._value, load[0]._value,
+                self.bias_update_rate)
         return y.reshape(shape)

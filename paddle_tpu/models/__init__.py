@@ -37,3 +37,8 @@ from .mellum2 import (  # noqa: F401
     Mellum2ForCausalLM,
     Mellum2Model,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHForCausalLM,
+    NemotronHModel,
+)

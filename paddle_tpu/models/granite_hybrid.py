@@ -41,18 +41,13 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 import paddle_tpu as paddle
 
 from .. import nn
-from ..core.dispatch import apply
 from ..incubate.moe import DroplessExperts
-from ..nn import functional as F
 from ..nn import initializer as I
-from ._hybrid import linear as _linear
-from ._hybrid import residual_mixer, routed_load
+from ._hybrid import Mamba2Mixer, NoPEAttention, residual_mixer, routed_load
 
 Held = Optional[Tuple[int, int]]  # (first, count); None: all
 
@@ -115,126 +110,33 @@ def _norm(cfg, width=None):
     return nn.RMSNorm(width or cfg.hidden_size, epsilon=cfg.rms_norm_eps)
 
 
-def _share_event(kind, held, published):
-    from ..profiler import trace
-    trace.emit("mixer_share", site=kind, heads=held, heads_published=published)
+class GraniteHybridAttention(NoPEAttention):
+    """Query heads held in whole KV groups, the scores times
+    ``attention_multiplier``."""
 
+    op_name = "granite_attention_heads"
 
-# ---------------------------------------------------------------------------
-# attention
-# ---------------------------------------------------------------------------
-def _attention_heads(q, k, v, *, heads, kv_heads, heads_published):
-    _share_event("attention", heads, heads_published)
-    b, s = q.shape[0], q.shape[1]
-    return (q.reshape(b, s, heads, -1), k.reshape(b, s, kv_heads, -1),
-            v.reshape(b, s, kv_heads, -1))
-
-
-class GraniteHybridAttention(nn.Layer):
     def __init__(self, cfg: GraniteHybridConfig):
-        super().__init__()
-        self.cfg = cfg
-        h, d = cfg.hidden_size, cfg.head_dim
         group = cfg.num_attention_heads // cfg.num_key_value_heads
-        first, self.heads = cfg.held("attention_heads")
-        if first % group or self.heads % group:
+        first, heads = cfg.held("attention_heads")
+        if first % group or heads % group:
             raise ValueError(
-                f"attention_heads_held {(first, self.heads)}: not whole "
+                f"attention_heads_held {(first, heads)}: not whole "
                 f"groups of {group} query heads on one KV head")
-        self.kv_heads = self.heads // group
-        self.q_proj = _linear(cfg, h, self.heads * d)
-        self.k_proj = _linear(cfg, h, self.kv_heads * d)
-        self.v_proj = _linear(cfg, h, self.kv_heads * d)
-        self.o_proj = _linear(cfg, self.heads * d, h)
-
-    def forward(self, x):
-        cfg = self.cfg
-        q, k, v = apply(
-            _attention_heads, self.q_proj(x), self.k_proj(x), self.v_proj(x),
-            heads=self.heads, kv_heads=self.kv_heads,
-            heads_published=cfg.num_attention_heads,
-            op_name="granite_attention_heads")
-        attn = F.scaled_dot_product_attention(
-            q, k, v, is_causal=True, scale=cfg.attention_multiplier)
-        return self.o_proj(attn.reshape([x.shape[0], x.shape[1], -1]))
+        super().__init__(cfg, heads, heads // group, cfg.head_dim,
+                         cfg.attention_multiplier, cfg.num_attention_heads)
 
 
-# ---------------------------------------------------------------------------
-# state space
-# ---------------------------------------------------------------------------
-def _mamba_mixer(xbcz, dt, conv_w, conv_b, a_log, dt_bias, d_skip, norm_w, *,
-                 heads, heads_published, p, groups, n, chunk, eps):
-    """From the two input projections to the gated, normalised output of the
-    scan, [b, s, heads * p]."""
-    # imported here: the modules bring Pallas in, which costs every program
-    # that imports paddle_tpu.models a second and a half at start-up
-    from ..ops import linear_attention as _la
-    from ..ops import state_space as _ss
+class GraniteHybridMamba(Mamba2Mixer):
+    """The held state-space heads; one statistic over their channels."""
 
-    _share_event("mamba", heads, heads_published)
-    b, s = xbcz.shape[0], xbcz.shape[1]
-    inner, bc = heads * p, groups * n
-    with jax.named_scope("short_conv"):
-        # over xbcz's first inner + 2 bc lanes, read where they lie; x, B
-        # and C each written where the scan reads it
-        x, bm, cm = _la.short_conv_silu(xbcz, conv_w, (inner, bc, bc),
-                                        bias=conv_b)
-    with jax.named_scope("ssd_scan"):
-        step = jax.nn.softplus(dt.astype(jnp.float32)
-                               + dt_bias.astype(jnp.float32))
-        y = _ss.ssd_scan(
-            x.reshape(b, s, heads, p), step, a_log,
-            bm.reshape(b, s, groups, n), cm.reshape(b, s, groups, n), d_skip,
-            chunk=chunk, heads_published=heads_published)
-    with jax.named_scope("gated_norm"):
-        # the gate z is xbcz's last inner lanes; the statistic is over the
-        # channels held here
-        return _la.gated_rms_norm(y.reshape(b, s, inner), xbcz, norm_w,
-                                  epsilon=eps, gate_first=True)
-
-
-class GraniteHybridMamba(nn.Layer):
     def __init__(self, cfg: GraniteHybridConfig):
-        super().__init__()
-        self.cfg = cfg
-        h, p = cfg.hidden_size, cfg.mamba_d_head
-        _, self.heads = cfg.held("mamba_heads")
-        inner = self.heads * p
-        channels = inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
-        self.in_proj_xbcz = _linear(cfg, h, channels + inner)
-        self.in_proj_dt = _linear(cfg, h, self.heads)
-        self.out_proj = _linear(cfg, inner, h)
-        self.conv_weight = self.create_parameter(
-            shape=[channels, cfg.mamba_d_conv],
-            default_initializer=I.Normal(0.0, cfg.initializer_range))
-        self.conv_bias = self.create_parameter(
-            shape=[channels], default_initializer=I.Constant(0.0))
-        # the public init: A_log = log U(1, 16), dt_bias the inverse softplus
-        # of dt in [1e-3, 1e-1], D = 1, gain = 1
-        rng = np.random.default_rng(0)
-        step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), self.heads))
-        self.A_log = self.create_parameter(
-            shape=[self.heads], default_initializer=I.Assign(np.log(
-                rng.uniform(1.0, 16.0, self.heads))))
-        self.dt_bias = self.create_parameter(
-            shape=[self.heads],
-            default_initializer=I.Assign(step + np.log(-np.expm1(-step))))
-        self.D = self.create_parameter(
-            shape=[self.heads], default_initializer=I.Constant(1.0))
-        self.norm_weight = self.create_parameter(
-            shape=[inner], default_initializer=I.Constant(1.0))
-
-    def forward(self, x):
-        cfg = self.cfg
-        y = apply(
-            _mamba_mixer, self.in_proj_xbcz(x), self.in_proj_dt(x),
-            self.conv_weight, self.conv_bias, self.A_log, self.dt_bias,
-            self.D, self.norm_weight,
-            heads=self.heads, heads_published=cfg.mamba_n_heads,
-            p=cfg.mamba_d_head, groups=cfg.mamba_n_groups,
-            n=cfg.mamba_d_state, chunk=cfg.mamba_chunk_size,
-            eps=cfg.rms_norm_eps, op_name="mamba_mixer")
-        return self.out_proj(y)
+        super().__init__(
+            cfg, heads=cfg.held("mamba_heads")[1],
+            heads_published=cfg.mamba_n_heads, p=cfg.mamba_d_head,
+            n=cfg.mamba_d_state, groups=cfg.mamba_n_groups,
+            conv=cfg.mamba_d_conv, chunk=cfg.mamba_chunk_size,
+            eps=cfg.rms_norm_eps)
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,7 @@ class Layer:
         self._sub_layers: "collections.OrderedDict[str, Layer]" = collections.OrderedDict()
         self._buffers: "collections.OrderedDict[str, Tensor]" = collections.OrderedDict()
         self._non_persistable_buffer_names = set()
+        self._dtype_kept_buffer_names = set()
         self._forward_pre_hooks = collections.OrderedDict()
         self._forward_post_hooks = collections.OrderedDict()
         self._casted_dtype = None  # set by amp O2 decorate / .to(dtype)
@@ -139,10 +140,15 @@ class Layer:
             for n, c in child._sub_layers.items():
                 child._adopt(n, c)
 
-    def register_buffer(self, name, tensor, persistable=True):
+    def register_buffer(self, name, tensor, persistable=True,
+                        keep_dtype=False):
+        """``keep_dtype``: ``to(dtype)`` (and so AMP-O2's ``decorate``)
+        leaves the buffer in its own type."""
         self._buffers[name] = tensor
         if not persistable:
             self._non_persistable_buffer_names.add(name)
+        if keep_dtype:
+            self._dtype_kept_buffer_names.add(name)
         return tensor
 
     # -- attribute magic -----------------------------------------------------
@@ -359,8 +365,11 @@ class Layer:
                         p._value = p._value.astype(
                             __import__("paddle_tpu").core.dtype.to_np_dtype(dtype)
                         )
+                kept = {id(b) for _, _, layer in self._walk()
+                        for name, b in layer._buffers.items()
+                        if name in layer._dtype_kept_buffer_names}
                 for b in self.buffers():
-                    if b.dtype.is_floating_point:
+                    if b.dtype.is_floating_point and id(b) not in kept:
                         b._value = b._value.astype(
                             __import__("paddle_tpu").core.dtype.to_np_dtype(dtype)
                         )

@@ -619,14 +619,20 @@ def _norm_xla(o, z, weight, epsilon):
         o.dtype).reshape(o.shape)
 
 
-def _gate_then_norm_xla(o, z, weight, epsilon):
+def _gate_then_norm_xla(o, z, weight, epsilon, group=None):
     gated = o.astype(F32) * jax.nn.silu(z.astype(F32))
+    if group is not None:  # [.., groups, group]: a statistic a group
+        gated = gated.reshape(*gated.shape[:-1], -1, group)
+        var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
+        normed = (gated * jax.lax.rsqrt(var + epsilon)).reshape(o.shape)
+        return (normed * weight.astype(F32)).astype(o.dtype)
     var = jnp.mean(jnp.square(gated), axis=-1, keepdims=True)
     return (gated * jax.lax.rsqrt(var + epsilon)
             * weight.astype(F32)).astype(o.dtype)
 
 
-def gated_rms_norm(o, z, weight, *, epsilon=1e-6, gate_first=False):
+def gated_rms_norm(o, z, weight, *, epsilon=1e-6, gate_first=False,
+                   group=None):
     """o / rms(o) * weight * silu(z), head by head: o [batch, seq, heads *
     d] as the rule leaves it, a head being ``d = len(weight)`` lanes; z the
     LAST heads * d lanes of [batch, seq, heads * d or more]. Statistics and
@@ -636,7 +642,9 @@ def gated_rms_norm(o, z, weight, *, epsilon=1e-6, gate_first=False):
     weight with ONE statistic over all of o's lanes and a gain of that
     width (a row's sum crosses the 128-lane blocks the kernels below work
     in: it runs as the ``jax.numpy`` expression, ``mixer_pass.why``
-    ``statistic_over_all_lanes``).
+    ``statistic_over_all_lanes``); with ``group``, one statistic for each
+    run of ``group`` lanes (``why`` ``statistic_per_group``), the gain
+    still of all lanes.
 
     Where d is one block of 128 lanes and the sequence whole rows of 8,
     two kernels do it (``gated_rms_norm_fwd``, ``_bwd``: do, dz and the
@@ -646,11 +654,13 @@ def gated_rms_norm(o, z, weight, *, epsilon=1e-6, gate_first=False):
     batch, seq, lanes = o.shape
     d, gate = weight.shape[0], z.shape[2] - lanes
     if gate_first:
-        if d != lanes:
-            raise ValueError(f"gate-first norm: a gain of {d} for {lanes}")
-        _pass_event("gated_rms_norm", None, "statistic_over_all_lanes",
-                    batch, seq, lanes)
-        return _gate_then_norm_xla(o, z[..., gate:], weight, epsilon)
+        if d != lanes or (group is not None and lanes % group):
+            raise ValueError(f"gate-first norm: a gain of {d} for {lanes}"
+                             f" in groups of {group}")
+        _pass_event("gated_rms_norm", None,
+                    "statistic_over_all_lanes" if group is None
+                    else "statistic_per_group", batch, seq, lanes)
+        return _gate_then_norm_xla(o, z[..., gate:], weight, epsilon, group)
     if d != 128:
         walk, why = None, "head_not_128_lanes"
     else:

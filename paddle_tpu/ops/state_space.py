@@ -20,15 +20,17 @@ for all heads of the group, made once a chunk.
 
 ``ssd_scan_fwd``  walks a grid of (batch, blocks of chunks, lane blocks of
     heads): x is read where the conv left it ([batch, seq, heads * P]: a lane
-    block of 128 holds 128 / P heads), B and C likewise ([batch, seq, N]); the
-    masked C B^T of the grid step's chunks is made at the first lane block
-    and kept in VMEM for the others; S^T [N, 128] of every lane block stays
-    in float32 VMEM scratch over a sequence's chunks. Writes y and, for the
-    backward, the state each chunk starts from (in the operands' type).
+    block of 128 holds 128 / P heads), B and C likewise ([batch, seq, groups
+    * N]: a lane block reads its group's N lanes, the heads of a group being
+    whole lane blocks side by side); the masked C B^T of the grid step's
+    chunks is made at a group's first lane block and kept in VMEM for the
+    group's others; S^T [N, 128] of every lane block stays in float32 VMEM
+    scratch over a sequence's chunks. Writes y and, for the backward, the
+    state each chunk starts from (in the operands' type).
 ``ssd_scan_bwd``  the chunks in reverse with dS in VMEM: makes the tables
     again, carries the cotangents through the four products, sums d(C B^T)
-    over the lane blocks in scratch and turns it to dB, dC at the last one;
-    writes dx, d dt, dg, dB, dC.
+    over a group's lane blocks in scratch and turns it to the group's dB, dC
+    at its last one; writes dx, d dt, dg, dB, dC.
 
 Operands of the products are in the type x comes in (bf16 under AMP-O2); the
 state, the decays, dt, the tables and every sum in float32. Shapes the
@@ -223,15 +225,22 @@ def _gate_rows(ref, c):
     return tuple(ref[k, c] for k in range(ref.shape[0]))
 
 
+def _in_group(block, per_group):
+    """The lane block's place in its B / C group."""
+    return jax.lax.rem(block, np.int32(per_group))
+
+
 def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, g_ref, y_ref, h_ref,
-                s_scr, scores_scr, *, chunk, per_step):
+                s_scr, scores_scr, *, chunk, per_step, per_group):
     block = pl.program_id(2)  # of 128 lanes: the heads it holds
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         s_scr[block] = jnp.zeros(s_scr.shape[1:], F32)
 
-    @pl.when(block == 0)  # C B^T: once for all heads
+    # C B^T: once for all heads of a group
+    @pl.when(block == 0 if per_group is None
+             else _in_group(block, per_group) == 0)
     def _():
         for c in range(per_step):
             rows = slice(c * chunk, (c + 1) * chunk)
@@ -252,14 +261,15 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, g_ref, y_ref, h_ref,
 def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, g_ref, h_ref, dy_ref,
                 dx_ref, ddt_ref, dg_ref, db_ref, dc_ref,
                 ds_scr, scores_scr, dscores_scr, db_scr, dc_scr, *,
-                chunk, per_step):
+                chunk, per_step, per_group):
     block, blocks = pl.program_id(2), pl.num_programs(2)
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_scr[block] = jnp.zeros(ds_scr.shape[1:], F32)
 
-    @pl.when(block == 0)
+    @pl.when(block == 0 if per_group is None
+             else _in_group(block, per_group) == 0)
     def _():
         for c in range(per_step):
             rows = slice(c * chunk, (c + 1) * chunk)
@@ -283,7 +293,9 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, g_ref, h_ref, dy_ref,
         dc_scr[rows, :] += dc
     ds_scr[block] = ds
 
-    @pl.when(block == blocks - 1)  # dB, dC: summed over the heads
+    # dB, dC: summed over the heads of the group
+    @pl.when(block == blocks - 1 if per_group is None
+             else _in_group(block, per_group) == per_group - 1)
     def _():
         for c in range(per_step):
             rows = slice(c * chunk, (c + 1) * chunk)
@@ -303,6 +315,7 @@ class _Geometry(NamedTuple):
     heads: int     # heads side by side in one block of 128 lanes
     blocks: int    # lane blocks of x: heads / ``heads``
     state: int     # N
+    per_group: int = None  # lane blocks of a B / C group; None: one group
 
 
 def _call(kernel, name, geo, batch, n_chunks, ins, outs, scratch,
@@ -310,9 +323,10 @@ def _call(kernel, name, geo, batch, n_chunks, ins, outs, scratch,
     """``kernel`` over the grid (batch, blocks of chunks, lane blocks).
     ``ins`` are (kind, array) pairs, ``outs`` (kind, shape, dtype); a kind is
     the block a grid step gets: "x" rows of one lane block out of [batch,
-    seq, heads * P], "bc" rows of [batch, seq, N], "gate" the block's heads
-    out of [batch * heads, chunks, 1, chunk], "h" the states of its chunks
-    out of [batch * lane blocks, chunks, N, 128]."""
+    seq, heads * P], "bc" rows of [batch, seq, N] (of its group's N lanes
+    out of [batch, seq, groups * N] with ``per_group``), "gate" the block's
+    heads out of [batch * heads, chunks, 1, chunk], "h" the states of its
+    chunks out of [batch * lane blocks, chunks, N, 128]."""
     rows = geo.chunk * geo.per_step
     n_blocks = n_chunks // geo.per_step
     blocks = np.int32(geo.blocks)
@@ -320,17 +334,23 @@ def _call(kernel, name, geo, batch, n_chunks, ins, outs, scratch,
     def at(j):
         return np.int32(n_blocks - 1) - j if reverse else j
 
+    def group(r):
+        if geo.per_group is None:
+            return _0
+        return jax.lax.div(r, np.int32(geo.per_group))
+
     specs = {
         "x": pl.BlockSpec((1, rows, 128), lambda i, j, r: (i, at(j), r)),
         "bc": pl.BlockSpec((1, rows, geo.state),
-                           lambda i, j, r: (i, at(j), _0)),
+                           lambda i, j, r: (i, at(j), group(r))),
         "gate": pl.BlockSpec((geo.heads, geo.per_step, 1, geo.chunk),
                              lambda i, j, r: (i * blocks + r, at(j), _0, _0)),
         "h": pl.BlockSpec((1, geo.per_step, geo.state, 128),
                           lambda i, j, r: (i * blocks + r, at(j), _0, _0)),
     }
     return pl.pallas_call(
-        functools.partial(kernel, chunk=geo.chunk, per_step=geo.per_step),
+        functools.partial(kernel, chunk=geo.chunk, per_step=geo.per_step,
+                          per_group=geo.per_group),
         name=name,
         grid=(batch, n_blocks, geo.blocks),
         in_specs=[specs[kind] for kind, _ in ins],
@@ -380,8 +400,8 @@ def _backward(x, bm, cm, dt, g, h, dy, geo):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _scan_vmem(x, bm, cm, dt, g, geo):
-    """y as x, from x [batch, seq, heads * P], B, C [batch, seq, N] and the
-    gates [batch * heads, chunks, 1, chunk] float32."""
+    """y as x, from x [batch, seq, heads * P], B, C [batch, seq, groups * N]
+    and the gates [batch * heads, chunks, 1, chunk] float32."""
     return _forward(x, bm, cm, dt, g, geo)[0]
 
 
@@ -442,10 +462,10 @@ def _scan_xla(x, bm, cm, dt, g, chunk):
 
 def _refusal(heads, p, groups, n_state, chunk):
     """Why the kernels cannot take these shapes, or None where they can."""
-    if groups != 1:
-        return "groups_not_one"
     if p > 128 or 128 % p or (heads * p) % 128:
         return "heads_not_blocks_of_128_lanes"
+    if (heads // groups * p) % 128:
+        return "group_not_blocks_of_128_lanes"
     if n_state % 128:
         return "state_not_blocks_of_128_lanes"
     if chunk % 128:
@@ -472,8 +492,8 @@ def ssd_scan(x, dt, A_log, B, C, D, *, chunk=256, heads_published=None):
     state are float32; the skip ``D x`` is added outside the kernels.
 
     A sequence that is not whole chunks is padded with steps of dt = 0
-    (which leave the state as it is) and the padding's rows dropped. One
-    group, P a divisor of 128 with heads * P whole blocks of 128 lanes, N
+    (which leave the state as it is) and the padding's rows dropped. P a
+    divisor of 128 with a group's heads x P whole blocks of 128 lanes, N
     and the chunk whole blocks of 128: the two kernels ``ssd_scan_fwd`` /
     ``ssd_scan_bwd``; other shapes the chunked ``jax.numpy`` form, counted
     as ``ssd_scan_fallbacks``. Each trace leaves one ``ssd_chunks`` event in
@@ -503,15 +523,21 @@ def ssd_scan(x, dt, A_log, B, C, D, *, chunk=256, heads_published=None):
     n = (seq + pad) // chunk
     if why is None:
         per_lane_block = 128 // p
-        geo = _Geometry(chunk, _per_step(n, chunk), per_lane_block,
-                        heads // per_lane_block, n_state)
+        blocks = heads // per_lane_block
+        geo = _Geometry(chunk, _per_step(n, chunk), per_lane_block, blocks,
+                        n_state, blocks // groups if groups > 1 else None)
 
         def gate(a):  # [b, seq, heads] -> [b * heads, chunks, 1, chunk]
             a = jnp.moveaxis(a.reshape(b, n, chunk, heads), 3, 1)
             return a.reshape(b * heads, n, 1, chunk)
 
-        y = _scan_vmem(x.reshape(b, n * chunk, heads * p), B[:, :, 0],
-                       C[:, :, 0], gate(dt), gate(g), geo)
+        def lanes(a):  # [b, seq, groups, N] -> [b, seq, groups * N]
+            if groups == 1:
+                return a[:, :, 0]
+            return a.reshape(b, n * chunk, groups * n_state)
+
+        y = _scan_vmem(x.reshape(b, n * chunk, heads * p), lanes(B),
+                       lanes(C), gate(dt), gate(g), geo)
         y = y.reshape(b, n * chunk, heads, p)
     else:
         y = _scan_xla(x, B, C, dt, g, chunk)

@@ -36,6 +36,12 @@ ChromeTracingLogger stack argues for, SURVEY.md §5):
                    as ssd_scan_fallbacks) (trace time, once per trace)
   mixer_share      a mixer that holds a share of its heads was traced: the
                    kind (site), the heads held and published
+  moe_route        the dropless expert layer was traced for a shape: the
+                   experts held of the router's, experts a token, the row
+                   buffer's rows, tokens, the bytes of the first pass's
+                   kept product, the activation ("swiglu" / "relu2"), the
+                   router ("softmax" / "sigmoid") and the routed scale
+                   (trace time, once per trace)
   flush            lazy-segment flush: reason, cache hit/miss/join,
                    fused vs bridged vs per-op fallback
   async_compile /  background-compile submissions and the joins that

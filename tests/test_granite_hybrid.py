@@ -226,8 +226,13 @@ def recurrence(x, dt, a_log, bm, cm, d_skip):
     (1, 200, 4, 64, 1, 128, 128, "vmem"),   # not whole chunks: padded
     (2, 96, 4, 16, 2, 32, 32, "xla"),       # two groups, narrow heads
     (1, 70, 2, 16, 1, 32, 32, "xla"),       # not whole chunks
+    (1, 512, 8, 64, 2, 128, 128, "vmem"),   # two groups of two lane blocks,
+    #                                         four chunks a grid step
+    (1, 256, 32, 64, 8, 128, 128, "vmem"),  # eight groups of two lane blocks
+    (2, 256, 16, 64, 8, 128, 128, "vmem"),  # eight groups of one lane block
 ], ids=["pairs", "two_chunks_a_step", "wide_head", "padded", "groups",
-        "xla_padded"])
+        "xla_padded", "two_groups_kernels", "eight_groups_kernels",
+        "eight_groups_a_block_each"])
 def test_chunked_scan_matches_the_token_recurrence(b, s, heads, p, groups, n,
                                                    chunk, path):
     """Forward and every input's gradient, with the kernels interpreted
@@ -268,7 +273,9 @@ def test_bf16_scan_stays_within_its_rounding_of_the_recurrence():
 
 def test_scan_refusals_name_their_reason():
     assert ss._refusal(16, 64, 1, 128, 256) is None
-    assert ss._refusal(16, 64, 2, 128, 256) == "groups_not_one"
+    assert ss._refusal(16, 64, 2, 128, 256) is None
+    assert ss._refusal(64, 64, 8, 128, 128) is None
+    assert ss._refusal(4, 64, 4, 128, 256) == "group_not_blocks_of_128_lanes"
     assert ss._refusal(3, 64, 1, 128, 256) == "heads_not_blocks_of_128_lanes"
     assert ss._refusal(8, 48, 1, 128, 256) == "heads_not_blocks_of_128_lanes"
     assert ss._refusal(16, 64, 1, 64, 256) == "state_not_blocks_of_128_lanes"

@@ -610,7 +610,7 @@ def test_output_and_every_gradient_in_one_pass_and_in_three(
 HELD_EXPERTS_APPLY = moe.held_experts_apply
 
 
-def _every_pass_in_the_loop_bwd(rows, res, dy):
+def _every_pass_in_the_loop_bwd(rows, act, res, dy):
     """The layer's written-out backward as it stood before its first pass
     took the forward's gate-up product, kept here to hold the new one to
     it: every pass inside the loop from zeros, each making its gate-up
@@ -652,14 +652,17 @@ def _every_pass_in_the_loop_bwd(rows, res, dy):
     return dx.astype(x.dtype), dwgt.astype(wgt.dtype), dgu, dd, None, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def every_pass_in_the_loop(x, wgt, w_gate_up, w_down, tok, offsets, rows):
-    return HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def every_pass_in_the_loop(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                           act="swiglu"):
+    return HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                              act)
 
 
 every_pass_in_the_loop.defvjp(
-    lambda x, wgt, w_gate_up, w_down, tok, offsets, rows: (
-        HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows),
+    lambda x, wgt, w_gate_up, w_down, tok, offsets, rows, act: (
+        HELD_EXPERTS_APPLY(x, wgt, w_gate_up, w_down, tok, offsets, rows,
+                           act),
         (x, wgt, w_gate_up, w_down, tok, offsets)),
     _every_pass_in_the_loop_bwd)
 
@@ -768,7 +771,9 @@ def test_counters_ride_the_compiled_step_and_one_event_a_compile(both):
     assert events[0].attrs == dict(
         held=held, num_experts=SIZES["router_experts"], top_k=top_k,
         buffer_rows=rows, tokens=tokens,
-        saved_gate_up_bytes=rows * 2 * SIZES["moe_intermediate_size"] * 4)
+        saved_first_product_bytes=(
+            rows * 2 * SIZES["moe_intermediate_size"] * 4),
+        act="swiglu", router="softmax", routed_scale=1.0)
     load = model.routed_load()
     assert load[0][1] == want
     assert all(ran % rows == 0 and ran >= routed for _, routed, ran in load)

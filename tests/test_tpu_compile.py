@@ -427,6 +427,33 @@ def test_ssd_scan_compiles_for_v5e(one_chip, compiled_kernels):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**28
 
 
+def test_grouped_ssd_scan_compiles_for_v5e(one_chip, compiled_kernels):
+    """nemotron3nano-train-s8192's scan: 64 heads of 64 on 8 B / C groups of
+    128 (8 heads, 4 lane blocks a group) at 2 x 8,192, chunk 128 (4 chunks a
+    grid step), forward and backward: the two kernels are in the compiled
+    text, no fallback, and no table of all chunks leaves VMEM."""
+    from paddle_tpu.core.dispatch import dispatch_counters
+
+    x = _sds((2, 8192, 64, 64), jnp.bfloat16, one_chip)
+    gate = _sds((2, 8192, 64), jnp.float32, one_chip)
+    head = _sds((64,), jnp.bfloat16, one_chip)
+    bc = _sds((2, 8192, 8, 128), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(*args):
+        def loss(*a):
+            return ssm.ssd_scan(*a, chunk=128).astype(jnp.float32).sum()
+
+        return jax.grad(loss, argnums=tuple(range(6)))(*args)
+
+    fallbacks = dispatch_counters()["ssd_scan_fallbacks"]
+    compiled = jax.jit(fwd_bwd).lower(x, gate, head, bc, bc, head).compile()
+    assert dispatch_counters()["ssd_scan_fallbacks"] == fallbacks
+    text = compiled.as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert not re.findall(r"f32\[[0-9,]*128,128\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 def test_granite_hybrid_train_step_compiles_for_v5e(one_chip,
                                                     compiled_kernels,
                                                     monkeypatch):
@@ -681,3 +708,79 @@ def test_mellum2_train_step_compiles_for_v5e(one_chip, compiled_kernels,
     assert mem.temp_size_in_bytes <= 4_805_358_592, mem
     assert sum(int(np.prod(p.shape)) for p in step._params) == 340_349_184
     assert len(step._params) == 39
+
+
+# ---------------------------------------------------------------------------
+# the one-part-a-layer hybrid decoder at the shapes of the cell
+# nemotron3nano-train-s8192 (2 x 8,192; benchmark/configs/nemotron-3-nano-*)
+# ---------------------------------------------------------------------------
+def test_nemotron_h_train_step_compiles_for_v5e(one_chip, compiled_kernels,
+                                                monkeypatch):
+    """The whole compile_train_step program of the cell, built from the
+    cell's own configuration and traffic files: published widths, the first
+    9 layers of the pattern (4 Mamba-2 on 8 B / C groups, 4 relu^2 expert
+    layers of 8 held experts under the sigmoid router, 1 attention layer),
+    an eighth of the vocabulary with an untied head, AMP O2 bf16, AdamW, 2 x
+    8,192 tokens, nothing recomputed. It fits the chip and holds the scan's
+    and the flash kernels, the grouped products and the scopes the cell's
+    readers time, with no scan fallen back. (PERF.md section 4 has the
+    memory it reads.)"""
+    import json
+
+    import paddle_tpu.nn.initializer as I
+    from benchmark.lib import program_nemotron_h as prog
+    from paddle_tpu.core import random as _random
+    from paddle_tpu.core.dispatch import dispatch_counters
+    from paddle_tpu.models import GPTPretrainingCriterion
+
+    # shapes are all that matter here: skip drawing a billion normals
+    monkeypatch.setattr(I.Normal, "_generate",
+                        lambda self, shape, dtype: jnp.zeros(shape, dtype))
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmark")
+    with open(os.path.join(here, "configs",
+                           "nemotron-3-nano-30b-a3b-ep16.json")) as f:
+        sizes = json.load(f)
+    with open(os.path.join(here, "workloads",
+                           "nemotron3nano-train-s8192.json")) as f:
+        mix = json.load(f)["traffic"]
+    assert (sizes["num_hidden_layers"], sizes["recompute_mixer"],
+            mix["batch"], mix["seq"]) == (9, False, 2, 8192)
+    _, model = prog.build_model(sizes)
+    model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+    crit = GPTPretrainingCriterion()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters(),
+                                 weight_decay=0.01)
+    step = paddle.jit.compile_train_step(
+        model, lambda lg, lb: crit(lg.astype("float32"), lb), opt)
+    step._opt_state = step._init_opt_state()
+    ids = _sds((2, 8192), jnp.int32, one_chip)
+    args = (tuple(p._value for p in step._params), tuple(step._opt_state),
+            tuple(b._value for b in step._buffers), _random.next_key(),
+            jnp.asarray(1e-4, jnp.float32), ids, ids)
+    specs = jax.tree_util.tree_map(
+        lambda a: _sds(tuple(a.shape), a.dtype, one_chip), args)
+    step._arg_specs = specs
+    fallbacks = dispatch_counters()["ssd_scan_fallbacks"]
+    compiled = step._build().lower(*specs).compile()
+    assert dispatch_counters()["ssd_scan_fallbacks"] == fallbacks
+    text = compiled.as_text()
+    for name in ("ssd_scan_fwd", "ssd_scan_bwd", "flash_attention_fwd",
+                 "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                 "ragged-dot", "short_conv_silu_fwd", "short_conv_silu_bwd",
+                 "ssd_scan", "short_conv", "gated_norm", "router", "experts",
+                 "shared_expert"):
+        assert name in text, name
+    assert "8192,8192]" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # 12.79 GiB of 15.75 (13.73 GB): 4.00 GB of state, 9.73 GB of
+    # temporaries, with every activation kept; the correction biases'
+    # balancing step (each expert layer's count of slots by router output)
+    # adds 1,896,448 bytes
+    assert total < 14.5 * 2**30, mem
+    assert mem.argument_size_in_bytes == 4_002_021_888, mem
+    assert mem.temp_size_in_bytes <= 9_730_076_672, mem
+    assert sum(int(np.prod(p.shape)) for p in step._params) == 666_962_944
+    assert len(step._params) == 72
